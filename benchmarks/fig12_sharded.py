@@ -1,8 +1,10 @@
 """Fig. 12 (repo extension): sharded contraction execution over a mesh.
 
 Times the shard-aware lowering (:mod:`repro.distributed.contract`) against
-the single-device engine on an 8-way simulated CPU mesh (2×4, axes
-``x``/``y``), for the three sharding regimes the planner distinguishes:
+the single-device engine on a mesh over the devices that exist (axes
+``x``/``y``; 2×4 on eight devices — simulate them on CPU with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``), for the three
+sharding regimes the planner distinguishes:
 
 * **batch-sharded** — the strided-batch mode lives on a mesh axis; zero
   collectives, the embarrassingly-parallel regime;
@@ -11,41 +13,43 @@ the single-device engine on an 8-way simulated CPU mesh (2×4, axes
 * **comm-aware path** — a 3-operand chain whose sharded path cost
   includes the collective term.
 
-Simulated host devices share one CPU, so wall-clock *speedups* here are
+Simulated host devices share one CPU, so wall-clock *speedups* there are
 not meaningful — what the numbers show is the collective overhead, and
 the ``derived`` column carries the real payload: max |Δ| against the
 single-device result (the differential guarantee) plus the collective
 structure.  Run on real devices, the same code path is the scaling story.
 
-The module re-execs itself in a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` so the forced
-device count never leaks into the parent process (same pattern as the
-dry-run tooling).
+Everything runs in this process: a process that holds an accelerator
+holds it alone, so there is no child to hand the devices to.
 """
 
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import PartitionSpec as P
+
+from benchmarks.common import time_fn
+from repro.core.contract import contract, infer_dims
+from repro.core.einsum import xeinsum
+from repro.core.notation import parse_spec
+from repro.distributed.contract import plan_sharded, sharded_contract
+from repro.launch.mesh import make_mesh
 
 __all__ = ["run"]
 
-_DEVICES = 8
+
+def _mesh():
+    """x×y mesh over the largest power-of-two number of devices."""
+    n = 1 << (len(jax.devices()).bit_length() - 1)
+    x = 2 if n >= 4 else 1
+    return make_mesh((x, n // x), ("x", "y"), devices=jax.devices()[:n])
 
 
-def _child(quick: bool) -> None:
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from jax.sharding import PartitionSpec as P
-
-    from benchmarks.common import time_fn
-    from repro.core.contract import contract
-    from repro.core.einsum import xeinsum
-    from repro.distributed.contract import plan_sharded, sharded_contract
-
-    mesh = jax.make_mesh((2, 4), ("x", "y"))
+def run(quick: bool = False):
+    rows = []
+    mesh = _mesh()
     n = 64 if quick else 256
     rng = np.random.default_rng(0)
 
@@ -63,9 +67,6 @@ def _child(quick: bool) -> None:
             ),
             *operands,
         )
-        from repro.core.contract import infer_dims
-        from repro.core.notation import parse_spec
-
         cs = parse_spec(spec)
         plan = plan_sharded(
             cs, infer_dims(cs, *operands), mesh=mesh, in_specs=in_specs,
@@ -82,12 +83,13 @@ def _child(quick: bool) -> None:
             + (["psum"] if plan.psum_axes else [])
             + (["gather"] if plan.gathers else [])
         ) or "none"
-        print(f"{name},{sharded_us:.1f},"
-              f"single_us={single_us:.1f};collectives={coll};maxerr={err:.1e}")
+        rows.append((name, sharded_us,
+                     f"single_us={single_us:.1f};collectives={coll};"
+                     f"maxerr={err:.1e}"))
 
     # batch-sharded strided-batched GEMM (paper case 1.3 regime): p on y
     row("fig12_batch_sharded", "mk,pkn->pmn",
-        (arr(n, n), arr(_DEVICES, n, n)),
+        (arr(n, n), arr(mesh.shape["y"], n, n)),
         (P(None, None), P("y", None, None)))
     # contracted mode sharded in both operands -> psum
     row("fig12_contracted_psum", "mk,kn->mn",
@@ -115,39 +117,6 @@ def _child(quick: bool) -> None:
     ref = xeinsum("ik,kn,nj->ij", A, B, C)
     got = xeinsum("ik,kn,nj->ij", A, B, C, mesh=mesh, in_specs=in_specs)
     err = float(jnp.max(jnp.abs(got - ref)))
-    print(f"fig12_chain_sharded,{chain_sharded:.1f},"
-          f"single_us={chain_single:.1f};maxerr={err:.1e}")
-
-
-def run(quick: bool = False):
-    """Spawn the 8-device child and parse its CSV rows."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={_DEVICES} "
-        + env.get("XLA_FLAGS", "")
-    ).strip()
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    argv = [sys.executable, "-m", "benchmarks.fig12_sharded", "--child"]
-    if quick:
-        argv.append("--quick")
-    out = subprocess.run(argv, capture_output=True, text=True, env=env,
-                         timeout=1200)
-    if out.returncode != 0:
-        raise RuntimeError(
-            f"fig12 child failed:\nSTDOUT:\n{out.stdout}\nSTDERR:\n{out.stderr}"
-        )
-    rows = []
-    for line in out.stdout.splitlines():
-        if not line.startswith("fig12_"):
-            continue
-        name, us, derived = line.split(",", 2)
-        rows.append((name, float(us), derived))
+    rows.append(("fig12_chain_sharded", chain_sharded,
+                 f"single_us={chain_single:.1f};maxerr={err:.1e}"))
     return rows
-
-
-if __name__ == "__main__":
-    if "--child" in sys.argv:
-        _child(quick="--quick" in sys.argv)
-    else:
-        for r in run(quick="--quick" in sys.argv):
-            print(r)

@@ -23,6 +23,7 @@ import traceback
 
 from benchmarks import common
 from benchmarks.common import emit
+from repro.utils import place_compile_cache
 
 MODULES = [
     "fig1_transpose_cost",
@@ -90,6 +91,7 @@ def main() -> None:
                          "fresh tracer per module, so figures don't "
                          "bleed into each other)")
     args = ap.parse_args()
+    place_compile_cache()
     if args.quick:
         common.QUICK = True
         os.environ.setdefault("REPRO_BENCH_QUICK", "1")

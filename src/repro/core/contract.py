@@ -32,8 +32,8 @@ single entry point for pairwise tensor contractions.  Strategies:
 Backends: ``"xla"`` (dot_general / vmap composition) or ``"pallas"``
 (the StridedBatchedGEMM family of TPU kernels).  With
 ``backend="pallas"``, ``tiles={"u"|"v"|"k"|"b": int}`` overrides the
-kernel tile sizes per call (validated; see
-:func:`repro.tuning.candidates.validate_tiles`, and
+kernel tile sizes per call (validated against the blocks the kernel
+runs; see :func:`repro.tuning.candidates.validate_plan_tiles`, and
 :func:`~repro.tuning.candidates.validate_native_tiles` for
 ``strategy="native"``, whose working set is accounted per mode).
 """
@@ -300,14 +300,9 @@ def _contract_impl(
         from repro.kernels import ops  # deferred: keeps core importable sans pallas
 
         if tiles is not None:
-            from repro.tuning.candidates import validate_tiles  # no cycle
+            from repro.tuning.candidates import validate_plan_tiles  # no cycle
 
-            eff = dict(tiles)
-            if plan.kind == CaseKind.EXCEPTIONAL and "b" not in eff:
-                # match execute_plan's brick-depth default so the VMEM
-                # check sees the tiles the kernel will actually run with
-                eff["b"] = ops.EXT_BATCH_TILE
-            validate_tiles(eff)
+            validate_plan_tiles(plan, tiles, jnp.result_type(A.dtype, B.dtype))
         return ops.execute_plan(plan, A, B, out_dtype=out_dtype, tiles=tiles)
     return _execute_xla(plan, A, B, preferred_element_type).astype(out_dtype)
 

@@ -109,7 +109,9 @@ def hooi(
         [("y3", "mnp,mi,nj->ijp", ("T", "A", "B")),
          ("g3", "ijp,ijq->pq", ("y3", "y3"), {"strategy": "direct"})]), **kw)
 
-    def body(fac):
+    # T is an argument, not a closure: a jitted closure would embed the
+    # whole tensor in the executable as a constant
+    def body(T, fac):
         A, B, C = fac
         g1, t1 = p1(T, C, B)
         A = _factor_from_gram(g1, i)
@@ -120,7 +122,7 @@ def hooi(
     step = jax.jit(body) if jit else body
     fac = (A, B, C)
     for _ in range(n_iter):
-        fac = step(fac)
+        fac = step(T, fac)
     A, B, C = fac
 
     # G_ijk = T ×1 Aᵀ ×2 Bᵀ ×3 Cᵀ — one four-operand expression

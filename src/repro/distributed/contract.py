@@ -53,7 +53,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.notation import ContractionSpec, parse_spec
@@ -429,7 +429,7 @@ def sharded_contract(
         mesh=mesh,
         in_specs=(plan.a_spec, plan.b_spec),
         out_specs=plan.out_spec,
-        check_rep=False,
+        check_vma=False,
     )
     out = fn(jnp.asarray(A), jnp.asarray(B))
     return (out, plan) if return_plan else out

@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.contract import contract
@@ -159,7 +159,7 @@ def moe_ffn_a2a(cfg, params, x, mesh, *, strategy=None, backend=None):
         in_specs=(P(axes), P(None, None), P(axes), P(axes) if has_g else P(),
                   P(axes)),
         out_specs=P(axes),
-        check_rep=False,
+        check_vma=False,
     )
     xt = x.reshape(T, E)
     wg_in = wpad["wg"] if has_g else jnp.zeros((), dt)

@@ -19,7 +19,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 __all__ = ["pipeline_forward", "pipeline_loss"]
@@ -82,7 +82,7 @@ def pipeline_forward(
         per_stage, mesh=mesh,
         in_specs=(spec_params, P()),       # x replicated; stages slice params
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(stage_params, x)
 

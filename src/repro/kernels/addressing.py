@@ -37,12 +37,23 @@ __all__ = [
     "block_index_map",
     "tile_element_offsets",
     "native_mode_tiles",
+    "legal_mode_tiles",
+    "role_mode_tiles",
+    "kernel_extents",
+    "LANE",
+    "SUBLANE",
 ]
 
 #: role → tile size.  u/v are the GEMM free modes (v is C's minor-most mode
 #: → lane axis: 128 wide), k the contracted mode (128 for the MXU), b the
-#: batch walk (1 = classic sb_gemm; >1 = a 3D brick per load).
+#: batch walk (1 = classic sb_gemm; >1 = a 3D brick per load).  Every
+#: tile is then raised to the TPU block rule by :func:`legal_mode_tiles`.
 DEFAULT_TILES = {"u": 128, "v": 128, "k": 128, "b": 1}
+
+#: TPU block rule: a block's last axis is a multiple of the lane width and
+#: its second-to-last a multiple of the sublane count — or the block spans
+#: the whole array along that axis.
+LANE, SUBLANE = 128, 8
 
 #: tile for contracted modes beyond the primary k (multi-mode k-groups
 #: that could not be fused into one view).  Sublane-depth: deep enough
@@ -167,7 +178,9 @@ def native_mode_tiles(
     * the largest contracted mode → the ``k`` tile; further contracted
       modes (unfused multi-k groups) get :data:`NATIVE_EXTRA_K_TILE`;
     * every other output mode walks at the ``b`` tile (the batch brick —
-      1 by default, >1 stages a 3D brick per load).
+      1 by default, >1 stages a 3D brick per load);
+    * each tile is then raised to the TPU block rule
+      (:func:`legal_mode_tiles`).
 
     Unlike :func:`repro.kernels.ops.plan_roles` this never fails: there
     is no layout precondition to satisfy, because the kernel addresses
@@ -189,4 +202,72 @@ def native_mode_tiles(
         mode_tiles.setdefault(m, NATIVE_EXTRA_K_TILE)
     for m in rest_c:
         mode_tiles.setdefault(m, role["b"])
-    return mode_tiles
+    return legal_mode_tiles(a_modes, b_modes, c_modes, dims, mode_tiles)
+
+
+def role_mode_tiles(
+    a_modes: str, b_modes: str, c_modes: str, dims: dict, roles: dict,
+    tiles: dict | None = None,
+) -> dict:
+    """Per-mode tile table of a role-based (planner) core: each mode takes
+    its role's tile (``tiles`` merged over :data:`DEFAULT_TILES`), raised
+    to the block rule by :func:`legal_mode_tiles`."""
+    role = {**DEFAULT_TILES, **(tiles or {})}
+    return legal_mode_tiles(
+        a_modes, b_modes, c_modes, dims, {m: role[roles[m]] for m in dims})
+
+
+def legal_mode_tiles(
+    a_modes: str, b_modes: str, c_modes: str, dims: dict, mode_tiles: dict
+) -> dict:
+    """Raise each mode's tile to the TPU block rule.
+
+    A mode on the last axis of A, B or C takes a tile that is a multiple
+    of :data:`LANE`, whatever its dim: a block narrower than the tile
+    spans the whole axis, and a lane-padded mode (:func:`kernel_extents`)
+    is padded to the whole tile.  On a second-to-last axis the tile is a
+    multiple of :data:`SUBLANE`, or at least the mode's dim.  Tiles are
+    rounded *up*, so a batch mode that lands on an operand's lane axis
+    walks a 128-deep brick instead of a 1-deep slice the compiler refuses.
+    """
+    lane = {modes[-1] for modes in (a_modes, b_modes, c_modes) if modes}
+    sublane = {modes[-2] for modes in (a_modes, b_modes, c_modes)
+               if len(modes) >= 2}
+    out = {}
+    for m, t in mode_tiles.items():
+        if m in lane:
+            t = -(-t // LANE) * LANE
+        elif m in sublane and t < dims[m]:
+            t = -(-t // SUBLANE) * SUBLANE
+        out[m] = t
+    return out
+
+
+def _lane_padded_modes(a_modes, b_modes, c_modes, dims, mode_tiles) -> set:
+    """Lane modes of blocks with three or more non-unit axes.
+
+    The in-kernel product folds such a block's axes into a matrix and
+    unfolds them again, and the TPU compiler cannot split a lane axis
+    that is not lane-aligned: these modes are padded to their whole
+    (lane-multiple) tile, even where the mode is narrower.
+    """
+    out = set()
+    for modes in (a_modes, b_modes, c_modes):
+        if sum(effective_tile(dims[m], mode_tiles[m]) > 1 for m in modes) >= 3:
+            out.add(modes[-1])
+    return out
+
+
+def kernel_extents(
+    a_modes: str, b_modes: str, c_modes: str, dims: dict, mode_tiles: dict
+) -> dict:
+    """Extent each mode is padded to before the kernel runs, under the
+    tiles of :func:`legal_mode_tiles`: a tile multiple
+    (:func:`padded_extent`), and for a lane-padded mode (see
+    :func:`_lane_padded_modes`) a whole tile even where the mode is
+    narrower.  The one place lane padding is decided: the kernel's blocks
+    follow from these extents and the same tiles."""
+    ext = {m: padded_extent(d, mode_tiles[m]) for m, d in dims.items()}
+    for m in _lane_padded_modes(a_modes, b_modes, c_modes, dims, mode_tiles):
+        ext[m] = -(-dims[m] // mode_tiles[m]) * mode_tiles[m]
+    return ext

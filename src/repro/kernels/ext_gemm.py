@@ -8,7 +8,7 @@ that stages a 3D brick — ``(u_tile, k_tile, batch_tile)`` in the operand's
 native axis order — in VMEM, contracts it slice-wise on the MXU against a
 2D-tiled operand, and writes regular C tiles.
 
-Mechanically this is :func:`repro.kernels.sb_gemm.sb_gemm_pallas` with
+Mechanically this is :func:`repro.kernels.ops.sb_contract` with
 ``tiles["b"] > 1`` (the brick depth); this module provides the explicitly
 named entry point and the brick-depth default used by ``ops.execute_plan``.
 
@@ -31,7 +31,7 @@ __all__ = ["ext_gemm", "EXT_BATCH_TILE"]
 
 
 def ext_gemm(spec: str, A, B, *, batch_tile: int = EXT_BATCH_TILE,
-             out_dtype=None, interpret: bool = True):
+             out_dtype=None):
     """Evaluate an exceptional-case contraction with the 3D-brick kernel.
 
     ``spec`` must plan as exceptional (e.g. the row-major mirrors of
@@ -55,5 +55,4 @@ def ext_gemm(spec: str, A, B, *, batch_tile: int = EXT_BATCH_TILE,
     return sb_contract(
         plan.fspec.a_modes, plan.fspec.b_modes, plan.fspec.c_modes, A, B,
         roles=roles, tiles={"b": batch_tile}, out_dtype=out_dtype,
-        interpret=interpret,
     )

@@ -19,11 +19,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from repro.kernels.sb_gemm import interpret_mode
 
 __all__ = ["flash_attention", "DEFAULT_BLOCKS"]
 
@@ -76,14 +74,15 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         o_ref[0] = (acc_ref[...] / l[:, None]).astype(out_dtype)
 
 
-def flash_attention(q, k, v, *, causal: bool = True, blocks: dict | None = None,
-                    interpret: bool = True):
+def flash_attention(q, k, v, *, causal: bool = True, blocks: dict | None = None):
     """q: (BH, S, D); k/v: (BH, T, D) → (BH, S, D).
 
     GQA callers fold (batch, kv_head, q_per_kv) into BH and pass the kv
     head's K/V for each q head (broadcast view — XLA keeps it unmaterialized).
-    S, T, D padded to block multiples by the caller or here.
+    S, T, D padded to block multiples by the caller or here.  Interpreted
+    off-TPU (:func:`~repro.kernels.sb_gemm.interpret_mode`).
     """
+    interpret = interpret_mode(q, k, v)
     blocks = {**DEFAULT_BLOCKS, **(blocks or {})}
     BH, S, D = q.shape
     T = k.shape[1]
@@ -97,9 +96,6 @@ def flash_attention(q, k, v, *, causal: bool = True, blocks: dict | None = None,
     Sp, Tp = q.shape[1], k.shape[1]
     nq, nk = Sp // bq, Tp // bk
     scale = D**-0.5
-
-    if pltpu is None:
-        raise RuntimeError("pallas TPU backend unavailable")
 
     out = pl.pallas_call(
         functools.partial(

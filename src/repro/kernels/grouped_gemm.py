@@ -34,8 +34,9 @@ test.  Within a group the inner loops are exactly the paper's kernel:
 k-innermost accumulation into an f32 VMEM scratch tile, emitted on the
 group's last k step.
 
-As in :mod:`repro.kernels.sb_gemm`, ``interpret=True`` validates the
-kernel off-TPU.  On real TPUs the flat operands should be staged
+As in :mod:`repro.kernels.sb_gemm`, the kernel is interpreted off-TPU
+(:func:`~repro.kernels.sb_gemm.interpret_mode`).  On real TPUs the flat
+operands should be staged
 HBM→VMEM with explicit DMA (the descriptor-driven ``pl.ds`` loads below
 mark the tile fetches to convert); the descriptor table itself belongs in
 SMEM.
@@ -49,11 +50,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU compiler params are optional (interpret mode does not need them)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from repro.kernels.sb_gemm import interpret_mode
 
 __all__ = [
     "GROUPED_DEFAULT_TILES",
@@ -255,7 +254,6 @@ def grouped_gemm_pallas(
     out_cols: int,
     out_rows: int | None = None,
     out_dtype=None,
-    interpret: bool = True,
 ):
     """Single-launch grouped GEMM over packed operands.
 
@@ -267,6 +265,7 @@ def grouped_gemm_pallas(
     is only correct when no group stores A transposed).  Group ``g``
     occupies rows ``c_off .. c_off+m_p``, columns ``0 .. n_p``.
     """
+    interpret = interpret_mode(A_flat, B_flat)
     tiles = {**GROUPED_DEFAULT_TILES, **(tiles or {})}
     out_dtype = out_dtype or jnp.result_type(A_flat.dtype, B_flat.dtype)
     tu, tv, tk = tiles["u"], tiles["v"], tiles["k"]
@@ -276,17 +275,6 @@ def grouped_gemm_pallas(
         out_rows = int(A_flat.shape[0])
     out_shape = jax.ShapeDtypeStruct((out_rows, out_cols), out_dtype)
 
-    kwargs = {}
-    if pltpu is not None and not interpret:  # pragma: no cover (TPU only)
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-        )
-    scratch = (
-        pltpu.VMEM((tu, tv), jnp.float32)
-        if pltpu is not None
-        else jax.ShapeDtypeStruct((tu, tv), jnp.float32)
-    )
     return pl.pallas_call(
         functools.partial(
             _kernel, tu=tu, tv=tv, tk=tk, out_dtype=out_dtype,
@@ -296,9 +284,12 @@ def grouped_gemm_pallas(
         in_specs=[pl.BlockSpec(memory_space=None)] * 3,
         out_specs=pl.BlockSpec(memory_space=None),
         out_shape=out_shape,
-        scratch_shapes=[scratch],
+        scratch_shapes=[pltpu.VMEM((tu, tv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+        ),
         interpret=interpret,
-        **kwargs,
     )(descs, A_flat, B_flat)
 
 

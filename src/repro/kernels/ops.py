@@ -4,8 +4,9 @@
 ``repro.core.contract``: it pads operands to tile multiples (zero padding
 is exact for contractions), assigns mode→role for the kernel, lifts nested
 batch modes through ``jax.vmap`` (paper Listing 2's outer loops), and
-dispatches to :func:`sb_gemm_pallas` — with a 3D batch brick for the
-exceptional cases (the extended-transpose operation, see ``ext_gemm.py``).
+dispatches to :func:`native_gemm_pallas` with the role tiles mapped onto
+modes — a 3D batch brick for the exceptional cases (the
+extended-transpose operation, see ``ext_gemm.py``).
 
 ``execute_native(spec, A, B)`` is the layout-oblivious entry (the
 ``"native"`` strategy): no plan, no roles, no layout precondition — the
@@ -25,12 +26,10 @@ import jax.numpy as jnp
 from repro.core.notation import CaseKind, ContractionSpec, parse_spec
 from repro.core.planner import Plan
 from repro.obs import trace as _trace
-from repro.kernels.addressing import native_mode_tiles, padded_extent
-from repro.kernels.sb_gemm import (
-    DEFAULT_TILES,
-    native_gemm_pallas,
-    sb_gemm_pallas,
+from repro.kernels.addressing import (
+    LANE, kernel_extents, native_mode_tiles, padded_extent, role_mode_tiles,
 )
+from repro.kernels.sb_gemm import DEFAULT_TILES, native_gemm_pallas
 
 __all__ = [
     "execute_plan", "execute_native", "sb_contract", "plan_roles",
@@ -38,8 +37,10 @@ __all__ = [
 ]
 
 #: brick depth for the extended-transpose kernel (paper §III-E): how many
-#: stride-1-batched matrices are staged in VMEM per load.
-EXT_BATCH_TILE = 8
+#: stride-1-batched matrices are staged in VMEM per load.  The batch mode
+#: is the operand's stride-1 (lane) axis, so the block rule makes the
+#: brick one lane tile deep (or the whole mode, when shorter).
+EXT_BATCH_TILE = LANE
 
 
 def _pad_to(x, modes: str, targets: dict):
@@ -52,9 +53,6 @@ def _pad_to(x, modes: str, targets: dict):
 def padded_dim(d: int, tile: int) -> int:
     """Dim after padding to a tile multiple (dims ≤ one tile stay as-is)."""
     return padded_extent(d, tile)
-
-
-_padded_dim = padded_dim  # historical alias
 
 
 def plan_roles(plan: Plan) -> dict | None:
@@ -97,21 +95,21 @@ def sb_contract(
     roles: dict,
     tiles: dict | None = None,
     out_dtype=None,
-    interpret: bool = True,
 ):
-    """Pad → kernel → slice for a core contraction (no nested modes)."""
-    tiles = {**DEFAULT_TILES, **(tiles or {})}
+    """Pad → kernel → slice for a core contraction (no nested modes).
+    One tile table decides both the padding and the kernel's blocks."""
     out_dtype = out_dtype or jnp.result_type(A.dtype, B.dtype)
     dims = {}
     for modes, x in ((spec_a, A), (spec_b, B)):
         for m, d in zip(modes, x.shape):
             dims[m] = d
-    targets = {m: _padded_dim(d, tiles[roles[m]]) for m, d in dims.items()}
+    mode_tiles = role_mode_tiles(spec_a, spec_b, spec_c, dims, roles, tiles)
+    targets = kernel_extents(spec_a, spec_b, spec_c, dims, mode_tiles)
     A = _pad_to(A, spec_a, targets)
     B = _pad_to(B, spec_b, targets)
-    out = sb_gemm_pallas(
+    out = native_gemm_pallas(
         A, B, a_modes=spec_a, b_modes=spec_b, c_modes=spec_c,
-        roles=roles, tiles=tiles, out_dtype=out_dtype, interpret=interpret,
+        mode_tiles=mode_tiles, out_dtype=out_dtype,
     )
     slicer = tuple(slice(0, dims[m]) for m in spec_c)
     return out[slicer]
@@ -124,7 +122,6 @@ def execute_native(
     *,
     tiles: dict | None = None,
     out_dtype=None,
-    interpret: bool = True,
 ):
     """Layout-oblivious single-kernel contraction (the ``"native"`` strategy).
 
@@ -151,16 +148,14 @@ def execute_native(
     out_dtype = out_dtype or jnp.result_type(A.dtype, B.dtype)
     tile_items = None if tiles is None else tuple(sorted(tiles.items()))
     if not _trace.enabled():
-        return _native_diff(cs, tile_items, jnp.dtype(out_dtype), interpret,
-                            A, B)
+        return _native_diff(cs, tile_items, jnp.dtype(out_dtype), A, B)
     with _trace.span("execute_native", "kernels") as sp:
         sp.set(spec=cs.spec_str(),
                tiles=dict(tile_items) if tile_items else None)
-        return _native_diff(cs, tile_items, jnp.dtype(out_dtype), interpret,
-                            A, B)
+        return _native_diff(cs, tile_items, jnp.dtype(out_dtype), A, B)
 
 
-def _execute_native_impl(cs, A, B, *, tiles, out_dtype, interpret):
+def _execute_native_impl(cs, A, B, *, tiles, out_dtype):
     if not cs.c_modes or not cs.a_modes or not cs.b_modes:
         from repro.core.contract import _direct
 
@@ -170,38 +165,38 @@ def _execute_native_impl(cs, A, B, *, tiles, out_dtype, interpret):
         for m, d in zip(modes, x.shape):
             dims[m] = d
     mode_tiles = native_mode_tiles(cs.a_modes, cs.b_modes, cs.c_modes, dims, tiles)
-    targets = {m: padded_dim(d, mode_tiles[m]) for m, d in dims.items()}
+    targets = kernel_extents(cs.a_modes, cs.b_modes, cs.c_modes, dims,
+                             mode_tiles)
     A = _pad_to(A, cs.a_modes, targets)
     B = _pad_to(B, cs.b_modes, targets)
     out = native_gemm_pallas(
         A, B, a_modes=cs.a_modes, b_modes=cs.b_modes, c_modes=cs.c_modes,
-        mode_tiles=mode_tiles, out_dtype=out_dtype, interpret=interpret,
+        mode_tiles=mode_tiles, out_dtype=out_dtype,
     )
     return out[tuple(slice(0, dims[m]) for m in cs.c_modes)]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
-def _native_diff(cs, tile_items, out_dtype, interpret, A, B):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _native_diff(cs, tile_items, out_dtype, A, B):
     tiles = None if tile_items is None else dict(tile_items)
-    return _execute_native_impl(
-        cs, A, B, tiles=tiles, out_dtype=out_dtype, interpret=interpret)
+    return _execute_native_impl(cs, A, B, tiles=tiles, out_dtype=out_dtype)
 
 
-def _native_diff_fwd(cs, tile_items, out_dtype, interpret, A, B):
-    return _native_diff(cs, tile_items, out_dtype, interpret, A, B), (A, B)
+def _native_diff_fwd(cs, tile_items, out_dtype, A, B):
+    return _native_diff(cs, tile_items, out_dtype, A, B), (A, B)
 
 
-def _native_diff_bwd(cs, tile_items, out_dtype, interpret, res, g):
+def _native_diff_bwd(cs, tile_items, out_dtype, res, g):
     # Einsum-transpose rule.  Forward tiles are role assignments for the
     # forward spec's mode classes; the transposed specs reclassify, so
     # the backward kernels take the default tile grid.
     A, B = res
     dA = execute_native(
         ContractionSpec(cs.c_modes, cs.b_modes, cs.a_modes), g, B,
-        out_dtype=A.dtype, interpret=interpret)
+        out_dtype=A.dtype)
     dB = execute_native(
         ContractionSpec(cs.c_modes, cs.a_modes, cs.b_modes), g, A,
-        out_dtype=B.dtype, interpret=interpret)
+        out_dtype=B.dtype)
     return dA, dB
 
 
@@ -209,7 +204,7 @@ _native_diff.defvjp(_native_diff_fwd, _native_diff_bwd)
 
 
 def grouped_matmul(As, Bs, *, tiles: dict | None = None, out_dtype=None,
-                   interpret: bool = True, trans_a=False, trans_b=False):
+                   trans_a=False, trans_b=False):
     """Variable-batch GEMM: one kernel launch over ragged groups.
 
     ``As[g] (m_g, k_g) @ Bs[g] (k_g, n_g)`` for every group in a single
@@ -232,19 +227,18 @@ def grouped_matmul(As, Bs, *, tiles: dict | None = None, out_dtype=None,
     """
     if not _trace.enabled():
         return _grouped_matmul_impl(
-            As, Bs, tiles=tiles, out_dtype=out_dtype, interpret=interpret,
+            As, Bs, tiles=tiles, out_dtype=out_dtype,
             trans_a=trans_a, trans_b=trans_b,
         )
     with _trace.span("grouped_matmul", "kernels") as sp:
         sp.set(n_groups=len(As), tiles=tiles)
         return _grouped_matmul_impl(
-            As, Bs, tiles=tiles, out_dtype=out_dtype, interpret=interpret,
+            As, Bs, tiles=tiles, out_dtype=out_dtype,
             trans_a=trans_a, trans_b=trans_b,
         )
 
 
-def _grouped_matmul_impl(As, Bs, *, tiles, out_dtype, interpret,
-                         trans_a, trans_b):
+def _grouped_matmul_impl(As, Bs, *, tiles, out_dtype, trans_a, trans_b):
     from repro.kernels.grouped_gemm import (
         GROUPED_DEFAULT_TILES, grouped_gemm_pallas, pack_groups,
     )
@@ -273,7 +267,7 @@ def _grouped_matmul_impl(As, Bs, *, tiles, out_dtype, interpret,
     out = grouped_gemm_pallas(
         A_flat, B_flat, descs,
         grid_dims=(mp_max, np_max, kp_max), tiles=eff, out_cols=out_cols,
-        out_rows=out_rows, out_dtype=out_dtype, interpret=interpret,
+        out_rows=out_rows, out_dtype=out_dtype,
     )
     results, row = [], 0
     for p in problems:
@@ -282,7 +276,7 @@ def _grouped_matmul_impl(As, Bs, *, tiles, out_dtype, interpret,
     return results
 
 
-def execute_plan(plan: Plan, A, B, *, out_dtype=None, interpret: bool = True,
+def execute_plan(plan: Plan, A, B, *, out_dtype=None,
                  tiles: dict | None = None):
     """Pallas-backend execution of a planner :class:`Plan`.
 
@@ -292,17 +286,15 @@ def execute_plan(plan: Plan, A, B, *, out_dtype=None, interpret: bool = True,
     knob, also reachable from the public API via ``contract(..., tiles=...)``.
     """
     if not _trace.enabled():
-        return _execute_plan_impl(
-            plan, A, B, out_dtype=out_dtype, interpret=interpret, tiles=tiles)
+        return _execute_plan_impl(plan, A, B, out_dtype=out_dtype, tiles=tiles)
     with _trace.span("execute_plan", "kernels") as sp:
         sp.set(spec=plan.spec.spec_str(), kind=plan.kind,
                nested=plan.nested or None, tiles=tiles,
                has_roles=plan_roles(plan) is not None)
-        return _execute_plan_impl(
-            plan, A, B, out_dtype=out_dtype, interpret=interpret, tiles=tiles)
+        return _execute_plan_impl(plan, A, B, out_dtype=out_dtype, tiles=tiles)
 
 
-def _execute_plan_impl(plan: Plan, A, B, *, out_dtype, interpret, tiles):
+def _execute_plan_impl(plan: Plan, A, B, *, out_dtype, tiles):
     fs, fd = plan.fspec, plan.fdims
     out_dtype = out_dtype or jnp.result_type(A.dtype, B.dtype)
 
@@ -313,10 +305,7 @@ def _execute_plan_impl(plan: Plan, A, B, *, out_dtype, interpret, tiles):
         # The native-layout kernel needs neither: every mode gets its own
         # grid axis, so the raw spec runs as-is (no permute, no copy, no
         # XLA fallback).
-        return execute_native(
-            plan.spec, A, B, tiles=tiles, out_dtype=out_dtype,
-            interpret=interpret,
-        )
+        return execute_native(plan.spec, A, B, tiles=tiles, out_dtype=out_dtype)
 
     # flattening reshapes are views (adjacent modes, packed layout)
     if plan.spec.a_modes != fs.a_modes:
@@ -334,7 +323,7 @@ def _execute_plan_impl(plan: Plan, A, B, *, out_dtype, interpret, tiles):
     def core(a, b, a_modes, b_modes, c_modes):
         return sb_contract(
             a_modes, b_modes, c_modes, a, b,
-            roles=roles, tiles=tiles, out_dtype=out_dtype, interpret=interpret,
+            roles=roles, tiles=tiles, out_dtype=out_dtype,
         )
 
     # nested batch modes → vmap at native positions (Listing 2 outer loops)

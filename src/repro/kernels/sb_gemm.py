@@ -19,12 +19,12 @@ or copy.  "Transposition" happens on the MXU: the tile contraction is a
 ``jnp.einsum`` over VMEM tiles (→ ``dot_general`` with arbitrary
 dimension numbers), the TPU analogue of GEMM's ``op`` flags.
 
-:func:`sb_gemm_pallas` is the role-based entry the planner drives: it
-maps the classic ``u``/``v``/``k``/``b`` role tiles onto modes and calls
-the native kernel.  The paper's *extended transpose* (§III-E) falls out
-as the configuration ``tiles["b"] > 1`` — a 3D VMEM brick of the operand
-whose stride-1 axis carries the batch ("3D tiling of B into cache") —
-see ``ext_gemm.py``.
+The planner drives the same kernel through ``ops.sb_contract``, which
+maps the classic ``u``/``v``/``k``/``b`` role tiles onto modes
+(:func:`~repro.kernels.addressing.role_mode_tiles`).  The paper's
+*extended transpose* (§III-E) falls out as the configuration
+``tiles["b"] > 1`` — a 3D VMEM brick of the operand whose stride-1 axis
+carries the batch ("3D tiling of B into cache") — see ``ext_gemm.py``.
 
 Partial products accumulate in an f32 VMEM scratch tile and are emitted
 on the last contracted step (MXU-friendly: tiles padded to multiples of
@@ -38,6 +38,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.addressing import (
     DEFAULT_TILES,
@@ -45,12 +46,42 @@ from repro.kernels.addressing import (
     effective_tile,
 )
 
-try:  # TPU compiler params are optional (interpret mode does not need them)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+__all__ = [
+    "native_gemm_pallas", "DEFAULT_TILES",
+    "VMEM_LIMIT_BYTES", "block_vmem_bytes", "interpret_mode",
+]
 
-__all__ = ["native_gemm_pallas", "sb_gemm_pallas", "DEFAULT_TILES"]
+#: scoped VMEM each kernel asks the compiler for: half of a TPU v5e
+#: TensorCore's 128 MiB.  The compiler's default scope (16 MiB on v5e)
+#: cannot hold the 128-deep brick an exceptional layout forces.
+VMEM_LIMIT_BYTES = 64 * 2**20
+
+
+def interpret_mode(*arrays) -> bool:
+    """Whether Pallas kernels over ``arrays`` run in the interpreter.
+
+    The one place this is decided: kernels compile on a TPU and are
+    interpreted on any other platform.  A concrete array says where it
+    lives; traced values run on the default backend.
+    """
+    for x in arrays:
+        if isinstance(x, jax.Array) and not isinstance(x, jax.core.Tracer):
+            return next(iter(x.devices())).platform != "tpu"
+    return jax.default_backend() != "tpu"
+
+
+def block_vmem_bytes(a_elems: int, b_elems: int, c_elems: int, in_dtype,
+                     out_dtype, *, accumulate: bool) -> int:
+    """VMEM one grid step of the native kernel holds.
+
+    A, B and C blocks are double-buffered by the pipeline; the f32 tile
+    product is a temporary, and a contracted grid axis adds the f32
+    accumulator scratch.
+    """
+    f32_tiles = 2 if accumulate else 1
+    return (2 * (a_elems + b_elems) * jnp.dtype(in_dtype).itemsize
+            + 2 * c_elems * jnp.dtype(out_dtype).itemsize
+            + f32_tiles * c_elems * 4)
 
 
 def _kernel(a_ref, b_ref, o_ref, acc_ref, *, tile_spec: str,
@@ -93,7 +124,7 @@ def native_gemm_pallas(
     c_modes: str,
     mode_tiles: dict,
     out_dtype=None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Single-call contraction of ``A`` and ``B`` in their native layouts.
 
@@ -106,9 +137,11 @@ def native_gemm_pallas(
     both operands must have rank ≥ 1; ``ops.execute_native`` routes the
     scalar edge cases to the direct path instead.
 
-    ``interpret=True`` runs the kernel body on CPU for validation; on
-    real TPUs pass ``interpret=False``.
+    ``interpret`` defaults to :func:`interpret_mode` of the operands;
+    pass ``False`` to compile for a TPU that is described, not attached.
     """
+    if interpret is None:
+        interpret = interpret_mode(A, B)
     out_dtype = out_dtype or jnp.result_type(A.dtype, B.dtype)
     dims: dict = {}
     for modes, x in ((a_modes, A), (b_modes, B)):
@@ -134,19 +167,6 @@ def native_gemm_pallas(
     out_shape = jax.ShapeDtypeStruct(tuple(dims[m] for m in c_modes), out_dtype)
     tile_spec = f"{a_modes},{b_modes}->{c_modes}"
 
-    kwargs = {}
-    if pltpu is not None and not interpret:  # pragma: no cover (TPU only)
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=(
-                ("parallel",) * len(c_modes) + ("arbitrary",) * len(k_axes)
-            ),
-        )
-
-    scratch = (
-        pltpu.VMEM(c_block, jnp.float32)
-        if pltpu is not None
-        else jax.ShapeDtypeStruct(c_block, jnp.float32)
-    )
     return pl.pallas_call(
         functools.partial(_kernel, tile_spec=tile_spec, k_axes=k_axes,
                           out_dtype=out_dtype,
@@ -155,42 +175,12 @@ def native_gemm_pallas(
         in_specs=[a_spec, b_spec],
         out_specs=c_spec,
         out_shape=out_shape,
-        scratch_shapes=[scratch],
+        scratch_shapes=[pltpu.VMEM(c_block, jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(
+                ("parallel",) * len(c_modes) + ("arbitrary",) * len(k_axes)
+            ),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
         interpret=interpret,
-        **kwargs,
     )(A, B)
-
-
-def sb_gemm_pallas(
-    A,
-    B,
-    *,
-    a_modes: str,
-    b_modes: str,
-    c_modes: str,
-    roles: dict,
-    tiles: dict | None = None,
-    out_dtype=None,
-    interpret: bool = True,
-):
-    """Single-call strided-batched contraction of ``A`` and ``B``.
-
-    ``a_modes/b_modes/c_modes`` are the *core* mode strings (one optional
-    batch mode ``b``, GEMM modes ``u``/``v``, contracted mode ``k`` — as
-    assigned by ``roles: {mode: role}``).  All mode dims must already be
-    padded to multiples of the role tiles (``ops.py`` does this).
-
-    This is the planner-facing veneer over :func:`native_gemm_pallas`:
-    the role table is just a per-mode tile assignment, and the native
-    kernel's per-mode grid subsumes the classic ``(b, u, v, k)`` one.
-    """
-    tiles = {**DEFAULT_TILES, **(tiles or {})}
-    dims: dict = {}
-    for modes, x in ((a_modes, A), (b_modes, B)):
-        for m, d in zip(modes, x.shape):
-            dims[m] = d
-    mode_tiles = {m: tiles[roles[m]] for m in dims}
-    return native_gemm_pallas(
-        A, B, a_modes=a_modes, b_modes=b_modes, c_modes=c_modes,
-        mode_tiles=mode_tiles, out_dtype=out_dtype, interpret=interpret,
-    )

@@ -25,10 +25,36 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.configs.base import ModelConfig
 from repro.launch.mesh import make_host_mesh, parse_mesh_shape
-from repro.models.transformer import Model
+from repro.models.transformer import init_params
 from repro.runtime.engine import ServingRuntime
 from repro.serving.engine import Request, ServeEngine
+from repro.utils import place_compile_cache
+
+__all__ = ["init_serving_params", "main"]
+
+
+def init_serving_params(cfg: ModelConfig, *, seed: int = 0, mesh=None):
+    """Random serving weights, made under ``jit`` directly where they live.
+
+    Weights take the activation dtype (bf16 for the published configs;
+    the f32 master copy is a training concern).  On a ``mesh`` every
+    leaf is created in the sharding the runtime serves it with (the
+    model zoo's logical-axis rules), so no device ever holds the whole
+    model; without one they are made on the default device.
+    """
+    cfg = cfg.with_(param_dtype=cfg.dtype)
+    key = jax.random.PRNGKey(seed)
+    shardings = None
+    if mesh is not None:
+        from repro.distributed.sharding import ShardingRules
+        from repro.launch.shardings import param_logical_axes, tree_shardings
+
+        shapes = jax.eval_shape(lambda k: init_params(k, cfg), key)
+        shardings = tree_shardings(
+            ShardingRules(mesh), param_logical_axes(shapes), shapes)
+    return jax.jit(lambda k: init_params(k, cfg), out_shardings=shardings)(key)
 
 
 def main():
@@ -119,6 +145,7 @@ def main():
                          "re-measure drifted keys and refit the cost "
                          "model past the drift gate (enables tracing)")
     args = ap.parse_args()
+    place_compile_cache()
     if args.paged and args.legacy:
         ap.error("--paged serves through the runtime; drop --legacy")
     want_health = bool(args.metrics_jsonl or args.metrics_prom
@@ -141,8 +168,7 @@ def main():
               f"{jax.default_backend()} devices")
 
     cfg = get_config(args.arch, smoke=args.smoke)
-    model = Model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    params = init_serving_params(cfg, seed=0, mesh=mesh)
 
     tuner = None
     if args.cache_imports:

@@ -35,6 +35,7 @@ from repro.models.transformer import Model
 from repro.training.data import SyntheticLM
 from repro.training.optimizer import AdamWConfig
 from repro.training.trainer import TrainConfig, Trainer
+from repro.utils import place_compile_cache
 
 
 def main():
@@ -56,6 +57,7 @@ def main():
     args = ap.parse_args()
 
     logging.basicConfig(level=logging.INFO)
+    place_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     # the WSD schedule is minicpm's training preset (its paper contribution)
     schedule = args.schedule or ("wsd" if args.arch.startswith("minicpm") else "cosine")

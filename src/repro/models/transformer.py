@@ -93,14 +93,14 @@ def init_params(key, cfg: ModelConfig):
             _init_block(k, cfg, s)
             for k, s in zip(jax.random.split(keys[4], max(len(cfg.prefix), 1)), cfg.prefix)
         ]
-    # pattern params stacked over periods: tree of (n_periods, ...) leaves
+    # pattern params stacked over periods: tree of (n_periods, ...) leaves,
+    # made stacked (vmap over the period keys) so no per-period copy exists
     def one_period(k):
         ks = jax.random.split(k, len(cfg.pattern))
         return [_init_block(kk, cfg, s) for kk, s in zip(ks, cfg.pattern)]
 
     period_keys = jax.random.split(keys[5], cfg.n_periods)
-    periods = [one_period(k) for k in period_keys]
-    params["pattern"] = jax.tree.map(lambda *xs: jnp.stack(xs), *periods)
+    params["pattern"] = jax.vmap(one_period)(period_keys)
     return params
 
 

@@ -18,9 +18,18 @@ analysis in record form:
 * ``roofline_bound_us`` — ``max(flops/PEAK_FLOPS, bytes/HBM_BW)``: the
   time the roofline says this contraction cannot beat.
 
+The ceilings are published per-chip peaks keyed by JAX ``device_kind``
+(:data:`PEAKS`); analytic bounds are stated for the target chip
+(:data:`TARGET_KIND`, a TPU v5e).  A *measured* share — a bound over a
+time taken on a device — is only reported for a device with published
+peaks: an accelerator of unknown kind raises (:func:`peaks`), and a CPU
+reports none (:func:`device_peaks`), since a host timing is no device
+time.
+
 A span carrying ``roofline_bound_us`` gains ``roofline_fraction`` (bound
-÷ measured duration) when it closes (see :class:`repro.obs.trace.Tracer`)
-— ~1.0 means roofline-saturating, ≪1 means overhead or a wrong strategy.
+÷ measured duration) when it closes on such a device (see
+:class:`repro.obs.trace.Tracer`) — ~1.0 means roofline-saturating, ≪1
+means overhead or a wrong strategy.
 Host-measured durations of *jit-traced* calls are trace time, not run
 time; emitters flag those spans ``eager=False``.  The autotuner's cache
 hits instead carry *measured* kernel time, giving the trustworthy
@@ -29,22 +38,65 @@ fraction (:func:`measured_fraction`).
 
 from __future__ import annotations
 
+import dataclasses
+
+import jax
 import numpy as np
 
 __all__ = [
-    "PEAK_FLOPS", "HBM_BW", "LINK_BW",
+    "PEAKS", "Peaks", "TARGET_KIND", "PEAK_FLOPS", "HBM_BW", "LINK_BW",
+    "peaks", "device_peaks",
     "roofline_bound_us", "arithmetic_intensity",
     "contraction_record", "measured_fraction",
 ]
 
-PEAK_FLOPS = 197e12      # bf16 per chip (TPU v5e)
-HBM_BW = 819e9           # bytes/s per chip
-LINK_BW = 50e9           # bytes/s per ICI link
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    flops: float      # bf16 FLOP/s per chip
+    hbm_bw: float     # HBM bytes/s per chip
+    link_bw: float    # bytes/s per chip-to-chip link
 
 
-def roofline_bound_us(flops: float, bytes_: float) -> float:
-    """Minimum achievable µs under the compute and memory ceilings."""
-    return max(flops / PEAK_FLOPS, bytes_ / HBM_BW) * 1e6
+#: Published per-chip peaks, keyed by JAX ``device_kind``.
+#: TPU v5e ("TPU v5 lite"): Google Cloud documentation, "TPU v5e" —
+#: 197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of
+#: chip-to-chip interconnect over 4 links (50 GB/s each).
+PEAKS = {"TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, link_bw=50e9)}
+
+#: the chip analytic bounds are stated for
+TARGET_KIND = "TPU v5 lite"
+PEAK_FLOPS = PEAKS[TARGET_KIND].flops
+HBM_BW = PEAKS[TARGET_KIND].hbm_bw
+LINK_BW = PEAKS[TARGET_KIND].link_bw
+
+
+def peaks(device_kind: str) -> Peaks:
+    """Published peaks of ``device_kind``; an unknown kind raises
+    ``KeyError`` — there is no default chip."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(PEAKS)})") from None
+
+
+def device_peaks(device=None) -> Peaks | None:
+    """Peaks of ``device`` (default: the first device), the chip a
+    measured time was taken on.  ``None`` on a CPU, whose host timings
+    are no device share; raises for an accelerator of unknown kind."""
+    device = device or jax.devices()[0]
+    if device.platform == "cpu":
+        return None
+    return peaks(device.device_kind)
+
+
+def roofline_bound_us(flops: float, bytes_: float,
+                      p: Peaks = PEAKS[TARGET_KIND]) -> float:
+    """Minimum achievable µs under the compute and memory ceilings of
+    ``p`` (default: the target chip)."""
+    return max(flops / p.flops, bytes_ / p.hbm_bw) * 1e6
 
 
 def arithmetic_intensity(flops: float, bytes_: float) -> float:
@@ -52,11 +104,13 @@ def arithmetic_intensity(flops: float, bytes_: float) -> float:
     return flops / bytes_ if bytes_ else 0.0
 
 
-def measured_fraction(flops: float, bytes_: float, measured_us: float) -> float:
-    """Achieved fraction of roofline from a *measured* kernel time."""
+def measured_fraction(flops: float, bytes_: float, measured_us: float,
+                      p: Peaks) -> float:
+    """Achieved fraction of roofline from a kernel time measured on a
+    chip with peaks ``p`` (:func:`device_peaks`)."""
     if measured_us <= 0:
         return 0.0
-    return roofline_bound_us(flops, bytes_) / measured_us
+    return roofline_bound_us(flops, bytes_, p) / measured_us
 
 
 def contraction_record(cs, dims: dict, dtype) -> dict:

@@ -48,6 +48,8 @@ from __future__ import annotations
 
 import time
 
+from repro.obs import roofline
+
 __all__ = [
     "Tracer",
     "Span",
@@ -121,6 +123,8 @@ class _NullSpan:
 #: the singleton every disabled-mode ``span()`` call returns.
 NULL_SPAN = _NullSpan()
 
+_UNSET = object()
+
 
 class Tracer:
     """Ring-buffered span recorder with an injectable monotonic clock.
@@ -130,6 +134,10 @@ class Tracer:
         oldest events (``dropped`` counts them).
       clock: a monotonic ``() -> float`` seconds callable
         (default ``time.perf_counter``); injectable for tests.
+
+    Spans report a ``roofline_fraction`` only where
+    :func:`repro.obs.roofline.device_peaks` gives the device's peaks: a
+    CPU reports none, an accelerator of unknown kind raises.
     """
 
     def __init__(self, capacity: int = 65536, clock=time.perf_counter):
@@ -137,6 +145,7 @@ class Tracer:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.capacity = int(capacity)
         self.clock = clock
+        self._peaks = _UNSET         # device peaks, looked up on first use
         self._epoch = clock()
         self._ring: list[dict] = []
         self._total = 0              # events ever recorded
@@ -173,7 +182,8 @@ class Tracer:
                 break
         dur = max(end - sp.ts, 0.0)
         bound = sp.attrs.get("roofline_bound_us")
-        if bound is not None and "roofline_fraction" not in sp.attrs:
+        if (bound is not None and "roofline_fraction" not in sp.attrs
+                and self._reports_shares()):
             sp.attrs["roofline_fraction"] = (
                 float(bound) / dur if dur > 0 else 0.0
             )
@@ -181,6 +191,12 @@ class Tracer:
             "ph": PH_SPAN, "name": sp.name, "cat": sp.cat,
             "ts": sp.ts, "dur": dur, "depth": sp.depth, "args": sp.attrs,
         })
+
+    def _reports_shares(self) -> bool:
+        """Whether span durations are device times with published peaks."""
+        if self._peaks is _UNSET:
+            self._peaks = roofline.device_peaks()
+        return self._peaks is not None
 
     def _record(self, ev: dict) -> None:
         ev["seq"] = self._total

@@ -176,26 +176,13 @@ class ServingRuntime:
                 lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params
             )
             p_sh = tree_shardings(self._rules, param_logical_axes(p_spec), p_spec)
+            # a no-op for params already made in these shardings
+            # (repro.launch.serve.init_serving_params)
             self.params = jax.device_put(params, p_sh)
         if self.paged:
             self.cache = None        # KV lives in self.kv.pool
         else:
-            # slot-stacked cache: every leaf gains a leading (slots,)
-            # axis, so each slot keeps an independent length/KV state.
-            one = init_cache(cfg, 1, max_len)
-            self.cache = jax.tree.map(
-                lambda x: jnp.zeros((slots,) + x.shape, x.dtype), one
-            )
-        if mesh is not None:
-            from repro.launch.shardings import cache_logical_axes, tree_shardings
-
-            c_spec = jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), self.cache
-            )
-            c_sh = tree_shardings(
-                self._rules, cache_logical_axes(self.cache), c_spec
-            )
-            self.cache = jax.device_put(self.cache, c_sh)
+            self.cache = self._slot_cache()
         self._tokens = np.zeros((slots, 1, 1), np.int32)
         self._decode_vmapped = jax.vmap(
             lambda p, c, t: decode_step(cfg, p, c, t), in_axes=(None, 0, 0)
@@ -225,6 +212,26 @@ class ServingRuntime:
                 self.tuner.reset_counters()
 
     # --------------------------------------------------------------- helpers
+    def _slot_cache(self):
+        """The slot-stacked cache: every leaf gains a leading (slots,)
+        axis, so each slot keeps an independent length/KV state.  Made
+        under ``jit`` directly in its sharding on a mesh."""
+        cfg, slots, max_len = self.cfg, self.slots, self.max_len
+
+        def make():
+            one = init_cache(cfg, 1, max_len)
+            return jax.tree.map(
+                lambda x: jnp.zeros((slots,) + x.shape, x.dtype), one)
+
+        shardings = None
+        if self.mesh is not None:
+            from repro.launch.shardings import cache_logical_axes, tree_shardings
+
+            c_spec = jax.eval_shape(make)
+            shardings = tree_shardings(
+                self._rules, cache_logical_axes(c_spec), c_spec)
+        return jax.jit(make, out_shardings=shardings)()
+
     @contextlib.contextmanager
     def _mesh_ctx(self):
         """Mesh + logical-sharding-rules context for model steps (no-op

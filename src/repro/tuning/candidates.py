@@ -21,29 +21,34 @@ import jax.numpy as jnp
 
 from repro.core.notation import CaseKind, ContractionSpec, parse_spec
 from repro.core.planner import Plan, make_plan
-from repro.kernels.addressing import effective_tile, native_mode_tiles
-from repro.kernels.ops import EXT_BATCH_TILE, padded_dim, plan_roles
-from repro.kernels.sb_gemm import DEFAULT_TILES
+from repro.kernels.addressing import (
+    effective_tile, kernel_extents, native_mode_tiles, role_mode_tiles,
+)
+from repro.kernels.ops import EXT_BATCH_TILE, plan_roles
+from repro.kernels.sb_gemm import (
+    DEFAULT_TILES, VMEM_LIMIT_BYTES, block_vmem_bytes,
+)
 
 __all__ = [
     "Candidate",
     "enumerate_candidates",
     "enumerate_grouped_candidates",
     "validate_tiles",
+    "validate_plan_tiles",
     "validate_native_tiles",
     "estimate_vmem_bytes",
     "estimate_native_vmem_bytes",
     "estimate_grouped_vmem_bytes",
     "VMEM_BUDGET_BYTES",
     "PALLAS_TILE_GRID",
-    "EXT_BRICK_GRID",
     "GROUPED_TILE_GRID",
 ]
 
-#: per-candidate VMEM budget for the (A, B, C, f32 accumulator) blocks.
-#: TPU cores have ~16 MiB of VMEM; half is left for double-buffering and
-#: compiler scratch, matching the sizing guidance in the Pallas guide.
-VMEM_BUDGET_BYTES = 8 * 2**20
+#: per-candidate VMEM budget: the scoped limit every kernel asks the
+#: compiler for, against the footprint of
+#: :func:`~repro.kernels.sb_gemm.block_vmem_bytes` (double-buffered
+#: blocks plus f32 product and accumulator).
+VMEM_BUDGET_BYTES = VMEM_LIMIT_BYTES
 
 #: the Pallas tile-config grid: overrides merged over ``DEFAULT_TILES``.
 #: Deliberately small — the measurement harness multiplies it by the
@@ -56,10 +61,6 @@ PALLAS_TILE_GRID = (
     {"u": 64, "k": 64},
     {"u": 512, "k": 64},
 )
-
-#: brick depths tried for exceptional plans (the extended-transpose 3D
-#: tile of the stride-1-batched operand, paper §III-E).
-EXT_BRICK_GRID = (4, EXT_BATCH_TILE, 16)
 
 #: tile grid for the grouped (variable-batch) kernel: overrides merged
 #: over :data:`~repro.kernels.grouped_gemm.GROUPED_DEFAULT_TILES`.  The
@@ -138,15 +139,18 @@ def validate_tiles(tiles: dict) -> None:
     Rules: keys must be kernel roles (``u``/``v``/``k``/``b``); values
     positive ints; ``u``/``v``/``k`` multiples of 8 (the TPU sublane
     granularity — non-divisible tiles force masked partial lanes the MXU
-    loader rejects); and the implied VMEM working set (A, B, C blocks plus
-    the f32 accumulator, conservatively at the requested — unclamped —
-    tile sizes) must fit :data:`VMEM_BUDGET_BYTES`.
+    loader rejects); and the implied VMEM footprint
+    (:func:`~repro.kernels.sb_gemm.block_vmem_bytes` in f32,
+    conservatively at the requested — unclamped — tile sizes) must fit
+    :data:`VMEM_BUDGET_BYTES`.
     """
     _check_tile_values(tiles)
     full = {**DEFAULT_TILES, **tiles}
     u, v, k, b = (full[r] for r in _ROLE_NAMES)
-    # worst-case blocks: A=(b,u,k), B=(b,k,v), C=(b,u,v) + f32 accumulator
-    bytes_needed = b * (u * k + k * v + u * v) * 4 + b * u * v * 4
+    # worst-case blocks: A=(b,u,k), B=(b,k,v), C=(b,u,v)
+    bytes_needed = block_vmem_bytes(
+        b * u * k, b * k * v, b * u * v, jnp.float32, jnp.float32,
+        accumulate=True)
     if bytes_needed > VMEM_BUDGET_BYTES:
         raise ValueError(
             f"tiles {full} are oversized: ~{bytes_needed / 2**20:.1f} MiB of VMEM "
@@ -154,30 +158,68 @@ def validate_tiles(tiles: dict) -> None:
         )
 
 
+def validate_plan_tiles(plan: Plan, tiles: dict, dtype) -> None:
+    """Validate a tile override for a Pallas ``plan``; raises ``ValueError``.
+
+    The gate ``contract(tiles=...)`` and the candidate enumeration share:
+    :func:`validate_tiles` on the requested tiles (an exceptional plan at
+    the kernel's brick depth unless ``b`` is given), then the footprint
+    of the blocks the kernel actually runs, after the block rule raised
+    them (:func:`estimate_vmem_bytes`).  A plan with no role assignment
+    runs the native kernel, and takes its check.
+    """
+    checked = dict(tiles)
+    if plan.kind == CaseKind.EXCEPTIONAL and "b" not in checked:
+        checked["b"] = EXT_BATCH_TILE
+    validate_tiles(checked)
+    roles = plan_roles(plan)
+    if roles is None:
+        validate_native_tiles(plan.spec, plan.dims, tiles, dtype=dtype)
+        return
+    bytes_needed = estimate_vmem_bytes(
+        plan, roles, {**DEFAULT_TILES, **checked}, dtype)
+    if bytes_needed > VMEM_BUDGET_BYTES:
+        raise ValueError(
+            f"tiles {checked} are oversized for {plan.spec.spec_str()} as "
+            f"the kernel runs them: ~{bytes_needed / 2**20:.1f} MiB of VMEM "
+            f"blocks exceeds the {VMEM_BUDGET_BYTES / 2**20:.0f} MiB budget"
+        )
+
+
 def estimate_vmem_bytes(plan: Plan, roles: dict, tiles: dict, dtype) -> int:
     """VMEM bytes for one grid step of ``plan`` under ``tiles``.
 
-    Sums the A/B/C blocks (operand dtype) and the f32 accumulator, with
-    each tile clamped to the padded mode dim exactly as the kernel's
-    BlockSpecs do.
+    The footprint of :func:`~repro.kernels.sb_gemm.block_vmem_bytes` for
+    the blocks the kernel runs: role tiles raised to the block rule
+    (:func:`~repro.kernels.addressing.role_mode_tiles`) and clamped to
+    the mode dims, exactly as ``ops.sb_contract`` builds them.
     """
-    itemsize = jnp.dtype(dtype).itemsize
-    fd = plan.fdims
+    a_modes, b_modes, c_modes = _core_modes(plan, roles)
+    dims = {m: plan.fdims[m] for m in roles}
+    mode_tiles = role_mode_tiles(a_modes, b_modes, c_modes, dims, roles, tiles)
+    return _footprint(a_modes, b_modes, c_modes, dims, mode_tiles, dtype)
+
+
+def _core_modes(plan: Plan, roles: dict) -> tuple[str, str, str]:
+    """The kernel's mode strings: nested batch modes are vmapped outside."""
+    fs = plan.fspec
+    return tuple("".join(m for m in modes if m in roles)
+                 for modes in (fs.a_modes, fs.b_modes, fs.c_modes))
+
+
+def _footprint(a_modes, b_modes, c_modes, dims, mode_tiles, dtype) -> int:
+    ext = kernel_extents(a_modes, b_modes, c_modes, dims, mode_tiles)
 
     def block_elems(modes: str) -> int:
         n = 1
         for m in modes:
-            if m not in roles:
-                continue  # nested batch mode: vmapped outside the kernel
-            tile = tiles[roles[m]]
-            n *= min(tile, padded_dim(fd[m], tile))
+            n *= effective_tile(ext[m], mode_tiles[m])
         return n
 
-    fs = plan.fspec
-    a = block_elems(fs.a_modes)
-    b = block_elems(fs.b_modes)
-    c = block_elems(fs.c_modes)
-    return (a + b) * itemsize + c * itemsize + c * 4
+    contracted = set(a_modes) & set(b_modes) - set(c_modes)
+    return block_vmem_bytes(
+        block_elems(a_modes), block_elems(b_modes), block_elems(c_modes),
+        dtype, dtype, accumulate=bool(contracted))
 
 
 def estimate_native_vmem_bytes(
@@ -194,19 +236,9 @@ def estimate_native_vmem_bytes(
     modes can afford tiles the role formula would reject.
     """
     cs = parse_spec(spec) if isinstance(spec, str) else spec
-    itemsize = jnp.dtype(dtype).itemsize
     mode_tiles = native_mode_tiles(cs.a_modes, cs.b_modes, cs.c_modes, dims, tiles)
-
-    def block_elems(modes: str) -> int:
-        n = 1
-        for m in modes:
-            n *= effective_tile(dims[m], mode_tiles[m])
-        return n
-
-    a = block_elems(cs.a_modes)
-    b = block_elems(cs.b_modes)
-    c = block_elems(cs.c_modes)
-    return (a + b) * itemsize + c * itemsize + c * 4
+    return _footprint(cs.a_modes, cs.b_modes, cs.c_modes, dims, mode_tiles,
+                      dtype)
 
 
 def validate_native_tiles(
@@ -234,15 +266,14 @@ def validate_native_tiles(
 
 
 def _effective_tiles(plan: Plan, roles: dict, tiles: dict) -> tuple:
-    """Tiles after clamping to padded dims — the dedup signature."""
-    out = {}
-    all_modes = plan.fspec.a_modes + plan.fspec.b_modes + plan.fspec.c_modes
-    for m in dict.fromkeys(all_modes):
-        r = roles.get(m)
-        if r is None:
-            continue  # nested batch mode: vmapped outside the kernel
-        out[r] = min(tiles[r], padded_dim(plan.fdims[m], tiles[r]))
-    return tuple(sorted(out.items()))
+    """Per-mode blocks after the block rule and clamping — the dedup
+    signature."""
+    a_modes, b_modes, c_modes = _core_modes(plan, roles)
+    dims = {m: plan.fdims[m] for m in roles}
+    mode_tiles = role_mode_tiles(a_modes, b_modes, c_modes, dims, roles, tiles)
+    ext = kernel_extents(a_modes, b_modes, c_modes, dims, mode_tiles)
+    return tuple(sorted(
+        (m, effective_tile(ext[m], t)) for m, t in mode_tiles.items()))
 
 
 def estimate_grouped_vmem_bytes(tiles: dict, dtype) -> int:
@@ -328,9 +359,9 @@ def enumerate_candidates(
     XLA candidates: ``"auto"`` (Algorithm 2 with flattening), ``"batched"``
     (only when it plans differently from auto), and ``"direct"`` (the
     good-XLA-user reference).  Pallas candidates: each distinct plan ×
-    each tile config from :data:`PALLAS_TILE_GRID` (brick depths from
-    :data:`EXT_BRICK_GRID` for exceptional plans) that clamps to a unique
-    effective tiling and fits the VMEM budget — plus the layout-oblivious
+    each tile config from :data:`PALLAS_TILE_GRID` (exceptional plans at
+    the kernel's brick depth) that clamps to a unique effective tiling
+    and fits the VMEM budget — plus the layout-oblivious
     ``"native"`` strategy, whose per-mode tile table is validated with
     :func:`validate_native_tiles` (it is legal for *every* non-scalar
     spec, including the degenerate/multi-k plans that have no role-based
@@ -362,33 +393,24 @@ def enumerate_candidates(
             roles = plan_roles(plan)
             if roles is None:
                 continue  # no single-kernel Pallas lowering for this plan
-            bricks = (
-                EXT_BRICK_GRID if plan.kind == CaseKind.EXCEPTIONAL else (None,)
-            )
-            for grid_cfg in PALLAS_TILE_GRID:
-                for brick in bricks:
-                    cfg = dict(grid_cfg)
-                    if brick is not None:  # exceptional: explicit brick depth
-                        cfg["b"] = brick
-                    tiles = {**DEFAULT_TILES, **cfg}
-                    eff = _effective_tiles(plan, roles, tiles)
-                    if (strategy, eff) in seen:
-                        continue
-                    seen.add((strategy, eff))
-                    try:
-                        # the same gate contract(tiles=...) applies — a
-                        # candidate must never be rejected at execution time
-                        validate_tiles(cfg)
-                    except ValueError:
-                        continue
-                    if (
-                        estimate_vmem_bytes(plan, roles, tiles, dtype)
-                        > VMEM_BUDGET_BYTES
-                    ):
-                        continue
-                    out.append(
-                        Candidate(strategy, "pallas", tuple(sorted(cfg.items())))
-                    )
+            for cfg in PALLAS_TILE_GRID:
+                tiles = {**DEFAULT_TILES, **cfg}
+                if plan.kind == CaseKind.EXCEPTIONAL:
+                    # the brick depth contract() validates and runs with
+                    tiles["b"] = EXT_BATCH_TILE
+                eff = _effective_tiles(plan, roles, tiles)
+                if (strategy, eff) in seen:
+                    continue
+                seen.add((strategy, eff))
+                try:
+                    # the same gate contract(tiles=...) applies — a
+                    # candidate must never be rejected at execution time
+                    validate_plan_tiles(plan, cfg, dtype)
+                except ValueError:
+                    continue
+                out.append(
+                    Candidate(strategy, "pallas", tuple(sorted(cfg.items())))
+                )
 
         seen_native: set[tuple] = set()
         for grid_cfg in PALLAS_TILE_GRID:

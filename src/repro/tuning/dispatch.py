@@ -327,19 +327,22 @@ class Dispatcher:
             self.hits += 1
             cand = hit[0]
             if _trace.enabled():
-                from repro.obs.roofline import contraction_record
+                from repro.obs.roofline import (
+                    contraction_record, device_peaks, measured_fraction,
+                )
 
                 rec = contraction_record(cs, dims, dtype)
                 measured_us = hit[1]
+                share = {}
+                peaks = device_peaks()
+                if peaks is not None:  # a CPU timing is no share
+                    share["roofline_fraction"] = measured_fraction(
+                        rec["flops"], rec["bytes"], measured_us, peaks)
                 _trace.instant(
                     "tuning_hit", "tuning", spec=cs.spec_str(),
                     winner=cand.key(), measured_us=measured_us,
                     flops=rec["flops"], bytes=rec["bytes"],
-                    intensity=rec["intensity"],
-                    roofline_fraction=(
-                        rec["roofline_bound_us"] / measured_us
-                        if measured_us > 0 else 0.0
-                    ),
+                    intensity=rec["intensity"], **share,
                 )
         return contract(
             cs, A, B,

@@ -45,6 +45,7 @@ from repro.core.einsum import xeinsum
 from repro.core.notation import CaseKind, ContractionSpec
 from repro.core.planner import make_plan
 from repro.core.program import compile_program
+from repro.launch.mesh import make_mesh
 
 pytestmark = pytest.mark.slow  # the fuzzer is the multi-minute tier-1 tail
 
@@ -284,7 +285,7 @@ def _seeded_shardings(mode_strings, output, dims, mesh):
 @multidevice
 @pytest.mark.parametrize("chunk", _chunks(N_PAIRWISE // 2))
 def test_sharded_pairwise_matches_single_device(chunk):
-    mesh = jax.make_mesh((2, 2), ("x", "y"))
+    mesh = make_mesh((2, 2), ("x", "y"))
     for i in range(chunk * CHUNK, min((chunk + 1) * CHUNK, N_PAIRWISE // 2)):
         rng = np.random.default_rng([SEED, i])  # same specs as single-device
         cs, dims = gen_pairwise(rng)
@@ -304,7 +305,7 @@ def test_sharded_pairwise_matches_single_device(chunk):
 @multidevice
 @pytest.mark.parametrize("chunk", _chunks(N_NARY // 2))
 def test_sharded_nary_matches_single_device(chunk):
-    mesh = jax.make_mesh((2, 2), ("x", "y"))
+    mesh = make_mesh((2, 2), ("x", "y"))
     for i in range(chunk * CHUNK, min((chunk + 1) * CHUNK, N_NARY // 2)):
         rng = np.random.default_rng([SEED, 10_000 + i])
         spec, dims = gen_nary(rng)

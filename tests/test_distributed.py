@@ -67,7 +67,8 @@ def test_sharding_rules_dedup_and_missing_axes():
     code = """
     import jax
     from repro.distributed.sharding import ShardingRules
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     rules = ShardingRules(mesh)
     # pod axis absent on this mesh -> dropped; duplicate mesh axis -> dropped
     spec = rules.physical(("batch", "kv_seq", "kv_heads", None))
@@ -83,6 +84,7 @@ def test_sharded_train_step_matches_single_device():
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs import get_config
     from repro.distributed.sharding import ShardingRules, use_rules
+    from repro.launch.mesh import make_mesh
     from repro.launch.shardings import (param_logical_axes, batch_logical_axes,
                                         tree_shardings)
     from repro.models.transformer import init_params, lm_loss
@@ -93,7 +95,7 @@ def test_sharded_train_step_matches_single_device():
     batch = {"tokens": toks}
     loss_1dev = jax.jit(lambda p, b: lm_loss(cfg, p, b)[0])(params, batch)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rules = ShardingRules(mesh)
     p_spec = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
     p_sh = tree_shardings(rules, param_logical_axes(p_spec), p_spec)
@@ -114,7 +116,8 @@ def test_pipeline_matches_sequential():
     code = """
     import jax, jax.numpy as jnp, numpy as np
     from repro.distributed.pipeline import pipeline_forward
-    mesh = jax.make_mesh((4,), ("pod",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4,), ("pod",))
     n_stages, n_micro, micro, d = 4, 8, 2, 16
     ks = jax.random.split(jax.random.PRNGKey(0), n_stages)
     Ws = jnp.stack([jax.random.normal(k, (d, d)) * 0.3 for k in ks])
@@ -142,6 +145,7 @@ def test_elastic_restore_across_mesh_sizes(tmp_path):
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs import get_config
     from repro.distributed.sharding import ShardingRules
+    from repro.launch.mesh import make_mesh
     from repro.launch.shardings import param_logical_axes, tree_shardings
     from repro.models.transformer import init_params
     from repro.training.checkpoint import save_checkpoint, restore_checkpoint
@@ -151,7 +155,7 @@ def test_elastic_restore_across_mesh_sizes(tmp_path):
     save_checkpoint({str(tmp_path)!r}, 7, params)
 
     # restore onto a 2x2 mesh (as if rescaled from some other fleet size)
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     rules = ShardingRules(mesh)
     p_spec = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params)
     shardings = tree_shardings(rules, param_logical_axes(p_spec), p_spec)

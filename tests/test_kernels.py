@@ -1,6 +1,7 @@
 """Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracle.
 
-All runs use interpret=True (CPU container; TPU is the target)."""
+On the CPU every kernel is interpreted (``interpret_mode``); the TPU
+compile of the same kernels is ``test_tpu_compile.py``."""
 
 import jax
 import jax.numpy as jnp

@@ -10,6 +10,7 @@ the serving runtime.
 """
 
 import json
+import types
 
 import jax
 import jax.numpy as jnp
@@ -115,14 +116,33 @@ class TestTracer:
         (ev,) = t.events()
         assert ev["args"]["error"] == "RuntimeError"
 
-    def test_roofline_fraction_derived_on_close(self):
+    def test_roofline_fraction_derived_on_close(self, monkeypatch):
+        real = obs_roofline.device_peaks
+
+        def on(platform, kind):
+            dev = types.SimpleNamespace(platform=platform, device_kind=kind)
+            monkeypatch.setattr(obs_roofline, "device_peaks",
+                                lambda: real(dev))
+
         clk = FakeClock()
+        on("tpu", "TPU v5 lite")
         t = trace.Tracer(clock=clk)
         with t.span("c") as sp:
             sp.set(roofline_bound_us=2.0)
             clk.advance(8e-6)   # dur = 8 µs
         (ev,) = t.events()
         assert ev["args"]["roofline_fraction"] == pytest.approx(0.25)
+        # a host timing is no device share; an unknown chip has no peaks
+        on("cpu", "cpu")
+        t = trace.Tracer(clock=clk)
+        with t.span("c") as sp:
+            sp.set(roofline_bound_us=2.0)
+        assert "roofline_fraction" not in t.events()[0]["args"]
+        on("tpu", "TPU v99")
+        t = trace.Tracer(clock=clk)
+        with pytest.raises(KeyError, match="TPU v99"):
+            with t.span("c") as sp:
+                sp.set(roofline_bound_us=2.0)
 
     def test_clear(self):
         t = trace.Tracer(clock=FakeClock())
@@ -342,10 +362,14 @@ class TestRoofline:
         assert memory == pytest.approx(1e12 / obs_roofline.HBM_BW * 1e6)
 
     def test_measured_fraction(self):
-        f = obs_roofline.measured_fraction(1e12, 1e9, 10_000.0)
+        v5e = obs_roofline.peaks(obs_roofline.TARGET_KIND)
+        f = obs_roofline.measured_fraction(1e12, 1e9, 10_000.0, v5e)
         bound = obs_roofline.roofline_bound_us(1e12, 1e9)
         assert f == pytest.approx(bound / 10_000.0)
-        assert obs_roofline.measured_fraction(1.0, 1.0, 0.0) == 0.0
+        assert obs_roofline.measured_fraction(1.0, 1.0, 0.0, v5e) == 0.0
+        for kind in ("cpu", "TPU v99"):  # no published peaks: no share
+            with pytest.raises(KeyError, match="no published peaks"):
+                obs_roofline.peaks(kind)
 
     def test_single_source_of_truth_with_launch(self):
         # launch.roofline re-exports these; equality by identity of value
@@ -456,7 +480,8 @@ class TestContractInstrumentation:
         assert args["case_kind"] == "flat_gemm"
         assert args["flops"] == 2 * 4 * 8 * 2
         assert args["roofline_bound_us"] > 0
-        assert "roofline_fraction" in args
+        # measured on the CPU: a host timing reports no device share
+        assert "roofline_fraction" not in args
 
     def test_contract_disabled_emits_nothing(self):
         from repro.core.contract import contract
@@ -498,7 +523,7 @@ class TestDispatcherInstrumentation:
         assert "tuning_hit" in names
         hit = [e for e in t.events() if e["name"] == "tuning_hit"][-1]
         assert hit["args"]["measured_us"] > 0
-        assert hit["args"]["roofline_fraction"] > 0
+        assert "roofline_fraction" not in hit["args"]  # CPU: no device share
         assert "winner" in hit["args"]
         tune = [e for e in t.events() if e["name"] == "tune"][-1]
         assert tune["args"]["n_measured"] >= 1

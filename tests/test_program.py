@@ -29,6 +29,7 @@ from repro.core.program import (
     record_programs,
 )
 from repro.tuning import Dispatcher, set_dispatcher
+from repro.launch.mesh import make_mesh
 
 
 @pytest.fixture(autouse=True)
@@ -224,7 +225,7 @@ def test_shard_placement_pass_annotates_pspecs():
     thread through the DAG) without needing simulated devices."""
     from jax.sharding import PartitionSpec as P
 
-    mesh = jax.make_mesh((1,), ("x",))
+    mesh = make_mesh((1,), ("x",))
     A, B, C = _rand(0, (4, 6)), _rand(1, (6, 8)), _rand(2, (8, 2))
     prog = build_program(
         {"A": A, "B": B, "C": C},

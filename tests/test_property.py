@@ -212,20 +212,32 @@ def test_tile_loads_in_bounds_and_exhaustive(case):
 def test_native_mode_tiles_invariants(case):
     """The role→mode assignment covers every grid mode exactly once, puts
     the lane (v) tile on C's minor-most mode and the k tile on the
-    largest contracted mode — for any mode ordering."""
-    from repro.kernels.addressing import native_mode_tiles
+    largest contracted mode — each raised to the TPU block rule, which
+    every operand's last two axes then obey — for any mode ordering."""
+    from repro.kernels.addressing import LANE, SUBLANE, native_mode_tiles
 
     a_modes, b_modes, c_modes, dims, _, grid_modes = case
     role = {"u": 64, "v": 128, "k": 32, "b": 1}
     mt = native_mode_tiles(a_modes, b_modes, c_modes, dims, role)
     assert set(mt) == set(grid_modes)
     assert all(isinstance(t, int) and t >= 1 for t in mt.values())
+
+    def raised(t):  # the role tile, or it rounded up to the block rule
+        return {t, -(-t // SUBLANE) * SUBLANE, -(-t // LANE) * LANE}
+
     if c_modes:
-        assert mt[c_modes[-1]] == role["v"]
+        assert mt[c_modes[-1]] in raised(role["v"])
     contracted = [m for m in a_modes if m in b_modes and m not in c_modes]
     if contracted:
         k_prim = max(contracted, key=lambda m: dims[m])
-        assert mt[k_prim] == role["k"]
+        assert mt[k_prim] in raised(role["k"])
+    for modes in (a_modes, b_modes, c_modes):
+        if modes:
+            t = mt[modes[-1]]
+            assert t % LANE == 0 or t >= dims[modes[-1]], (modes, t)
+        if len(modes) >= 2:
+            t = mt[modes[-2]]
+            assert t % SUBLANE == 0 or t >= dims[modes[-2]], (modes, t)
 
 
 @given(st.lists(st.integers(1, 6), min_size=1, max_size=3), st.integers(0, 2**31 - 1))

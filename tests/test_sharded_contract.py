@@ -29,6 +29,7 @@ from repro.distributed.contract import (
     sharded_contract,
 )
 from repro.distributed.sharding import specs_equal
+from repro.launch.mesh import make_mesh
 
 pytestmark = pytest.mark.skipif(
     jax.device_count() < 8,
@@ -38,7 +39,7 @@ pytestmark = pytest.mark.skipif(
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((2, 4), ("x", "y"))
+    return make_mesh((2, 4), ("x", "y"))
 
 
 def rand(shape, seed=0):
@@ -339,5 +340,5 @@ def test_serve_engine_sharded_matches_single_device():
         return [r.output for r in reqs]
 
     single = serve(None)
-    sharded = serve(jax.make_mesh((2, 4), ("data", "model")))
+    sharded = serve(make_mesh((2, 4), ("data", "model")))
     assert single == sharded

@@ -87,28 +87,71 @@ def test_candidates_scalar_spec_degrades_to_direct():
 
 def test_all_pallas_candidates_pass_contract_validation():
     # a candidate the enumerator emits must never be rejected at execution
-    # time — contract(tiles=...) applies validate_tiles to the raw override
+    # time — contract(tiles=...) applies the same gate to the override
+    from repro.core.planner import make_plan
     from repro.core.table2 import CASES
+    from repro.tuning.candidates import (
+        validate_native_tiles, validate_plan_tiles,
+    )
 
     for label in ("1.3", "3.4"):  # sb_gemm and exceptional regimes
         rm = CASES[label].row_major()
         cs = parse_spec(rm)
         dims = {m: 256 if m in "kn" else 32 for m in set(cs.a_modes + cs.b_modes)}
         for c in enumerate_candidates(rm, dims, backends=("xla", "pallas")):
-            if c.tiles:
-                validate_tiles(c.tiles_dict)  # must not raise
+            if not c.tiles:
+                continue
+            if c.strategy == "native":  # must not raise
+                validate_native_tiles(cs, dims, c.tiles_dict)
+                continue
+            plan = make_plan(cs, dims, allow_flatten=c.strategy == "auto")
+            validate_plan_tiles(plan, c.tiles_dict, jnp.float32)
+
+
+def test_tiles_validated_as_the_kernel_raises_them():
+    # Table II 3.4 at 512: its batch mode is an operand's lane axis, so a
+    # b=8 brick runs 128 deep.  The role formula passes the tiles as
+    # requested; the gate must see the blocks the kernel would run.
+    from repro.core.table2 import CASES
+
+    rm = CASES["3.4"].row_major()
+    cs = parse_spec(rm)
+    A, B = (jax.ShapeDtypeStruct((512,) * len(modes), jnp.bfloat16)
+            for modes in (cs.a_modes, cs.b_modes))
+    tiles = {"b": 8, "u": 256, "k": 256}
+    validate_tiles(tiles)  # fits as requested
+    with pytest.raises(ValueError, match="as the kernel runs them"):
+        jax.eval_shape(lambda a, b: contract(
+            rm, a, b, strategy="batched", backend="pallas", tiles=tiles), A, B)
 
 
 def test_exceptional_case_gets_brick_candidates():
     # row-major mirror of Table II case 3.4 plans as exceptional
     from repro.core.table2 import CASES
 
+    # Its batch mode is the 3D operand's stride-1 (lane) axis, so the TPU
+    # block rule fixes the brick at one lane tile, or the whole mode when
+    # shorter: every emitted candidate runs that brick.
+    from repro.core.planner import make_plan
+    from repro.kernels.addressing import effective_tile, role_mode_tiles
+    from repro.kernels.ops import EXT_BATCH_TILE, plan_roles
+
     rm = CASES["3.4"].row_major()
     cs = parse_spec(rm)
-    dims = {m: 16 for m in set(cs.a_modes + cs.b_modes)}
-    cands = enumerate_candidates(rm, dims, backends=("xla", "pallas"))
-    bricks = {dict(c.tiles).get("b") for c in cands if c.backend == "pallas"}
-    assert len(bricks) > 1  # more than one brick depth survived VMEM checks
+    for n, brick in ((16, 16), (512, 128)):
+        dims = {m: n for m in set(cs.a_modes + cs.b_modes)}
+        plan = make_plan(cs, dims, allow_flatten=False)
+        roles = plan_roles(plan)
+        cands = [c for c in enumerate_candidates(rm, dims,
+                                                 backends=("xla", "pallas"))
+                 if c.backend == "pallas" and c.strategy != "native"]
+        assert cands
+        for c in cands:
+            tiles = {"b": EXT_BATCH_TILE, **c.tiles_dict}
+            fs = plan.fspec
+            mt = role_mode_tiles(fs.a_modes, fs.b_modes, fs.c_modes, dims,
+                                 roles, tiles)
+            assert effective_tile(n, mt[plan.sb_batch]) == brick, c.key()
 
 
 def test_native_candidates_enumerated_and_execute():

@@ -1,0 +1,15 @@
+"""Per cent of the chip's bf16 peak that a whole decode step reaches:
+the operations of its tokens at their live context lengths over its
+device time.  Means over the launches of the traced window (counts:
+bench/counts.py; time: trace)."""
+
+import numpy as np
+
+from bench.metrics._shared import decode_bounds, launches
+
+
+def read(run):
+    d, b = launches(run, "serve_decode"), decode_bounds(run)
+    if d is None or b is None:
+        return None
+    return 100.0 * b[1] / float(np.mean(d)) / run.peaks.flops

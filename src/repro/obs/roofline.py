@@ -26,14 +26,12 @@ peaks: an accelerator of unknown kind raises (:func:`peaks`), and a CPU
 reports none (:func:`device_peaks`), since a host timing is no device
 time.
 
-A span carrying ``roofline_bound_us`` gains ``roofline_fraction`` (bound
-÷ measured duration) when it closes on such a device (see
-:class:`repro.obs.trace.Tracer`) — ~1.0 means roofline-saturating, ≪1
-means overhead or a wrong strategy.
-Host-measured durations of *jit-traced* calls are trace time, not run
-time; emitters flag those spans ``eager=False``.  The autotuner's cache
-hits instead carry *measured* kernel time, giving the trustworthy
-fraction (:func:`measured_fraction`).
+Spans carry ``roofline_bound_us`` as a count and no share: a span's
+duration is host time of a call that may only have been enqueued (and
+of a *jit-traced* call, trace time; emitters flag those ``eager=False``).
+The autotuner's cache hits carry *measured* kernel time instead, which
+gives a trustworthy fraction (:func:`measured_fraction`) — ~1.0 means
+roofline-saturating, ≪1 means overhead or a wrong strategy.
 """
 
 from __future__ import annotations

@@ -37,18 +37,20 @@ each event's ``depth``, and the exporter keeps one Perfetto track per
 ``cat`` (layer), so a ``contract`` span opened inside a ``decode_batch``
 span renders nested across the ``core`` and ``runtime`` tracks.
 
-A span finishing with ``roofline_bound_us`` among its attributes gains a
-derived ``roofline_fraction`` (= bound / measured duration) at exit —
-the achieved-vs-roofline attribution per-contraction spans carry (see
-:mod:`repro.obs.roofline`; only meaningful for spans whose duration is a
-real eager execution, flagged ``eager=True`` by the emitters).
+Every span also enters a ``jax.profiler.TraceAnnotation`` named
+``<cat>.<name>`` (``runtime.tick``, ``pages.grow``, ...) for its
+lifetime, so a ``jax.profiler`` capture holds the program's spans on
+its host plane, on the same clock as the device ops.  The annotation
+carries the name alone: attributes stay in the ring buffer, and the
+names stay stable for whatever reduces the capture.  With tracing
+disabled no annotation is made.
 """
 
 from __future__ import annotations
 
 import time
 
-from repro.obs import roofline
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Tracer",
@@ -73,7 +75,8 @@ class Span:
     with :meth:`set`.  Truthy — the disabled-mode :data:`NULL_SPAN` is
     falsy, which is the one branch hot sites pay for attributes."""
 
-    __slots__ = ("_tracer", "name", "cat", "ts", "depth", "attrs")
+    __slots__ = ("_tracer", "_annotation", "name", "cat", "ts", "depth",
+                 "attrs")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, ts: float,
                  depth: int, attrs: dict | None):
@@ -83,6 +86,8 @@ class Span:
         self.ts = ts
         self.depth = depth
         self.attrs = dict(attrs) if attrs else {}
+        self._annotation = TraceAnnotation(f"{cat}.{name}")
+        self._annotation.__enter__()
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -123,8 +128,6 @@ class _NullSpan:
 #: the singleton every disabled-mode ``span()`` call returns.
 NULL_SPAN = _NullSpan()
 
-_UNSET = object()
-
 
 class Tracer:
     """Ring-buffered span recorder with an injectable monotonic clock.
@@ -134,10 +137,6 @@ class Tracer:
         oldest events (``dropped`` counts them).
       clock: a monotonic ``() -> float`` seconds callable
         (default ``time.perf_counter``); injectable for tests.
-
-    Spans report a ``roofline_fraction`` only where
-    :func:`repro.obs.roofline.device_peaks` gives the device's peaks: a
-    CPU reports none, an accelerator of unknown kind raises.
     """
 
     def __init__(self, capacity: int = 65536, clock=time.perf_counter):
@@ -145,7 +144,6 @@ class Tracer:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.capacity = int(capacity)
         self.clock = clock
-        self._peaks = _UNSET         # device peaks, looked up on first use
         self._epoch = clock()
         self._ring: list[dict] = []
         self._total = 0              # events ever recorded
@@ -180,23 +178,12 @@ class Tracer:
             if self._open[i] is sp:
                 del self._open[i]
                 break
-        dur = max(end - sp.ts, 0.0)
-        bound = sp.attrs.get("roofline_bound_us")
-        if (bound is not None and "roofline_fraction" not in sp.attrs
-                and self._reports_shares()):
-            sp.attrs["roofline_fraction"] = (
-                float(bound) / dur if dur > 0 else 0.0
-            )
+        sp._annotation.__exit__(None, None, None)
         self._record({
             "ph": PH_SPAN, "name": sp.name, "cat": sp.cat,
-            "ts": sp.ts, "dur": dur, "depth": sp.depth, "args": sp.attrs,
+            "ts": sp.ts, "dur": max(end - sp.ts, 0.0), "depth": sp.depth,
+            "args": sp.attrs,
         })
-
-    def _reports_shares(self) -> bool:
-        """Whether span durations are device times with published peaks."""
-        if self._peaks is _UNSET:
-            self._peaks = roofline.device_peaks()
-        return self._peaks is not None
 
     def _record(self, ev: dict) -> None:
         ev["seq"] = self._total

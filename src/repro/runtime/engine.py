@@ -400,26 +400,26 @@ class ServingRuntime:
         """
         vm = self._decode_vmapped
         if bucket == self.slots:
-            def fn(p, cache, toks, idx):
+            def serve_decode_dense(p, cache, toks, idx):
                 del idx  # full batch: row == slot id
                 return vm(p, cache, toks)
         else:
-            def fn(p, cache, toks, idx):
+            def serve_decode_dense(p, cache, toks, idx):
                 sub = jax.tree.map(lambda x: x[idx], cache)
                 logits, new_sub = vm(p, sub, toks[idx])
                 cache = jax.tree.map(
                     lambda full, ns: full.at[idx].set(ns), cache, new_sub
                 )
                 return logits, cache
-        return jax.jit(fn)
+        return jax.jit(serve_decode_dense)
 
     def _build_prefill(self):
         cfg = self.cfg
 
-        def fn(p, toks, c):
+        def serve_prefill(p, toks, c):
             return prefill(cfg, p, {"tokens": toks}, c)
 
-        return jax.jit(fn)
+        return jax.jit(serve_prefill)
 
     # ------------------------------------------------------------ lifecycle
     def _reject_reason(self, request: Request) -> str | None:
@@ -572,24 +572,30 @@ class ServingRuntime:
     def _page_stage(self, state: RequestState):
         """Batch-1 prefill staging cache: the request's pages gathered
         dense (``P * page_size`` rows), cache length = shared prefix."""
-        P = self._page_width(state)
-        key = self.buckets.key("page_view", P, self._fingerprint())
-        fn = self.buckets.get(key, lambda: self.kv.build_view(P))
-        table = jnp.asarray(self._page_table(state, P)[None])
-        length = jnp.full((1,), state.shared_tokens, jnp.int32)
-        return fn(self.kv.pool, table, length)
+        with _trace.span("stage", "pages") as sp:
+            if sp:
+                sp.set(rid=state.rid)
+            P = self._page_width(state)
+            key = self.buckets.key("page_view", P, self._fingerprint())
+            fn = self.buckets.get(key, lambda: self.kv.build_view(P))
+            table = jnp.asarray(self._page_table(state, P)[None])
+            length = jnp.full((1,), state.shared_tokens, jnp.int32)
+            return fn(self.kv.pool, table, length)
 
     def _page_commit(self, state: RequestState) -> None:
         """Scatter a finished prefill's staging cache back into its
         pages and publish the full prompt pages to the prefix index.
         Re-writing a shared page is bit-idempotent: its staged rows were
         gathered from that very page and prefill never touched them."""
-        P = self._page_width(state)
-        key = self.buckets.key("page_commit", P, self._fingerprint())
-        fn = self.buckets.get(key, lambda: self.kv.build_commit(P))
-        pages = jnp.asarray(self._page_table(state, P))
-        self.kv.pool = fn(self.kv.pool, state.cache, pages)
-        self.pool.register(state)
+        with _trace.span("commit", "pages") as sp:
+            if sp:
+                sp.set(rid=state.rid)
+            P = self._page_width(state)
+            key = self.buckets.key("page_commit", P, self._fingerprint())
+            fn = self.buckets.get(key, lambda: self.kv.build_commit(P))
+            pages = jnp.asarray(self._page_table(state, P))
+            self.kv.pool = fn(self.kv.pool, state.cache, pages)
+            self.pool.register(state)
 
     def _ensure_decode_capacity(self, decodes: list[RequestState]) -> None:
         """Grow page tables for this decode step, preempting on pressure.
@@ -651,13 +657,49 @@ class ServingRuntime:
             self._run_decode_impl(decodes)
 
     def _run_decode_impl(self, decodes: list[RequestState]) -> None:
+        """One decode step: grow page tables (paged), build and send the
+        inputs, launch, wait for the tokens, emit them — each phase its
+        own span, so a device idle gap lands in the phase that held it."""
         if self.paged:
-            self._ensure_decode_capacity(decodes)
-            if decodes:
-                self._run_decode_paged(decodes)
-            return
+            with _span("grow", "pages", decodes):
+                self._ensure_decode_capacity(decodes)
+            if not decodes:
+                return
         n = len(decodes)
         bucket = self.lattice.decode_bucket(n)
+        with _span("decode_inputs", "runtime", decodes):
+            if self.paged:
+                fn, args, rows = self._decode_inputs_paged(decodes, bucket)
+            else:
+                fn, args, rows = self._decode_inputs_dense(decodes, bucket)
+        with _span("decode_launch", "runtime", decodes):
+            with self._mesh_ctx():
+                logits, out = fn(self.params, *args)
+        if self.paged:
+            self.kv.pool = out
+        else:
+            self.cache = out
+        with _span("decode_sync", "runtime", decodes):
+            if self.logits_probe is not None:
+                self.logits_probe(logits)
+            if self.greedy:
+                nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+                toks = [int(nxt[r]) for r in rows]
+            else:
+                toks = [self._sample(s, logits[r, 0])
+                        for s, r in zip(decodes, rows)]
+        with _span("emit", "runtime", decodes):
+            self.metrics.on_decode(n, bucket)
+            for state, tok in zip(decodes, toks):
+                state.request.output.append(tok)
+                self._tokens[state.slot, 0, 0] = tok
+                self.metrics.on_token()
+                self._maybe_finish(state)
+
+    def _decode_inputs_dense(self, decodes: list[RequestState], bucket: int):
+        """(step, arguments after the params, logits row of each request)
+        for one decode over the slot-stacked cache."""
+        n = len(decodes)
         key = self.buckets.key("decode", bucket, self._fingerprint())
         fn = self.buckets.get(key, lambda: self._build_decode(bucket))
         if bucket == self.slots:
@@ -669,35 +711,19 @@ class ServingRuntime:
             # identical updates, so the scatter is value-deterministic
             idx = np.asarray(slot_ids + [slot_ids[0]] * (bucket - n))
             rows = list(range(n))
-        with self._mesh_ctx():
-            logits, self.cache = fn(
-                self.params, self.cache, jnp.asarray(self._tokens),
-                jnp.asarray(idx),
-            )
-        if self.logits_probe is not None:
-            self.logits_probe(logits)
-        self.metrics.on_decode(n, bucket)
-        if self.greedy:
-            nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
-            toks = [int(nxt[r]) for r in rows]
-        else:
-            toks = [self._sample(s, logits[r, 0])
-                    for s, r in zip(decodes, rows)]
-        for state, tok in zip(decodes, toks):
-            state.request.output.append(tok)
-            self._tokens[state.slot, 0, 0] = tok
-            self.metrics.on_token()
-            self._maybe_finish(state)
+        args = (self.cache, jnp.asarray(self._tokens), jnp.asarray(idx))
+        return fn, args, rows
 
-    def _run_decode_paged(self, decodes: list[RequestState]) -> None:
-        """One decode step over page tables: gather each request's pages
-        into a view, step, scatter the one written KV row back.  The
+    def _decode_inputs_paged(self, decodes: list[RequestState], bucket: int):
+        """(step, arguments after the params, logits row of each request)
+        for one decode over page tables: gather each request's pages into
+        a view, step, scatter the one written KV row back.  The
         executable is keyed on the (slot-bucket, page-bucket) lattice
         point; the batch pads with full duplicates of request 0's
         (table, length, token) row, so the padded rows compute — and
-        scatter — identical values."""
+        scatter — identical values.  Counts the KV rows the step attends
+        against those its view gathers."""
         n = len(decodes)
-        bucket = self.lattice.decode_bucket(n)
         P = self.lattice.page_bucket(max(len(s.pages) for s in decodes))
         key = self.buckets.key("decode", (bucket, P), self._fingerprint())
         fn = self.buckets.get(
@@ -709,29 +735,17 @@ class ServingRuntime:
             [s.prompt_len + s.n_generated - 1 for s in decodes], np.int32
         )
         toks = self._tokens[[s.slot for s in decodes]]
+        # the step writes row `length` and attends rows 0..length
+        self.metrics.on_kv_rows(int(lengths.sum()) + n,
+                                bucket * P * self.page_size)
         if bucket > n:
             pad = bucket - n
             tables = np.concatenate([tables, np.repeat(tables[:1], pad, 0)])
             lengths = np.concatenate([lengths, np.repeat(lengths[:1], pad)])
             toks = np.concatenate([toks, np.repeat(toks[:1], pad, 0)])
-        logits, self.kv.pool = fn(
-            self.params, self.kv.pool, jnp.asarray(tables),
-            jnp.asarray(lengths), jnp.asarray(toks),
-        )
-        if self.logits_probe is not None:
-            self.logits_probe(logits)
-        self.metrics.on_decode(n, bucket)
-        if self.greedy:
-            nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
-            out = [int(nxt[r]) for r in range(n)]
-        else:
-            out = [self._sample(s, logits[r, 0])
-                   for r, s in enumerate(decodes)]
-        for state, tok in zip(decodes, out):
-            state.request.output.append(tok)
-            self._tokens[state.slot, 0, 0] = tok
-            self.metrics.on_token()
-            self._maybe_finish(state)
+        args = (self.kv.pool, jnp.asarray(tables), jnp.asarray(lengths),
+                jnp.asarray(toks))
+        return fn, args, list(range(n))
 
     def tick(self) -> None:
         """One scheduler round: admissions → prefill chunks → decode.
@@ -841,6 +855,15 @@ class ServingRuntime:
                 stacklevel=2,
             )
         return requests
+
+
+def _span(name: str, cat: str, batch: list[RequestState]):
+    """A trace span carrying the ``rids`` of a decode batch; one branch
+    when tracing is off."""
+    sp = _trace.span(name, cat)
+    if sp:
+        sp.set(rids=[s.rid for s in batch])
+    return sp
 
 
 def _write_slot(cache, one, slot: int):

@@ -27,7 +27,10 @@ class ServingMetrics:
     *occupancy* (active slots / engine slots — how full the engine runs)
     from *decode efficiency* (active slots / bucket rows — how much of
     each launched decode batch is useful work; 1.0 for a perfectly
-    snapped bucket).
+    snapped bucket).  On the paged path *KV liveness* counts rows: of
+    the rows each decode launch gathers into its view (``bucket × P ×
+    page_size``, ``kv_rows_viewed``), those its active requests attend
+    (length + 1 each, ``kv_rows_live``).
 
     **Event ordering is enforced.**  Per-request events are only
     honoured for a request with a live ``on_submit`` record, and a first
@@ -61,6 +64,8 @@ class ServingMetrics:
         self.prefix_hits = 0
         self.prefix_shared_pages = 0
         self.prefix_shared_tokens = 0
+        self.kv_rows_live = 0      # Σ rows attended over decode launches
+        self.kv_rows_viewed = 0    # Σ rows gathered into decode views
         self._pool_free_min: int | None = None   # high-water memory pressure
         self._pool_used = 0.0      # Σ used fraction over gauge samples
         self._pool_samples = 0
@@ -142,6 +147,12 @@ class ServingMetrics:
         self._active_rows += int(n_active)
         self._bucket_rows += int(bucket_rows)
 
+    def on_kv_rows(self, live: int, viewed: int) -> None:
+        """One paged decode launch: ``live`` rows attended by its active
+        requests, ``viewed`` rows in the views it gathers."""
+        self.kv_rows_live += int(live)
+        self.kv_rows_viewed += int(viewed)
+
     def on_tick(self, n_active: int) -> None:
         self.ticks += 1
         self._occupancy += n_active / self.slots
@@ -188,6 +199,8 @@ class ServingMetrics:
             "prefix_hits": self.prefix_hits,
             "prefix_shared_pages": self.prefix_shared_pages,
             "prefix_shared_tokens": self.prefix_shared_tokens,
+            "kv_rows_live": self.kv_rows_live,
+            "kv_rows_viewed": self.kv_rows_viewed,
             "pool_free_min": (
                 -1 if self._pool_free_min is None else self._pool_free_min
             ),

@@ -370,11 +370,11 @@ class PagedKV:
         ``prefill`` expects."""
         import jax
 
-        def fn(pool, table, length):
+        def serve_page_view(pool, table, length):
             view = self._gather(pool, table, length)
             return jax.tree.map(lambda x: x[0], view)
 
-        return jax.jit(fn)
+        return jax.jit(serve_page_view)
 
     def build_commit(self, P: int):
         """Jitted prefill commit: re-cut a staged batch-1 cache of
@@ -386,7 +386,7 @@ class PagedKV:
 
         ps = self.page_size
 
-        def fn(pool, stage, pages):
+        def serve_page_commit(pool, stage, pages):
             def c(pool_leaf, stage_leaf, ax):
                 if ax < 0:
                     return pool_leaf          # lengths live host-side
@@ -398,7 +398,7 @@ class PagedKV:
 
             return jax.tree.map(c, pool, stage, self.axes)
 
-        return jax.jit(fn)
+        return jax.jit(serve_page_commit)
 
     def build_decode(self, decode_vmapped, bucket: int, P: int):
         """Jitted paged decode for one ``(slot-bucket, page-bucket)``
@@ -408,15 +408,25 @@ class PagedKV:
         The engine pads the batch to ``bucket`` by duplicating an
         active request's (table, length, token) row — duplicates
         compute identical updates, so the row scatter is
-        value-deterministic (same rule as the unpaged slot scatter)."""
+        value-deterministic (same rule as the unpaged slot scatter).
+
+        The gather, the model step and the row scatter run under the
+        name scopes ``kv_view``, ``decode_model`` and ``kv_write``, which
+        reach each op's ``op_name`` metadata and change nothing else in
+        the compiled program.  So JAX's persistent compilation cache,
+        whose key leaves metadata out unless
+        ``jax_compilation_cache_include_metadata_in_key`` is set, may
+        load an executable carrying the metadata of an older compile."""
         import jax
         import jax.numpy as jnp
 
         ps = self.page_size
 
-        def fn(params, pool, tables, lengths, toks):
-            view = self._gather(pool, tables, lengths)
-            logits, new_view = decode_vmapped(params, view, toks)
+        def serve_decode(params, pool, tables, lengths, toks):
+            with jax.named_scope("kv_view"):
+                view = self._gather(pool, tables, lengths)
+            with jax.named_scope("decode_model"):
+                logits, new_view = decode_vmapped(params, view, toks)
 
             def s(pool_leaf, new_leaf, ax):
                 if ax < 0:
@@ -434,7 +444,8 @@ class PagedKV:
                     rowm.astype(flat.dtype))
                 return jnp.moveaxis(flat.reshape(pm.shape), 1, 1 + ax)
 
-            new_pool = jax.tree.map(s, pool, new_view, self.axes)
+            with jax.named_scope("kv_write"):
+                new_pool = jax.tree.map(s, pool, new_view, self.axes)
             return logits, new_pool
 
-        return jax.jit(fn)
+        return jax.jit(serve_decode)

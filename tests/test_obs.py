@@ -10,7 +10,6 @@ the serving runtime.
 """
 
 import json
-import types
 
 import jax
 import jax.numpy as jnp
@@ -116,33 +115,41 @@ class TestTracer:
         (ev,) = t.events()
         assert ev["args"]["error"] == "RuntimeError"
 
-    def test_roofline_fraction_derived_on_close(self, monkeypatch):
-        real = obs_roofline.device_peaks
-
-        def on(platform, kind):
-            dev = types.SimpleNamespace(platform=platform, device_kind=kind)
-            monkeypatch.setattr(obs_roofline, "device_peaks",
-                                lambda: real(dev))
-
+    def test_roofline_bound_is_kept_as_a_count(self):
         clk = FakeClock()
-        on("tpu", "TPU v5 lite")
         t = trace.Tracer(clock=clk)
         with t.span("c") as sp:
             sp.set(roofline_bound_us=2.0)
-            clk.advance(8e-6)   # dur = 8 µs
+            clk.advance(8e-6)
         (ev,) = t.events()
-        assert ev["args"]["roofline_fraction"] == pytest.approx(0.25)
-        # a host timing is no device share; an unknown chip has no peaks
-        on("cpu", "cpu")
-        t = trace.Tracer(clock=clk)
-        with t.span("c") as sp:
-            sp.set(roofline_bound_us=2.0)
-        assert "roofline_fraction" not in t.events()[0]["args"]
-        on("tpu", "TPU v99")
-        t = trace.Tracer(clock=clk)
-        with pytest.raises(KeyError, match="TPU v99"):
-            with t.span("c") as sp:
-                sp.set(roofline_bound_us=2.0)
+        assert ev["args"] == {"roofline_bound_us": 2.0}
+        assert ev["dur"] == pytest.approx(8.0)
+
+    def test_span_is_a_profiler_annotation_named_cat_dot_name(
+            self, monkeypatch):
+        log = []
+
+        class Annotation:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                log.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                log.append(("exit", self.name))
+
+        monkeypatch.setattr(trace, "TraceAnnotation", Annotation)
+        t = trace.Tracer(clock=FakeClock())
+        with t.span("tick", "runtime"):
+            with t.span("decode_sync", "runtime") as sp:
+                sp.set(rids=[3, 4])
+        # attributes stay in the ring buffer, never in the name
+        assert log == [("enter", "runtime.tick"),
+                       ("enter", "runtime.decode_sync"),
+                       ("exit", "runtime.decode_sync"),
+                       ("exit", "runtime.tick")]
+        assert t.events()[0]["args"] == {"rids": [3, 4]}
 
     def test_clear(self):
         t = trace.Tracer(clock=FakeClock())

@@ -11,7 +11,9 @@ process at a time may load the TPU library, and every test worker
 imports this file.
 """
 
+import contextlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -119,3 +121,64 @@ def test_contract_route_compiles_for_v5e(name, one_chip, monkeypatch):
     spec, dims, kw = ROUTES[name]
     _, _, a, b = _avals(spec, dims, one_chip)
     _assert_kernel(lambda A, B: contract(spec, A, B, **kw), a, b)
+
+
+#: the debug tables a compiled module's text lists (source files, lines)
+DEBUG_TABLES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
+
+
+def _strip_metadata(hlo: str) -> list[str]:
+    """The module's instructions with their metadata and the debug
+    tables taken out: what the chip runs."""
+    out, table = [], False
+    for line in re.sub(r", metadata=\{[^}]*\}", "", hlo).splitlines():
+        if line in DEBUG_TABLES:
+            table = True
+        elif table and not line.strip():
+            table = False
+        elif not table:
+            out.append(line)
+    return out
+
+
+def test_scoped_serve_decode_compiles_to_the_same_program(one_chip,
+                                                         monkeypatch):
+    """The paged decode at the minicpm-2b decode-batch cell's lattice
+    point (2 slots, page bucket 32, pages of 16, a 1,792-row pool) at
+    published widths, 2 layers of 40 (every layer carries the same
+    scopes): the ``kv_view`` / ``decode_model`` / ``kv_write`` name
+    scopes change the ops' metadata and nothing else."""
+    from repro.configs import get_config
+    from repro.models.transformer import decode_step, init_params
+    from repro.runtime.pages import PagedKV
+
+    monkeypatch.setattr("repro.kernels.sb_gemm.interpret_mode",
+                        lambda *arrays: False)
+    cfg = get_config("minicpm-2b", n_periods=2, dtype="bfloat16",
+                     param_dtype="bfloat16")
+    kv = PagedKV(cfg, n_pages=2 * (1792 // 16) + 1, page_size=16)
+    bucket, P = 2, 32
+
+    def aval(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(aval, jax.eval_shape(
+        lambda k: init_params(k, cfg), jax.random.key(0)))
+    args = (params, jax.tree.map(aval, kv.pool),
+            aval(jnp.zeros((bucket, P), jnp.int32)),
+            aval(jnp.zeros((bucket,), jnp.int32)),
+            aval(jnp.zeros((bucket, 1, 1), jnp.int32)))
+    vm = jax.vmap(lambda p, c, t: decode_step(cfg, p, c, t),
+                  in_axes=(None, 0, 0))
+
+    def compiled():
+        return kv.build_decode(vm, bucket, P).lower(*args).compile().as_text()
+
+    scoped = compiled()
+    assert 'op_name="jit(serve_decode)/kv_view/' in scoped
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = compiled()
+    assert "kv_view" not in plain
+    ops = _strip_metadata(scoped)
+    assert len(ops) > 100 and ops == _strip_metadata(plain)

@@ -25,9 +25,9 @@ Two halves, mirroring the scheduler/engine split:
   copy-on-write fault path.  Eviction is page release — refcounts drop,
   pages return to the free list at zero (and leave the prefix index).
 * :class:`PagedKV` — the device half: one pooled cache tree (every
-  leaf's token axis re-cut into ``(n_pages, page_size)``) plus the
-  jitted gather/commit/decode builders the engine caches per bucket
-  lattice point.
+  token leaf stored page-major, ``(n_pages, ..., page_size, row
+  features)``) plus the jitted gather/commit/decode builders the engine
+  caches per bucket lattice point.
 
 **Page 0 is the null page.**  Gather and commit pad their page tables
 to the lattice width with it; whatever lands there is only ever read
@@ -309,13 +309,19 @@ def _leaf_token_axis(a, b):
 class PagedKV:
     """The pooled device cache and its jitted gather/commit/decode ops.
 
-    ``pool`` is the cache tree of ``init_cache(cfg, 1, page_size)`` with
-    every leaf stacked to a leading ``(n_pages,)`` axis — each page is a
-    ``page_size``-row slice of every layer's KV at once, so one page
-    table describes a request's whole cache.  The builders return jitted
-    callables the engine caches in its :class:`~repro.runtime.buckets.
-    BucketTable` keyed by the page-count lattice point ``P`` (views are
-    ``P * page_size`` rows wide), keeping the compile set bounded.
+    ``pool`` holds, for every token leaf of ``init_cache(cfg, 1,
+    page_size)``, the pages of that leaf stacked page-major, with each
+    row's features flattened into one trailing axis: a leaf of batch-1
+    shape ``pre + (page_size,) + post`` is pooled as ``(n_pages,) + pre
+    + (page_size, prod(post))``.  Each page is a ``page_size``-row slice
+    of every layer's KV at once, so one page table describes a request's
+    whole cache; a page is contiguous in memory, and the row's features
+    (``G * D`` for K and V) are the minor, lane axis whatever the head
+    size.  Length leaves are not pooled (``None``): the views take them
+    from the request lengths.  The builders return jitted callables the
+    engine caches in its :class:`~repro.runtime.buckets.BucketTable`
+    keyed by the page-count lattice point ``P`` (views are ``P *
+    page_size`` rows wide), keeping the compile set bounded.
     """
 
     def __init__(self, cfg, n_pages: int, page_size: int, dtype=None):
@@ -331,9 +337,18 @@ class PagedKV:
         two = init_cache(cfg, 1, 2 * page_size, dtype)
         #: token-axis per leaf (in batch-1 leaf coordinates), -1 = length
         self.axes = jax.tree.map(_leaf_token_axis, one, two)
-        self.pool = jax.tree.map(
-            lambda x: jnp.zeros((self.n_pages,) + x.shape, x.dtype), one
-        )
+        #: batch-1 leaf shapes and dtypes, the views' own layout
+        self.shapes = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), one)
+
+        def pages(ax, x):
+            if ax < 0:
+                return None
+            row = int(np.prod(x.shape[ax + 1:]))
+            return jnp.zeros((self.n_pages,) + x.shape[:ax]
+                             + (self.page_size, row), x.dtype)
+
+        self.pool = jax.tree.map(pages, self.axes, one)
 
     # -------------------------------------------------------------- weights
     def _gather(self, pool, tables, lengths):
@@ -342,26 +357,35 @@ class PagedKV:
         ``tables``: (B, P) page ids (null-padded); ``lengths``: (B,)
         cache lengths.  Returns a cache tree of batch views whose token
         axes are ``P * page_size`` wide; length leaves broadcast
-        ``lengths``."""
+        ``lengths``.
+
+        Each table entry is one dynamic slice of a whole, contiguous
+        page, unrolled: the TPU compiler's own gather (``leaf[tables]``)
+        first cuts the pool into 384-lane slices, a pass over the whole
+        pool, and a loop of page copies runs slower at wide page
+        buckets.  The pages are stacked with the layer axis ahead of the
+        batch axis, the order in which the model's scan over layers
+        reads them, so the view needs no transpose of its own."""
         import jax
         import jax.numpy as jnp
+        from jax import lax
 
-        B = tables.shape[0]
+        B, P = tables.shape
+        ids = [list(row) for row in tables]
 
-        def g(leaf, ax):
-            base = leaf.shape[1:]
+        def g(ax, spec, leaf):
             if ax < 0:
                 return jnp.broadcast_to(
-                    lengths.reshape((B,) + (1,) * len(base)), (B,) + base
-                ).astype(leaf.dtype)
-            x = leaf[tables]                      # (B, P) + base
-            x = jnp.moveaxis(x, 1, 1 + ax)        # page axis next to its rows
-            shp = (x.shape[:1 + ax]
-                   + (x.shape[1 + ax] * x.shape[2 + ax],)
-                   + x.shape[3 + ax:])
-            return x.reshape(shp)
+                    lengths.reshape((B,) + (1,) * spec.ndim),
+                    (B,) + spec.shape).astype(spec.dtype)
+            rows = [jnp.stack([lax.dynamic_index_in_dim(
+                leaf, i, keepdims=False, allow_negative_indices=False)
+                for i in row], axis=ax) for row in ids]  # pre + (P, ps, F)
+            x = jnp.moveaxis(jnp.stack(rows, axis=1), 1, 0)
+            return x.reshape((B,) + spec.shape[:ax]
+                             + (P * self.page_size,) + spec.shape[ax + 1:])
 
-        return jax.tree.map(g, pool, self.axes)
+        return jax.tree.map(g, self.axes, self.shapes, pool)
 
     def build_view(self, P: int):
         """Jitted batch-1 view builder (prefill staging): maps a
@@ -378,39 +402,38 @@ class PagedKV:
 
     def build_commit(self, P: int):
         """Jitted prefill commit: re-cut a staged batch-1 cache of
-        ``P * page_size`` rows into pages and scatter them into the
-        pool at ``pages`` (null-padded to P).  Writing a shared page is
-        bit-idempotent — the staged rows were gathered from it."""
+        ``P * page_size`` rows into ``P`` pages and scatter them whole
+        into the pool at ``pages`` (null-padded to P).  Writing a shared
+        page is bit-idempotent — the staged rows were gathered from
+        it."""
         import jax
         import jax.numpy as jnp
 
-        ps = self.page_size
-
         def serve_page_commit(pool, stage, pages):
-            def c(pool_leaf, stage_leaf, ax):
+            def c(ax, pool_leaf, stage_leaf):
                 if ax < 0:
                     return pool_leaf          # lengths live host-side
-                sm = jnp.moveaxis(stage_leaf, ax, 0)            # (W, ...)
-                sm = sm.reshape((P, ps) + sm.shape[1:])
-                pm = jnp.moveaxis(pool_leaf, 1 + ax, 1)         # (N, ps, ...)
-                pm = pm.at[pages].set(sm.astype(pm.dtype))
-                return jnp.moveaxis(pm, 1, 1 + ax)
+                pre = stage_leaf.shape[:ax]
+                x = stage_leaf.reshape(pre + (P,) + pool_leaf.shape[-2:])
+                x = jnp.moveaxis(x, ax, 0)    # (P,) + pre + (ps, F)
+                return pool_leaf.at[pages].set(x.astype(pool_leaf.dtype))
 
-            return jax.tree.map(c, pool, stage, self.axes)
+            return jax.tree.map(c, self.axes, pool, stage)
 
         return jax.jit(serve_page_commit)
 
     def build_decode(self, decode_vmapped, bucket: int, P: int):
         """Jitted paged decode for one ``(slot-bucket, page-bucket)``
-        lattice point: gather views, run the vmapped step, scatter the
-        single written row of every leaf back into its page.
+        lattice point: gather views, run the vmapped step, write the
+        single new row of every leaf back into its page, in place, one
+        ``dynamic_update_slice`` per batch row.
 
         The engine pads the batch to ``bucket`` by duplicating an
         active request's (table, length, token) row — duplicates
-        compute identical updates, so the row scatter is
+        compute identical updates, so the row writes are
         value-deterministic (same rule as the unpaged slot scatter).
 
-        The gather, the model step and the row scatter run under the
+        The gather, the model step and the row writes run under the
         name scopes ``kv_view``, ``decode_model`` and ``kv_write``, which
         reach each op's ``op_name`` metadata and change nothing else in
         the compiled program.  So JAX's persistent compilation cache,
@@ -418,7 +441,7 @@ class PagedKV:
         ``jax_compilation_cache_include_metadata_in_key`` is set, may
         load an executable carrying the metadata of an older compile."""
         import jax
-        import jax.numpy as jnp
+        from jax import lax
 
         ps = self.page_size
 
@@ -428,24 +451,24 @@ class PagedKV:
             with jax.named_scope("decode_model"):
                 logits, new_view = decode_vmapped(params, view, toks)
 
-            def s(pool_leaf, new_leaf, ax):
+            def s(ax, pool_leaf, new_leaf):
                 if ax < 0:
                     return pool_leaf
-                B = lengths.shape[0]
-                sel = lengths.reshape((B,) + (1,) * (new_leaf.ndim - 1))
-                row = jnp.take_along_axis(new_leaf, sel, axis=1 + ax)
-                pages = jnp.take_along_axis(
-                    tables, (lengths // ps)[:, None], axis=1)[:, 0]
-                # flatten (page, row-in-page) into global rows to scatter
-                pm = jnp.moveaxis(pool_leaf, 1 + ax, 1)
-                flat = pm.reshape((pm.shape[0] * pm.shape[1],) + pm.shape[2:])
-                rowm = jnp.moveaxis(row, 1 + ax, 1)[:, 0]
-                flat = flat.at[pages * ps + lengths % ps].set(
-                    rowm.astype(flat.dtype))
-                return jnp.moveaxis(flat.reshape(pm.shape), 1, 1 + ax)
+                pre, post = new_leaf.shape[1:1 + ax], new_leaf.shape[2 + ax:]
+                for b in range(new_leaf.shape[0]):
+                    n = lengths[b]
+                    row = lax.dynamic_slice(
+                        new_leaf, (b,) + (0,) * ax + (n,) + (0,) * len(post),
+                        (1,) + pre + (1,) + post)
+                    pool_leaf = lax.dynamic_update_slice(
+                        pool_leaf,
+                        row.reshape((1,) + pre + (1, -1)).astype(
+                            pool_leaf.dtype),
+                        (tables[b, n // ps],) + (0,) * ax + (n % ps, 0))
+                return pool_leaf
 
             with jax.named_scope("kv_write"):
-                new_pool = jax.tree.map(s, pool, new_view, self.axes)
+                new_pool = jax.tree.map(s, self.axes, pool, new_view)
             return logits, new_pool
 
         return jax.jit(serve_decode)

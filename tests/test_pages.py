@@ -361,3 +361,158 @@ def test_paged_guardrails(served):
     with pytest.raises(NotImplementedError, match="sharded"):
         ServingRuntime(cfg, params, slots=2, max_len=32, paged=True,
                        precompile=False, mesh=object())
+
+
+# ------------------------------------------- PagedKV gather / commit / write
+KV_ROWS = 4                                    # rows per page
+KV_LATTICE = BucketLattice(2, max_chunk=8, max_pages=8).page_buckets
+
+
+@pytest.fixture(scope="module", params=["bf16", "kv_quant"])
+def paged_kv(request):
+    """A pool of 2 x 8 pages + the null page, every row of every leaf
+    filled with distinct small integers (exact in bf16 and int8)."""
+    from repro.configs import get_config
+    from repro.runtime.pages import PagedKV
+
+    cfg = get_config("minicpm-2b", smoke=True).with_(
+        n_periods=2, kv_quant=request.param == "kv_quant")
+    kv = PagedKV(cfg, n_pages=2 * max(KV_LATTICE) + 1, page_size=KV_ROWS)
+    rng = np.random.default_rng(3)
+    kv.pool = jax.tree.map(
+        lambda x: jax.numpy.asarray(rng.integers(-99, 99, x.shape), x.dtype),
+        kv.pool)
+    return kv
+
+
+def _tables(kv, P, lengths, seed=0):
+    """(B, P) page tables covering each length's rows with distinct
+    pages, padded with the null page."""
+    rng = np.random.default_rng(seed)
+    free = list(rng.permutation(np.arange(1, kv.n_pages)))
+    out = np.full((len(lengths), P), NULL_PAGE, np.int32)
+    for b, n in enumerate(lengths):
+        k = n // kv.page_size + 1
+        out[b, :k] = [free.pop() for _ in range(k)]
+    return out
+
+
+def _dense_view(kv, pool, tables, lengths):
+    """The reference view, page by page in NumPy: each request's pages
+    laid end to end along the token axis, rows unflattened."""
+    def view(ax, spec, leaf):
+        B = len(tables)
+        if ax < 0:
+            return np.broadcast_to(
+                np.reshape(lengths, (B,) + (1,) * spec.ndim),
+                (B,) + spec.shape).astype(spec.dtype)
+        leaf = np.asarray(leaf)
+        return np.stack([
+            np.concatenate([leaf[p] for p in row], axis=ax).reshape(
+                spec.shape[:ax] + (-1,) + spec.shape[ax + 1:])
+            for row in tables])
+
+    return jax.tree.map(view, kv.axes, kv.shapes, pool)
+
+
+def _assert_trees_equal(got, want):
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("P", KV_LATTICE)
+def test_gather_matches_dense_reference(paged_kv, P):
+    """The page-major pool's views equal a dense view built row by row
+    from the same pages, null-padded tables included."""
+    kv = paged_kv
+    lengths = np.asarray([P * kv.page_size - 1, (P * kv.page_size) // 3],
+                         np.int32)
+    tables = _tables(kv, P, lengths)
+    got = jax.jit(kv._gather)(kv.pool, jax.numpy.asarray(tables),
+                              jax.numpy.asarray(lengths))
+    _assert_trees_equal(got, _dense_view(kv, kv.pool, tables, lengths))
+
+
+@pytest.mark.parametrize("P", KV_LATTICE)
+def test_commit_then_gather_round_trips(paged_kv, P):
+    """A staged batch-1 cache committed into its pages reads back
+    unchanged through the view; pages outside the table are untouched."""
+    kv = paged_kv
+    n = max(1, P - 1)                  # real pages; the rest null-padded
+    pages = _tables(kv, P, [n * kv.page_size - 1], seed=P)[0]
+    rng = np.random.default_rng(P)
+
+    def staged(ax, spec):
+        shape = spec.shape if ax < 0 else (
+            spec.shape[:ax] + (P * kv.page_size,) + spec.shape[ax + 1:])
+        return jax.numpy.asarray(rng.integers(-99, 99, shape), spec.dtype)
+
+    stage = jax.tree.map(staged, kv.axes, kv.shapes)
+    pool = kv.build_commit(P)(kv.pool, stage, jax.numpy.asarray(pages))
+    length = np.asarray([7], np.int32)
+    view = kv.build_view(P)(pool, jax.numpy.asarray(pages[None]),
+                            jax.numpy.asarray(length))
+    rows = n * kv.page_size
+
+    def check(ax, spec, got, want, before, after):
+        if ax < 0:
+            np.testing.assert_array_equal(np.asarray(got), 7)
+            return
+        keep = (slice(None),) * ax + (slice(0, rows),)
+        np.testing.assert_array_equal(np.asarray(got)[keep],
+                                      np.asarray(want)[keep])
+        untouched = np.setdiff1d(np.arange(kv.n_pages), pages)
+        np.testing.assert_array_equal(np.asarray(after)[untouched],
+                                      np.asarray(before)[untouched])
+
+    jax.tree.map(check, kv.axes, kv.shapes, view, stage, kv.pool, pool)
+
+
+def _marking_step(kv):
+    """A stand-in for the vmapped model step: writes ``token + 1`` into
+    row ``length`` of every token leaf and returns the token as logits."""
+    from jax import lax
+
+    def step(params, cache, tok):
+        def w(ax, leaf):
+            if ax < 0:
+                return leaf
+            row = jax.numpy.full(leaf.shape[:ax] + (1,) + leaf.shape[ax + 1:],
+                                 tok[0, 0] + 1, leaf.dtype)
+            return lax.dynamic_update_slice_in_dim(leaf, row, cache["length"],
+                                                   axis=ax)
+
+        return tok[:, 0].astype(np.float32), jax.tree.map(w, kv.axes, cache)
+
+    return jax.vmap(step, in_axes=(None, 0, 0))
+
+
+@pytest.mark.parametrize("P", KV_LATTICE)
+def test_decode_writes_one_row_per_request(paged_kv, P):
+    """The decode's row write lands at ``(tables[b, len // ps], len %
+    ps)`` and nowhere else, with the batch padded by a duplicate of
+    request 0 as the engine pads it."""
+    kv = paged_kv
+    lengths = np.asarray([P * kv.page_size - 2, (P * kv.page_size) // 2],
+                         np.int32)
+    tables = _tables(kv, P, lengths, seed=7)
+    toks = np.asarray([[[5]], [[9]]], np.int32)
+    pad = lambda a: np.concatenate([a, a[:1]])            # noqa: E731
+    step = kv.build_decode(_marking_step(kv), 3, P)
+    logits, pool = step(None, kv.pool, *map(jax.numpy.asarray, (
+        pad(tables), pad(lengths), pad(toks))))
+    np.testing.assert_array_equal(np.asarray(logits)[:, 0], [5, 9, 5])
+
+    def check(ax, before, after):
+        if ax < 0:
+            assert before is None and after is None
+            return
+        want = np.array(before)
+        for b, n in enumerate(lengths):
+            page, row = tables[b, n // kv.page_size], n % kv.page_size
+            want[(page,) + (slice(None),) * ax + (row,)] = toks[b, 0, 0] + 1
+        np.testing.assert_array_equal(np.asarray(after), want)
+
+    jax.tree.map(check, kv.axes, kv.pool, pool)

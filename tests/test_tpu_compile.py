@@ -17,6 +17,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -141,23 +142,22 @@ def _strip_metadata(hlo: str) -> list[str]:
     return out
 
 
-def test_scoped_serve_decode_compiles_to_the_same_program(one_chip,
-                                                         monkeypatch):
-    """The paged decode at the minicpm-2b decode-batch cell's lattice
-    point (2 slots, page bucket 32, pages of 16, a 1,792-row pool) at
-    published widths, 2 layers of 40 (every layer carries the same
-    scopes): the ``kv_view`` / ``decode_model`` / ``kv_write`` name
-    scopes change the ops' metadata and nothing else."""
+#: the minicpm-2b decode-batch cell's lattice point: 2 slots, page bucket
+#: 32, pages of 16 rows, a 1,792-row pool per slot
+SLOTS, PAGES, PAGE_ROWS, POOL_ROWS = 2, 32, 16, 1792
+
+
+def _paged_steps(one_chip):
+    """(PagedKV, abstract decode arguments, vmapped decode) for minicpm-2b
+    at published widths, 2 layers of 40 (every layer is the same)."""
     from repro.configs import get_config
     from repro.models.transformer import decode_step, init_params
     from repro.runtime.pages import PagedKV
 
-    monkeypatch.setattr("repro.kernels.sb_gemm.interpret_mode",
-                        lambda *arrays: False)
     cfg = get_config("minicpm-2b", n_periods=2, dtype="bfloat16",
                      param_dtype="bfloat16")
-    kv = PagedKV(cfg, n_pages=2 * (1792 // 16) + 1, page_size=16)
-    bucket, P = 2, 32
+    kv = PagedKV(cfg, n_pages=SLOTS * (POOL_ROWS // PAGE_ROWS) + 1,
+                 page_size=PAGE_ROWS)
 
     def aval(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
@@ -165,14 +165,26 @@ def test_scoped_serve_decode_compiles_to_the_same_program(one_chip,
     params = jax.tree.map(aval, jax.eval_shape(
         lambda k: init_params(k, cfg), jax.random.key(0)))
     args = (params, jax.tree.map(aval, kv.pool),
-            aval(jnp.zeros((bucket, P), jnp.int32)),
-            aval(jnp.zeros((bucket,), jnp.int32)),
-            aval(jnp.zeros((bucket, 1, 1), jnp.int32)))
+            aval(jnp.zeros((SLOTS, PAGES), jnp.int32)),
+            aval(jnp.zeros((SLOTS,), jnp.int32)),
+            aval(jnp.zeros((SLOTS, 1, 1), jnp.int32)))
     vm = jax.vmap(lambda p, c, t: decode_step(cfg, p, c, t),
                   in_axes=(None, 0, 0))
+    return kv, args, vm
+
+
+def test_scoped_serve_decode_compiles_to_the_same_program(one_chip,
+                                                         monkeypatch):
+    """The paged decode at the decode-batch cell's lattice point: the
+    ``kv_view`` / ``decode_model`` / ``kv_write`` name scopes change the
+    ops' metadata and nothing else."""
+    monkeypatch.setattr("repro.kernels.sb_gemm.interpret_mode",
+                        lambda *arrays: False)
+    kv, args, vm = _paged_steps(one_chip)
 
     def compiled():
-        return kv.build_decode(vm, bucket, P).lower(*args).compile().as_text()
+        return kv.build_decode(vm, SLOTS, PAGES).lower(
+            *args).compile().as_text()
 
     scoped = compiled()
     assert 'op_name="jit(serve_decode)/kv_view/' in scoped
@@ -182,3 +194,68 @@ def test_scoped_serve_decode_compiles_to_the_same_program(one_chip,
     assert "kv_view" not in plain
     ops = _strip_metadata(scoped)
     assert len(ops) > 100 and ops == _strip_metadata(plain)
+
+
+#: an array-shaped HLO value: ``bf16[225,2,1,16,2304]{4,3,2,1,0:T(8,128)...}``
+_ARRAY = re.compile(r"\b[a-z]+[0-9]*\[([0-9,]*)\]\{([0-9,]*)[:}]")
+#: one instruction: name, result array, layout and memory space, opcode
+_INSTR = re.compile(r"^\s*(?:ROOT )?%\S+ = [a-z]+[0-9]*\[([0-9,]*)\]"
+                    r"\{([0-9,]*)(:[^}]*)?\} ([a-z-]+)\(", re.M)
+
+
+def _dims(text: str) -> tuple[int, ...]:
+    return tuple(int(d) for d in text.split(",") if d)
+
+
+def _row_major(dims, minor_to_major) -> bool:
+    """Whether a layout stores ``dims`` in their logical order, the
+    first dimension outermost (unit dimensions may sit anywhere)."""
+    order = [d for d in minor_to_major if dims[d] != 1]
+    return order == sorted(order, reverse=True)
+
+
+def test_paged_pool_is_page_major_and_never_relaid_out(one_chip,
+                                                      monkeypatch):
+    """The pool's token leaves enter the compiled decode and commit
+    page-major and lane-dense (the row's features, a multiple of 128, on
+    the lanes), and neither program holds a copy of a whole pool leaf
+    into another layout: every value of a pool leaf's size keeps its
+    row-major layout, and each leaf is copied into device memory at
+    most once, the out-of-place result."""
+    monkeypatch.setattr("repro.kernels.sb_gemm.interpret_mode",
+                        lambda *arrays: False)
+    kv, args, vm = _paged_steps(one_chip)
+    pool = args[1]
+    leaves = jax.tree.leaves(pool)
+    assert leaves and all(x.shape[0] == kv.n_pages and x.shape[-1] % 128 == 0
+                          for x in leaves)
+    sizes = {x.size for x in leaves}
+    stage = jax.eval_shape(kv.build_view(PAGES), pool,
+                           jnp.zeros((1, PAGES), jnp.int32),
+                           jnp.zeros((1,), jnp.int32))
+    stage = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=one_chip), stage)
+    programs = {
+        "serve_decode": kv.build_decode(vm, SLOTS, PAGES).lower(*args),
+        "serve_page_commit": kv.build_commit(PAGES).lower(
+            pool, stage, jax.ShapeDtypeStruct((PAGES,), jnp.int32,
+                                              sharding=one_chip)),
+    }
+    for name, lowered in programs.items():
+        text = lowered.compile().as_text()
+        entry = text.split("entry_computation_layout={", 1)[1].split("\n")[0]
+        entered = [(_dims(d), _dims(m)) for d, m in _ARRAY.findall(entry)
+                   if _dims(d) == leaves[0].shape]
+        assert len(entered) >= len(leaves), (name, entry[:400])
+        for dims, layout in entered:
+            assert layout[-1] == 0 and _row_major(dims, layout), (name,
+                                                                  layout)
+        copies = 0
+        for d, m, space, opcode in _INSTR.findall(text):
+            dims, layout = _dims(d), _dims(m)
+            if int(np.prod(dims)) not in sizes:
+                continue
+            assert _row_major(dims, layout), (name, opcode, dims, layout)
+            # a copy into another memory space (S(n)) is not a result
+            copies += opcode in ("copy", "copy-done") and "S(" not in space
+        assert copies <= len(leaves), (name, copies)

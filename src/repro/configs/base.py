@@ -7,7 +7,23 @@ from typing import Literal
 
 import jax.numpy as jnp
 
-__all__ = ["LayerSpec", "MoEConfig", "SSMConfig", "FrontendConfig", "ModelConfig"]
+__all__ = ["YaRN", "LayerSpec", "MoEConfig", "SSMConfig", "FrontendConfig",
+           "ModelConfig"]
+
+
+@dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN rope scaling (transformers' ``_compute_yarn_parameters``):
+    frequencies blended between interpolated and original by a linear
+    ramp over the rotation counts ``beta_fast``..``beta_slow`` at
+    ``original_max_positions``, cos and sin scaled by
+    ``attention_factor`` (default ``0.1 ln(factor) + 1``)."""
+
+    factor: float
+    original_max_positions: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float | None = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -17,17 +33,27 @@ class LayerSpec:
     mixer: Literal["attn", "mamba"] = "attn"
     ff: Literal["dense", "moe", "none"] = "dense"
     window: int | None = None  # sliding-window size for local attention
+    yarn: YaRN | None = None   # rope scaling of this layer (None: plain)
 
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int = 8
+    n_experts: int = 8            # published experts: the router's width
     top_k: int = 2
     d_expert: int = 1024          # per-expert FFN hidden size
     n_shared: int = 0             # shared (always-on) experts
     d_shared: int = 0             # hidden size of the shared expert block
     capacity_factor: float = 1.25
     router_noise: float = 0.0
+    #: the experts this chip holds, ``first_held .. first_held + n_held
+    #: - 1`` (None: all): an expert-parallel share.  Serving routes over
+    #: all ``n_experts`` and adds only the held experts' terms.
+    n_held: int | None = None
+    first_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_experts if self.n_held is None else self.n_held
 
 
 @dataclasses.dataclass(frozen=True)
@@ -140,7 +166,7 @@ class ModelConfig:
                 if active_only:
                     n += moe.top_k * per_expert
                 else:
-                    n += moe.n_experts * per_expert
+                    n += moe.held * per_expert
                 if moe.n_shared:
                     n += moe.n_shared * mult * E * (moe.d_shared or moe.d_expert)
         return n

@@ -19,6 +19,7 @@ from repro.configs import (
     jamba_v0p1_52b,
     kimi_k2_1t,
     mamba2_1p3b,
+    mellum2_12b,
     minicpm_2b,
     qwen2_moe_a2p7b,
 )
@@ -35,6 +36,7 @@ ARCHS = {
     "granite-20b": granite_20b.make_config,
     "gemma2-27b": gemma2_27b.make_config,
     "minicpm-2b": minicpm_2b.make_config,
+    "mellum2-12b-a2.5b": mellum2_12b.make_config,
     "internlm2-20b": internlm2_20b.make_config,
     "hubert-xlarge": hubert_xlarge.make_config,
 }
@@ -65,7 +67,7 @@ def shrink(cfg: ModelConfig) -> ModelConfig:
         changes["moe"] = dataclasses.replace(
             cfg.moe, n_experts=4, top_k=min(cfg.moe.top_k, 2), d_expert=32,
             d_shared=32 if cfg.moe.n_shared else 0,
-            n_shared=min(cfg.moe.n_shared, 2),
+            n_shared=min(cfg.moe.n_shared, 2), n_held=None, first_held=0,
         )
     if cfg.ssm is not None:
         changes["ssm"] = dataclasses.replace(

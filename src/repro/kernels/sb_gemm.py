@@ -49,6 +49,7 @@ from repro.kernels.addressing import (
 __all__ = [
     "native_gemm_pallas", "DEFAULT_TILES",
     "VMEM_LIMIT_BYTES", "block_vmem_bytes", "interpret_mode",
+    "expert_swiglu_pallas", "expert_order",
 ]
 
 #: scoped VMEM each kernel asks the compiler for: half of a TPU v5e
@@ -184,3 +185,101 @@ def native_gemm_pallas(
         ),
         interpret=interpret,
     )(A, B)
+
+
+# ------------------------------------------------------------ expert FFN
+def expert_order(touched):
+    """(order, n): the held experts with at least one token first, in
+    ascending order, then the last of them repeated; ``n`` of them.
+    Repeating a block index makes the pipeline skip the weight copy, so
+    an expert no token chose costs no HBM read."""
+    X = touched.shape[0]
+    ar = jnp.arange(X, dtype=jnp.int32)
+    idx = jnp.argsort(jnp.where(touched, ar, X + ar)).astype(jnp.int32)
+    n = jnp.sum(touched.astype(jnp.int32))
+    order = jnp.where(ar < n, idx, idx[jnp.maximum(n - 1, 0)])
+    return order, n.reshape(1)
+
+
+def _expert_kernel(order_ref, n_ref, x_ref, c_ref, wi_ref, wg_ref, wo_ref,
+                   o_ref, acc_ref, *, upcast: bool):
+    """Grid step i: accumulate ``(c * silu(x Wg) * (x Wi)) Wo`` of the
+    i-th touched expert."""
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(i < n_ref[0])
+    def _step():
+        x, wi, wg, wo = x_ref[...], wi_ref[...], wg_ref[...], wo_ref[...]
+        if upcast:  # interpret-on-CPU only: XLA:CPU lacks bf16 dot thunks
+            x, wi, wg, wo = (a.astype(jnp.float32) for a in (x, wi, wg, wo))
+        g = jnp.dot(x, wg, preferred_element_type=jnp.float32)
+        u = jnp.dot(x, wi, preferred_element_type=jnp.float32)
+        h = jax.nn.silu(g) * u * c_ref[...][:, :1]
+        acc_ref[...] += jnp.dot(h.astype(wo.dtype), wo,
+                                preferred_element_type=jnp.float32)
+
+    @pl.when(i == pl.num_programs(0) - 1)
+    def _emit():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def expert_swiglu_pallas(x, comb, wi, wg, wo, *, interpret: bool | None = None):
+    """Dropless SwiGLU over held experts: ``sum_e comb[:, e] * (silu(x
+    Wg_e) * (x Wi_e)) Wo_e``, one kernel.
+
+    The paper's strided-batched GEMM with the expert as batch mode: each
+    grid step reads one expert's weights, at the expert's stride, once,
+    and applies them to every token row; a token's weight for an expert
+    that did not choose it is zero.  So no token is dropped whatever the
+    routing, no weight is gathered per token, and an expert no token
+    chose is skipped (:func:`expert_order`).  The rows are bounded by the
+    token group, which serving keeps at one decode batch or one prefill
+    chunk, where reading the weights costs more than the padded rows'
+    operations.  A step holds one whole expert (three 2304 x 896 bf16
+    matrices at published widths, 12.4 MB, double-buffered).
+
+    ``x``: (T, E); ``comb``: (T, X) float32 combine weights; ``wi``,
+    ``wg``: (X, E, F); ``wo``: (X, F, E).  Returns (T, E) in ``x``'s
+    dtype, accumulated in float32.
+    """
+    if interpret is None:
+        interpret = interpret_mode(x, wi)
+    T, E = x.shape
+    X, _, F = wi.shape
+    rows = -(-T // 16) * 16          # bf16 sublane tile
+    order, n = expert_order(jnp.any(comb > 0, axis=0))
+    xp = jnp.pad(x, ((0, rows - T), (0, 0)))
+    # one weight per (expert, row), repeated over a lane tile
+    cp = jnp.broadcast_to(
+        jnp.pad(comb.astype(jnp.float32), ((0, rows - T), (0, 0))).T[..., None],
+        (X, rows, 128))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(X,),
+        in_specs=[
+            pl.BlockSpec((rows, E), lambda i, o, n: (0, 0)),
+            pl.BlockSpec((None, rows, 128), lambda i, o, n: (o[i], 0, 0)),
+            pl.BlockSpec((None, E, F), lambda i, o, n: (o[i], 0, 0)),
+            pl.BlockSpec((None, E, F), lambda i, o, n: (o[i], 0, 0)),
+            pl.BlockSpec((None, F, E), lambda i, o, n: (o[i], 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((rows, E), lambda i, o, n: (0, 0)),
+        scratch_shapes=[pltpu.VMEM((rows, E), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        functools.partial(_expert_kernel,
+                          upcast=interpret and x.dtype != jnp.float32),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rows, E), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
+        interpret=interpret,
+        name="expert_swiglu",
+    )(order, n, xp, cp, wi, wg, wo)
+    return out[:T]

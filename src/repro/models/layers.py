@@ -10,6 +10,7 @@ Attention's QKᵀ/PV products *are* strided-batched GEMMs (batch =
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -19,7 +20,7 @@ from repro.core.einsum import xeinsum
 from repro.distributed.sharding import logical
 
 __all__ = [
-    "rms_norm", "rope", "attention", "mlp", "init_attn", "init_mlp",
+    "rms_norm", "rope", "rope_freqs", "attention", "mlp", "init_attn", "init_mlp",
     "dense", "init_dense", "softcap",
 ]
 
@@ -53,13 +54,50 @@ def init_rms(key, d):
 
 
 # ---------------------------------------------------------------- rope
-def rope(x, positions, theta: float = 10_000.0):
-    """Rotary embedding on the last axis of x: (..., seq, heads, head_dim)."""
-    d = x.shape[-1]
+def rope_freqs(d: int, theta: float, yarn=None):
+    """Inverse frequencies of a rope over ``d`` features, and the factor
+    cos and sin are scaled by.
+
+    ``yarn`` (a :class:`~repro.configs.base.YaRN`) follows transformers'
+    ``_compute_yarn_parameters``: dimension ``i`` keeps its original
+    frequency ``theta^(-2i/d)`` where it turns more than ``beta_fast``
+    times over ``original_max_positions``, is divided by ``factor``
+    where it turns fewer than ``beta_slow`` times, and blends linearly
+    between (correction range floored and ceiled, clipped to
+    ``[0, d - 1]``)."""
     half = d // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn is None:
+        return freqs, 1.0
+
+    def dim_of(rotations):
+        return (d * math.log(yarn.original_max_positions
+                             / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim_of(yarn.beta_slow)), d - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    freqs = freqs * (1.0 - ramp + ramp / yarn.factor)
+    scale = yarn.attention_factor
+    if scale is None:
+        scale = 0.1 * math.log(yarn.factor) + 1.0 if yarn.factor > 1 else 1.0
+    return freqs, scale
+
+
+def rope(x, positions, theta: float = 10_000.0, yarn=None):
+    """Rotary embedding on the last axis of x: (..., seq, heads, head_dim).
+    ``yarn``: the layer's rope scaling (:func:`rope_freqs`)."""
+    d = x.shape[-1]
+    half = d // 2
+    freqs, scale = rope_freqs(d, theta, yarn)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., seq, half)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None:
+        cos, sin = cos * scale, sin * scale
     cos = cos[..., None, :]  # broadcast over heads
     sin = sin[..., None, :]
     x1, x2 = x[..., :half], x[..., half:]
@@ -111,6 +149,7 @@ def attention(
     *,
     positions,              # (S,) token positions (for rope + causal mask)
     window: int | None = None,
+    yarn=None,              # the layer's rope scaling (None: plain rope)
     kv_cache=None,          # optional dict(k=(B,T,G,D), v=..., length=())
 ):
     """GQA/MQA attention.  Returns (out, new_kv_cache | None).
@@ -127,8 +166,9 @@ def attention(
     q = dense(cfg, x, params["wq"]).reshape(B, S, G, R, D)
     k = dense(cfg, x, params["wk"]).reshape(B, S, G, D)
     v = dense(cfg, x, params["wv"]).reshape(B, S, G, D)
-    q = rope(q.reshape(B, S, H, D), positions, cfg.rope_theta).reshape(B, S, G, R, D)
-    k = rope(k, positions, cfg.rope_theta)
+    q = rope(q.reshape(B, S, H, D), positions, cfg.rope_theta,
+             yarn).reshape(B, S, G, R, D)
+    k = rope(k, positions, cfg.rope_theta, yarn)
     q = logical(q, "batch", None, "kv_heads", None, None)
     k = logical(k, "batch", None, "kv_heads", None)
 

@@ -1,9 +1,22 @@
-"""Mixture-of-Experts with GShard-style grouped, capacity-based routing.
+"""Mixture-of-Experts: a dropless layer over the held experts (serving)
+and GShard-style grouped, capacity-based routing (training).
 
-Tokens are split into *groups* (sharded over the data axes); each group
-dispatches into per-expert capacity slots through one-hot dispatch/combine
-tensors — the einsum formulation GSPMD partitions into all-to-alls, rather
-than the sort/scatter formulation it can only replicate.
+Serving (:func:`moe_held`, every cached call: prefill chunks and decode
+steps) routes in float32 over all ``n_experts`` published experts,
+renormalises the top-k weights, and adds only the terms of the experts
+this chip holds (``MoEConfig.n_held`` from ``first_held``; an
+expert-parallel share, whose exchange with the other chips is not run).
+Its expert FFN is one Pallas kernel over the held experts
+(:func:`repro.kernels.sb_gemm.expert_swiglu_pallas`): no capacity, no
+dropped token, no weight gathered per token.  The decode step is vmapped
+over slots, so the layer carries its own batching rule: under ``vmap``
+the whole batch's tokens are one token group.
+
+Training (:func:`moe_ffn`) splits tokens into *groups* (sharded over
+the data axes); each group dispatches into per-expert capacity slots
+through one-hot dispatch/combine tensors — the einsum formulation GSPMD
+partitions into all-to-alls, rather than the sort/scatter formulation it
+can only replicate.
 
 The expert FFN itself is the paper's primitive incarnate: a strided-batched
 GEMM with the *expert* as batch mode — ``contract("xge,xef->xgf", ...)``
@@ -25,9 +38,11 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig, MoEConfig
 from repro.core.einsum import xeinsum
 from repro.distributed.sharding import logical
+from repro.kernels.sb_gemm import expert_swiglu_pallas
 from repro.models.layers import init_dense, init_mlp, mlp
 
-__all__ = ["init_moe", "moe_ffn", "router_aux_loss"]
+__all__ = ["init_moe", "moe_ffn", "moe_held", "route", "held_weights",
+           "router_aux_loss"]
 
 
 def _ctr(cfg: ModelConfig):
@@ -41,13 +56,14 @@ def init_moe(key, cfg: ModelConfig):
     E, F = cfg.d_model, m.d_expert
     dt = jnp.dtype(cfg.param_dtype)
     kr, k1, k2, k3, ks = jax.random.split(key, 5)
+    X = m.held  # the router spans all published experts, weights the held
     params = {
         "router": init_dense(kr, E, m.n_experts, jnp.float32),
-        "wi": (jax.random.normal(k1, (m.n_experts, E, F)) * E**-0.5).astype(dt),
-        "wo": (jax.random.normal(k3, (m.n_experts, F, E)) * F**-0.5).astype(dt),
+        "wi": (jax.random.normal(k1, (X, E, F)) * E**-0.5).astype(dt),
+        "wo": (jax.random.normal(k3, (X, F, E)) * F**-0.5).astype(dt),
     }
     if cfg.mlp_act == "swiglu":
-        params["wg"] = (jax.random.normal(k2, (m.n_experts, E, F)) * E**-0.5).astype(dt)
+        params["wg"] = (jax.random.normal(k2, (X, E, F)) * E**-0.5).astype(dt)
     if m.n_shared:
         sub = []
         for _ in range(m.n_shared):
@@ -84,10 +100,73 @@ def _dispatch_tensors(gates, top_w, top_e, n_experts: int, capacity: int):
     return dispatch, combine
 
 
+def route(m: MoEConfig, router, x):
+    """Float32 routing of tokens ``x`` (T, E) over all ``m.n_experts``:
+    softmax, top-k, renormalised.  Returns (weights, experts), each
+    (T, k)."""
+    logits = jnp.matmul(x.astype(jnp.float32), router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    top_w, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), m.top_k)
+    return top_w / jnp.sum(top_w, axis=-1, keepdims=True), top_e
+
+
+def held_weights(m: MoEConfig, top_w, top_e):
+    """(T, n_held) float32 combine weights of the held experts (zero
+    where a token did not choose the expert) and the (T, n_held) int32
+    count of each token's choices that land on them."""
+    local = top_e - m.first_held
+    chose = local[..., None] == jnp.arange(m.held)          # (T, k, X)
+    comb = jnp.sum(jnp.where(chose, top_w[..., None], 0.0), axis=1)
+    return comb, jnp.sum(chose, axis=1, dtype=jnp.int32)
+
+
+def _held_ffn(cfg: ModelConfig, params, x):
+    m: MoEConfig = cfg.moe
+    if cfg.mlp_act != "swiglu":
+        raise NotImplementedError("the held-expert kernel is SwiGLU")
+    B, S, E = x.shape
+    xt = x.reshape(B * S, E)
+    with jax.named_scope("moe_route"):
+        comb, held = held_weights(m, *route(m, params["router"], xt))
+    with jax.named_scope("moe_experts"):
+        y = expert_swiglu_pallas(
+            xt, comb, *(params[k].astype(x.dtype) for k in ("wi", "wg", "wo")))
+    y = y.reshape(B, S, E)
+    for i in range(m.n_shared):  # every chip computes these alike
+        y = y + mlp(cfg, jax.tree.map(lambda p, i=i: p[i], params["shared"]), x)
+    return y, held.reshape(B, S, -1)
+
+
+def moe_held(cfg: ModelConfig, params, x):
+    """The dropless held-expert layer.  x: (B, S, E) → (y (B, S, E),
+    held (B, S, n_held) int32: each token's choices among the held
+    experts).
+
+    Under ``vmap`` over ``x`` (the runtime's decode over slots) the
+    batch's tokens run as one group: one routing, one kernel launch,
+    each held expert's weights read once for all of them."""
+    fn = jax.custom_batching.custom_vmap(functools.partial(_held_ffn, cfg))
+
+    @fn.def_vmap
+    def _one_group(axis_size, in_batched, params, x):
+        p_batched, x_batched = in_batched
+        if any(jax.tree.leaves(p_batched)):
+            raise NotImplementedError("expert weights batched under vmap")
+        if not x_batched:
+            return fn(params, x), (False, False)
+        y, held = fn(params, x.reshape((-1,) + x.shape[2:]))
+        return (y.reshape(x.shape), held.reshape(x.shape[:-1] + (-1,))), (
+            True, True)
+
+    return fn(params, x)
+
+
 def moe_ffn(cfg: ModelConfig, params, x, *, capacity: int | None = None):
-    """x: (B, S, E) → (B, S, E), plus aux metrics dict."""
+    """x: (B, S, E) → (B, S, E), plus aux metrics dict (training)."""
     ctr = _ctr(cfg)
     m: MoEConfig = cfg.moe
+    if m.held != m.n_experts:
+        raise NotImplementedError("the capacity path holds every expert")
     B, S, E = x.shape
     T = B * S
     dt = x.dtype

@@ -19,7 +19,7 @@ from repro.core.einsum import xeinsum
 from repro.distributed.sharding import logical
 from repro.models import layers as L
 from repro.models.frontend import apply_frontend, init_frontend
-from repro.models.moe import init_moe, moe_ffn
+from repro.models.moe import init_moe, moe_ffn, moe_held
 from repro.models.ssm import init_mamba, init_ssm_cache, mamba_mixer
 
 __all__ = [
@@ -53,13 +53,17 @@ def _init_block(key, cfg: ModelConfig, spec: LayerSpec):
 
 
 def _block(cfg: ModelConfig, spec: LayerSpec, params, x, *, positions, cache=None):
-    """Pre-norm residual block.  Returns (x, new_cache, aux)."""
+    """Pre-norm residual block.  Returns (x, new_cache, aux).
+
+    A sparse layer runs the dropless held-expert layer on cached calls
+    (serving), whose aux is ``{"moe_held": (B, S, n_held) int32}``, and
+    the capacity-routed layer otherwise (training)."""
     aux = {}
     h = L.rms_norm(x, params["norm1"], cfg.rms_eps)
     if spec.mixer == "attn":
         out, new_cache = L.attention(
             cfg, params["attn"], h, positions=positions,
-            window=spec.window, kv_cache=cache,
+            window=spec.window, yarn=spec.yarn, kv_cache=cache,
         )
     else:
         out, new_cache = mamba_mixer(
@@ -70,6 +74,10 @@ def _block(cfg: ModelConfig, spec: LayerSpec, params, x, *, positions, cache=Non
         h = L.rms_norm(x, params["norm2"], cfg.rms_eps)
         if spec.ff == "dense":
             x = x + L.mlp(cfg, params["mlp"], h)
+        elif cache is not None:
+            y, held = moe_held(cfg, params["moe"], h)
+            x = x + y
+            aux = {"moe_held": held}
         else:
             y, aux = moe_ffn(cfg, params["moe"], h)
             x = x + y
@@ -124,13 +132,19 @@ def _embed_inputs(cfg: ModelConfig, params, batch):
 
 
 def _run_stack(cfg: ModelConfig, params, x, positions, cache=None, remat=False):
-    """Shared stack runner.  Returns (x, new_cache | None, aux)."""
+    """Shared stack runner.  Returns (x, new_cache | None, aux).
+
+    A cached call's aux is ``{"moe_held": (n_moe_layers, B, S, n_held)}``
+    in layer order for a stack with sparse layers, else empty."""
     aux_acc = {"load_balance_loss": jnp.zeros((), jnp.float32)}
-    new_prefix = []
+    new_prefix, prefix_held = [], []
     prefix_caches = cache["prefix"] if cache is not None else [None] * len(cfg.prefix)
     for spec, p, c in zip(cfg.prefix, params.get("prefix", []), prefix_caches):
         x, nc, aux = _block(cfg, spec, p, x, positions=positions, cache=c)
-        aux_acc = _acc_aux(aux_acc, aux)
+        if "moe_held" in aux:
+            prefix_held.append(aux["moe_held"][None])
+        else:
+            aux_acc = _acc_aux(aux_acc, aux)
         new_prefix.append(nc)
 
     if cache is None:
@@ -149,16 +163,18 @@ def _run_stack(cfg: ModelConfig, params, x, positions, cache=None, remat=False):
 
     def period_body_cached(x, inp):
         period_params, period_cache = inp
-        new_caches = []
+        new_caches, held = [], []
         for j, spec in enumerate(cfg.pattern):
-            x, nc, _ = _block(
+            x, nc, aux = _block(
                 cfg, spec, period_params[j], x, positions=positions,
                 cache=period_cache[j],
             )
             new_caches.append(nc)
-        return x, new_caches
+            if "moe_held" in aux:
+                held.append(aux["moe_held"])
+        return x, (new_caches, jnp.stack(held) if held else None)
 
-    x, new_pattern = jax.lax.scan(
+    x, (new_pattern, held) = jax.lax.scan(
         period_body_cached, x, (params["pattern"], cache["pattern"])
     )
     new_cache = {
@@ -166,7 +182,10 @@ def _run_stack(cfg: ModelConfig, params, x, positions, cache=None, remat=False):
         "pattern": new_pattern,
         "length": cache["length"] + positions.shape[0],
     }
-    return x, new_cache, aux_acc
+    if held is not None:   # (periods, sparse layers of a period, ...)
+        prefix_held.append(held.reshape((-1,) + held.shape[2:]))
+    aux = {"moe_held": jnp.concatenate(prefix_held)} if prefix_held else {}
+    return x, new_cache, aux
 
 
 def _lm_head(cfg: ModelConfig, params, x):
@@ -189,12 +208,13 @@ def forward(cfg: ModelConfig, params, batch, *, remat: bool = True):
     return _lm_head(cfg, params, x), aux
 
 
-def prefill(cfg: ModelConfig, params, batch, cache):
+def prefill(cfg: ModelConfig, params, batch, cache, *, stats: bool = False):
     """Serving prefill: runs the prompt, fills the cache.
 
-    Returns (last_logits (B, V), new_cache).  Only the last position hits
-    the LM head — at 32k prompts the full-seq logits tensor must never be
-    materialized.
+    Returns (last_logits (B, V), new_cache), and with ``stats`` the
+    cached stack's aux (``moe_held``: see :func:`_run_stack`).  Only the
+    last position hits the LM head — at 32k prompts the full-seq logits
+    tensor must never be materialized.
 
     Positions continue from ``cache["length"]``, so a prompt may be
     prefilled in chunks (the runtime's chunked prefill): each chunk sees
@@ -203,8 +223,9 @@ def prefill(cfg: ModelConfig, params, batch, cache):
     ``arange`` behavior."""
     x = _embed_inputs(cfg, params, batch)
     positions = cache["length"] + jnp.arange(x.shape[1])
-    x, new_cache, _ = _run_stack(cfg, params, x, positions, cache=cache)
-    return _lm_head(cfg, params, x[:, -1:])[:, -1], new_cache
+    x, new_cache, aux = _run_stack(cfg, params, x, positions, cache=cache)
+    out = _lm_head(cfg, params, x[:, -1:])[:, -1], new_cache
+    return (*out, aux) if stats else out
 
 
 def lm_loss(cfg: ModelConfig, params, batch, *, remat: bool = True,
@@ -261,15 +282,18 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=None):
     return {"prefix": prefix, "pattern": pattern, "length": jnp.zeros((), jnp.int32)}
 
 
-def decode_step(cfg: ModelConfig, params, cache, tokens):
-    """One decode step.  tokens: (B, 1).  Returns (logits (B, V), new_cache)."""
+def decode_step(cfg: ModelConfig, params, cache, tokens, *, stats: bool = False):
+    """One decode step.  tokens: (B, 1).  Returns (logits (B, V),
+    new_cache), and with ``stats`` the cached stack's aux (``moe_held``:
+    see :func:`_run_stack`)."""
     if cfg.encoder_only:
         raise ValueError(f"{cfg.arch_id} is encoder-only: no decode step")
     dt = cfg.activation_dtype()
     x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
     pos = cache["length"][None]
-    x, new_cache, _ = _run_stack(cfg, params, x, pos, cache=cache)
-    return _lm_head(cfg, params, x)[:, -1], new_cache
+    x, new_cache, aux = _run_stack(cfg, params, x, pos, cache=cache)
+    out = _lm_head(cfg, params, x)[:, -1], new_cache
+    return (*out, aux) if stats else out
 
 
 class Model:

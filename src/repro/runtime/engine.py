@@ -184,8 +184,14 @@ class ServingRuntime:
         else:
             self.cache = self._slot_cache()
         self._tokens = np.zeros((slots, 1, 1), np.int32)
+        #: a sparse model's steps also return their routing counts
+        #: (``moe_held``), pulled with the tokens (:meth:`_pull`)
+        self._moe_layers = sum(s.ff == "moe" for s in cfg.layers)
+        self._stats = self._moe_layers > 0
+        self._moe_pending: list = []     # prefill counts not yet pulled
         self._decode_vmapped = jax.vmap(
-            lambda p, c, t: decode_step(cfg, p, c, t), in_axes=(None, 0, 0)
+            lambda p, c, t: decode_step(cfg, p, c, t, stats=self._stats),
+            in_axes=(None, 0, 0)
         )
         self.tuner = tuner
         self.pretune_stats: dict | None = None
@@ -406,18 +412,18 @@ class ServingRuntime:
         else:
             def serve_decode_dense(p, cache, toks, idx):
                 sub = jax.tree.map(lambda x: x[idx], cache)
-                logits, new_sub = vm(p, sub, toks[idx])
+                logits, new_sub, *stats = vm(p, sub, toks[idx])
                 cache = jax.tree.map(
                     lambda full, ns: full.at[idx].set(ns), cache, new_sub
                 )
-                return logits, cache
+                return (logits, cache, *stats)
         return jax.jit(serve_decode_dense)
 
     def _build_prefill(self):
-        cfg = self.cfg
+        cfg, stats = self.cfg, self._stats
 
         def serve_prefill(p, toks, c):
-            return prefill(cfg, p, {"tokens": toks}, c)
+            return prefill(cfg, p, {"tokens": toks}, c, stats=stats)
 
         return jax.jit(serve_prefill)
 
@@ -536,11 +542,14 @@ class ServingRuntime:
         key = self.buckets.key("prefill", size, self._fingerprint())
         fn = self.buckets.get(key, self._build_prefill)
         with self._mesh_ctx():
-            logits, state.cache = fn(self.params, toks, state.cache)
+            logits, state.cache, *stats = fn(self.params, toks, state.cache)
+        if stats:
+            self._moe_pending.append(stats[0]["moe_held"])
         state.pos += chunk
         self.metrics.on_prefill_chunk(chunk)
         if state.remaining_prompt == 0:
             first = self._sample(state, logits[0])
+            self._pull()
             state.request.output.append(first)
             self._tokens[state.slot, 0, 0] = first
             if self.paged:
@@ -674,7 +683,7 @@ class ServingRuntime:
                 fn, args, rows = self._decode_inputs_dense(decodes, bucket)
         with _span("decode_launch", "runtime", decodes):
             with self._mesh_ctx():
-                logits, out = fn(self.params, *args)
+                logits, out, *stats = fn(self.params, *args)
         if self.paged:
             self.kv.pool = out
         else:
@@ -682,12 +691,16 @@ class ServingRuntime:
         with _span("decode_sync", "runtime", decodes):
             if self.logits_probe is not None:
                 self.logits_probe(logits)
+            held = stats[0]["moe_held"] if stats else None
             if self.greedy:
-                nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+                nxt, held = self._pull(jnp.argmax(logits[:, 0], axis=-1), held)
                 toks = [int(nxt[r]) for r in rows]
             else:
                 toks = [self._sample(s, logits[r, 0])
                         for s, r in zip(decodes, rows)]
+                _, held = self._pull(None, held)
+            if held is not None:   # (rows, layers, 1, 1, held experts)
+                self._count_moe("decode", held[rows, :, 0, 0])
         with _span("emit", "runtime", decodes):
             self.metrics.on_decode(n, bucket)
             for state, tok in zip(decodes, toks):
@@ -695,6 +708,27 @@ class ServingRuntime:
                 self._tokens[state.slot, 0, 0] = tok
                 self.metrics.on_token()
                 self._maybe_finish(state)
+
+    def _pull(self, value=None, held=None):
+        """``value`` and a decode's routing counts ``held`` on the host,
+        with every prefill chunk's counts still on the device, in one
+        transfer, made where the runtime waits for the device anyway (a
+        token it samples); the prefill counts go to the metrics."""
+        value, held, pending = jax.device_get(
+            (value, held, self._moe_pending))
+        self._moe_pending = []
+        for h in pending:          # (layers, 1, chunk, held experts)
+            self._count_moe("prefill", np.swapaxes(h[:, 0], 0, 1))
+        return value, held
+
+    def _count_moe(self, phase: str, held) -> None:
+        """Count one launch's routing: ``held`` (tokens, sparse layers,
+        held experts) holds each token's choices among the held
+        experts."""
+        tokens = held.shape[0]
+        self.metrics.on_moe(
+            phase, tokens * self.cfg.moe.top_k * self._moe_layers,
+            int(held.sum()), int(np.count_nonzero(held.sum(axis=0))))
 
     def _decode_inputs_dense(self, decodes: list[RequestState], bucket: int):
         """(step, arguments after the params, logits row of each request)
@@ -836,6 +870,7 @@ class ServingRuntime:
             if tick_callback is not None:
                 tick_callback(steps)
         self.metrics.stop()
+        self._pull()
         if self.scheduler.has_work():
             leftover = [s for s in list(self.scheduler.queue)
                         + list(self.scheduler.active.values())]

@@ -30,7 +30,12 @@ class ServingMetrics:
     snapped bucket).  On the paged path *KV liveness* counts rows: of
     the rows each decode launch gathers into its view (``bucket × P ×
     page_size``, ``kv_rows_viewed``), those its active requests attend
-    (length + 1 each, ``kv_rows_live``).
+    (length + 1 each, ``kv_rows_live``).  A sparse model counts its
+    routing, for decode and prefill apart (``moe_*_decode``,
+    ``moe_*_prefill``): the (token, expert) pairs routed over all its
+    sparse layers (``pairs_routed``), those that land on the experts this
+    chip holds (``pairs_held``), and the held experts with at least one
+    token, summed over layers and launches (``experts_touched``).
 
     **Event ordering is enforced.**  Per-request events are only
     honoured for a request with a live ``on_submit`` record, and a first
@@ -66,6 +71,10 @@ class ServingMetrics:
         self.prefix_shared_tokens = 0
         self.kv_rows_live = 0      # Σ rows attended over decode launches
         self.kv_rows_viewed = 0    # Σ rows gathered into decode views
+        self.moe = {f"moe_{what}_{phase}": 0
+                    for phase in ("decode", "prefill")
+                    for what in ("pairs_routed", "pairs_held",
+                                 "experts_touched")}
         self._pool_free_min: int | None = None   # high-water memory pressure
         self._pool_used = 0.0      # Σ used fraction over gauge samples
         self._pool_samples = 0
@@ -153,6 +162,13 @@ class ServingMetrics:
         self.kv_rows_live += int(live)
         self.kv_rows_viewed += int(viewed)
 
+    def on_moe(self, phase: str, routed: int, held: int,
+               touched: int) -> None:
+        """One launch's routing (``phase`` "decode" or "prefill")."""
+        self.moe[f"moe_pairs_routed_{phase}"] += int(routed)
+        self.moe[f"moe_pairs_held_{phase}"] += int(held)
+        self.moe[f"moe_experts_touched_{phase}"] += int(touched)
+
     def on_tick(self, n_active: int) -> None:
         self.ticks += 1
         self._occupancy += n_active / self.slots
@@ -201,6 +217,7 @@ class ServingMetrics:
             "prefix_shared_tokens": self.prefix_shared_tokens,
             "kv_rows_live": self.kv_rows_live,
             "kv_rows_viewed": self.kv_rows_viewed,
+            **self.moe,
             "pool_free_min": (
                 -1 if self._pool_free_min is None else self._pool_free_min
             ),

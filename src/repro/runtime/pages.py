@@ -55,6 +55,13 @@ __all__ = ["NULL_PAGE", "PagePool", "PagedKV", "PoolExhausted"]
 #: reserved scratch page: pads page tables up to the lattice width.
 NULL_PAGE = 0
 
+#: most whole-page slices a view unrolls (batch rows x pages x pooled
+#: leaves).  Compiling unrolled slices for v5e takes ~9 ms a slice
+#: (mellum2-12b-a2.5b's 8 x 224 x 8 view: 128 s on a CPU host), one XLA
+#: gather under a second; minicpm-2b's views unroll at most 2 x 112 x 2
+#: = 448 slices and keep the form measured fastest for it (PERF.md).
+MAX_UNROLLED_PAGES = 512
+
 
 class PoolExhausted(RuntimeError):
     """No free page — the caller decides who to preempt."""
@@ -365,23 +372,31 @@ class PagedKV:
         pool, and a loop of page copies runs slower at wide page
         buckets.  The pages are stacked with the layer axis ahead of the
         batch axis, the order in which the model's scan over layers
-        reads them, so the view needs no transpose of its own."""
+        reads them, so the view needs no transpose of its own.  Past
+        :data:`MAX_UNROLLED_PAGES` slices, whose compile would take
+        minutes, one gather with the same output order takes their
+        place."""
         import jax
         import jax.numpy as jnp
         from jax import lax
 
         B, P = tables.shape
         ids = [list(row) for row in tables]
+        leaves = sum(ax >= 0 for ax in jax.tree.leaves(self.axes))
+        unroll = B * P * leaves <= MAX_UNROLLED_PAGES
 
         def g(ax, spec, leaf):
             if ax < 0:
                 return jnp.broadcast_to(
                     lengths.reshape((B,) + (1,) * spec.ndim),
                     (B,) + spec.shape).astype(spec.dtype)
-            rows = [jnp.stack([lax.dynamic_index_in_dim(
-                leaf, i, keepdims=False, allow_negative_indices=False)
-                for i in row], axis=ax) for row in ids]  # pre + (P, ps, F)
-            x = jnp.moveaxis(jnp.stack(rows, axis=1), 1, 0)
+            if unroll:
+                rows = [jnp.stack([lax.dynamic_index_in_dim(
+                    leaf, i, keepdims=False, allow_negative_indices=False)
+                    for i in row], axis=ax) for row in ids]  # pre+(P,ps,F)
+                x = jnp.moveaxis(jnp.stack(rows, axis=1), 1, 0)
+            else:   # the same pages, one gather
+                x = jnp.moveaxis(leaf[tables], 1, 1 + ax)
             return x.reshape((B,) + spec.shape[:ax]
                              + (P * self.page_size,) + spec.shape[ax + 1:])
 
@@ -426,7 +441,9 @@ class PagedKV:
         """Jitted paged decode for one ``(slot-bucket, page-bucket)``
         lattice point: gather views, run the vmapped step, write the
         single new row of every leaf back into its page, in place, one
-        ``dynamic_update_slice`` per batch row.
+        ``dynamic_update_slice`` per batch row.  Returns ``(logits,
+        new_pool)`` and whatever the step returns after its cache (a
+        sparse model's routing counts).
 
         The engine pads the batch to ``bucket`` by duplicating an
         active request's (table, length, token) row — duplicates
@@ -449,7 +466,7 @@ class PagedKV:
             with jax.named_scope("kv_view"):
                 view = self._gather(pool, tables, lengths)
             with jax.named_scope("decode_model"):
-                logits, new_view = decode_vmapped(params, view, toks)
+                logits, new_view, *stats = decode_vmapped(params, view, toks)
 
             def s(ax, pool_leaf, new_leaf):
                 if ax < 0:
@@ -469,6 +486,6 @@ class PagedKV:
 
             with jax.named_scope("kv_write"):
                 new_pool = jax.tree.map(s, self.axes, pool, new_view)
-            return logits, new_pool
+            return (logits, new_pool, *stats)
 
         return jax.jit(serve_decode)

@@ -436,6 +436,22 @@ def test_gather_matches_dense_reference(paged_kv, P):
 
 
 @pytest.mark.parametrize("P", KV_LATTICE)
+def test_one_gather_matches_dense_reference(paged_kv, P, monkeypatch):
+    """Past ``MAX_UNROLLED_PAGES`` slices the view is one gather: the
+    same views as the unrolled slices."""
+    from repro.runtime import pages
+
+    monkeypatch.setattr(pages, "MAX_UNROLLED_PAGES", 0)
+    kv = paged_kv
+    lengths = np.asarray([P * kv.page_size - 1, (P * kv.page_size) // 3],
+                         np.int32)
+    tables = _tables(kv, P, lengths, seed=1)
+    got = jax.jit(kv._gather)(kv.pool, jax.numpy.asarray(tables),
+                              jax.numpy.asarray(lengths))
+    _assert_trees_equal(got, _dense_view(kv, kv.pool, tables, lengths))
+
+
+@pytest.mark.parametrize("P", KV_LATTICE)
 def test_commit_then_gather_round_trips(paged_kv, P):
     """A staged batch-1 cache committed into its pages reads back
     unchanged through the view; pages outside the table are untouched."""

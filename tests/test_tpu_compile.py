@@ -225,24 +225,30 @@ def test_paged_pool_is_page_major_and_never_relaid_out(one_chip,
     monkeypatch.setattr("repro.kernels.sb_gemm.interpret_mode",
                         lambda *arrays: False)
     kv, args, vm = _paged_steps(one_chip)
+    _assert_pool_never_relaid_out(kv, args, vm, SLOTS, PAGES, one_chip)
+
+
+def _assert_pool_never_relaid_out(kv, args, vm, slots, pages, one_chip):
+    """What the test above checks, for any config's paged decode."""
     pool = args[1]
     leaves = jax.tree.leaves(pool)
     assert leaves and all(x.shape[0] == kv.n_pages and x.shape[-1] % 128 == 0
                           for x in leaves)
     sizes = {x.size for x in leaves}
-    stage = jax.eval_shape(kv.build_view(PAGES), pool,
-                           jnp.zeros((1, PAGES), jnp.int32),
+    stage = jax.eval_shape(kv.build_view(pages), pool,
+                           jnp.zeros((1, pages), jnp.int32),
                            jnp.zeros((1,), jnp.int32))
     stage = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
         x.shape, x.dtype, sharding=one_chip), stage)
     programs = {
-        "serve_decode": kv.build_decode(vm, SLOTS, PAGES).lower(*args),
-        "serve_page_commit": kv.build_commit(PAGES).lower(
-            pool, stage, jax.ShapeDtypeStruct((PAGES,), jnp.int32,
+        "serve_decode": kv.build_decode(vm, slots, pages).lower(*args),
+        "serve_page_commit": kv.build_commit(pages).lower(
+            pool, stage, jax.ShapeDtypeStruct((pages,), jnp.int32,
                                               sharding=one_chip)),
     }
+    texts = {}
     for name, lowered in programs.items():
-        text = lowered.compile().as_text()
+        text = texts[name] = lowered.compile().as_text()
         entry = text.split("entry_computation_layout={", 1)[1].split("\n")[0]
         entered = [(_dims(d), _dims(m)) for d, m in _ARRAY.findall(entry)
                    if _dims(d) == leaves[0].shape]
@@ -259,3 +265,71 @@ def test_paged_pool_is_page_major_and_never_relaid_out(one_chip,
             # a copy into another memory space (S(n)) is not a result
             copies += opcode in ("copy", "copy-done") and "S(" not in space
         assert copies <= len(leaves), (name, copies)
+    return texts
+
+
+# ------------------------------------------------------- held experts
+#: mellum2-12b-a2.5b at published widths: 16 held experts of 2304 x 896
+EXPERTS, D_MODEL, D_EXPERT = 16, 2304, 896
+
+
+@pytest.mark.parametrize("rows", [8, 64])
+def test_expert_kernel_compiles_for_v5e(rows, one_chip):
+    """The held-expert SwiGLU at published widths, for a decode batch of
+    8 and a prefill chunk of 64 tokens."""
+    from repro.kernels.sb_gemm import expert_swiglu_pallas
+
+    def aval(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(lambda *a: expert_swiglu_pallas(*a, interpret=False)).lower(
+        aval((rows, D_MODEL)), aval((rows, EXPERTS), jnp.float32),
+        aval((EXPERTS, D_MODEL, D_EXPERT)), aval((EXPERTS, D_MODEL, D_EXPERT)),
+        aval((EXPERTS, D_EXPERT, D_MODEL))).compile().as_text()
+    assert _kernels(text) == ["expert_swiglu"]
+
+
+def _kernels(text: str) -> list[str]:
+    """Names of the Mosaic kernels a compiled module calls."""
+    return sorted({re.search(r"%([\w-]+?)(?:\.\d+)? = ", line).group(1)
+                   for line in text.splitlines()
+                   if 'custom_call_target="tpu_custom_call"' in line})
+
+
+def test_sparse_serve_decode_holds_only_the_expert_kernel(one_chip,
+                                                          monkeypatch):
+    """mellum2-12b-a2.5b's paged decode at 8 slots (one period of its
+    four layer kinds, published widths, 16 held experts): the expert
+    kernel is the only Mosaic kernel in ``serve_decode``, and its GQA
+    pool (4 x 128 = 512 lanes a row) is page-major and never relaid
+    out."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models.transformer import decode_step, init_params
+    from repro.runtime.pages import PagedKV
+
+    monkeypatch.setattr("repro.kernels.sb_gemm.interpret_mode",
+                        lambda *arrays: False)
+    slots, pages = 8, 32
+    cfg = get_config("mellum2-12b-a2.5b", pad_vocab=False, n_periods=1,
+                     dtype="bfloat16", param_dtype="bfloat16")
+    cfg = cfg.with_(moe=dataclasses.replace(cfg.moe, n_held=EXPERTS))
+    kv = PagedKV(cfg, n_pages=slots * (3584 // PAGE_ROWS) + 1,
+                 page_size=PAGE_ROWS)
+
+    def aval(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(aval, jax.eval_shape(
+        lambda k: init_params(k, cfg), jax.random.key(0)))
+    args = (params, jax.tree.map(aval, kv.pool),
+            aval(jnp.zeros((slots, pages), jnp.int32)),
+            aval(jnp.zeros((slots,), jnp.int32)),
+            aval(jnp.zeros((slots, 1, 1), jnp.int32)))
+    vm = jax.vmap(lambda p, c, t: decode_step(cfg, p, c, t, stats=True),
+                  in_axes=(None, 0, 0))
+    assert {x.shape[-1] for x in jax.tree.leaves(kv.pool)} == {512}
+    texts = _assert_pool_never_relaid_out(kv, args, vm, slots, pages,
+                                          one_chip)
+    assert _kernels(texts["serve_decode"]) == ["expert_swiglu"]

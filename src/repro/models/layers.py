@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -18,10 +19,11 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core.einsum import xeinsum
 from repro.distributed.sharding import logical
+from repro.kernels.flash_attn import paged_attention
 
 __all__ = [
     "rms_norm", "rope", "rope_freqs", "attention", "mlp", "init_attn", "init_mlp",
-    "dense", "init_dense", "softcap",
+    "dense", "init_dense", "softcap", "KVPages",
 ]
 
 _NEG_INF = -2.0**30  # large-negative mask value safe in bf16
@@ -131,6 +133,19 @@ def init_attn(key, cfg: ModelConfig):
     }
 
 
+class KVPages(NamedTuple):
+    """One attention layer's cached K/V as decode reads them: in place,
+    from the page-major pool of :class:`repro.runtime.pages.PagedKV`.
+    ``k``, ``v``: pool leaves ``(n_pages, L, 1, page_size, G * D)``, of
+    which ``layer`` is this layer's index on the ``L`` axis; ``tables``:
+    (B, P) page ids; ``lengths``: (B,) rows each request has cached."""
+    k: Any
+    v: Any
+    tables: Any
+    lengths: Any
+    layer: Any = 0
+
+
 def _attn_mask(q_pos, k_pos, *, causal: bool, window: int | None):
     """(q, k) boolean mask: True = attend."""
     rel = q_pos[:, None] - k_pos[None, :]
@@ -150,7 +165,8 @@ def attention(
     positions,              # (S,) token positions (for rope + causal mask)
     window: int | None = None,
     yarn=None,              # the layer's rope scaling (None: plain rope)
-    kv_cache=None,          # optional dict(k=(B,T,G,D), v=..., length=())
+    kv_cache=None,          # optional dict(k=(B,T,G,D), v=..., length=()),
+                            # or KVPages
 ):
     """GQA/MQA attention.  Returns (out, new_kv_cache | None).
 
@@ -158,6 +174,13 @@ def attention(
     (b, g) — strided-batched GEMMs in the paper's sense, with the repeat
     group r of GQA riding the GEMM's free rows (granite's MQA: G=1 and the
     K/V operands are *broadcast* across q-heads — Listing 1's lo=0).
+
+    With ``kv_cache`` a :class:`KVPages` (decode, ``S == 1``, each row of
+    the batch its own request at position ``positions[b, 0]``), the
+    cached rows are read in place by
+    :func:`~repro.kernels.flash_attn.paged_attention` and the new cache
+    is only the step's own K/V row, ``dict(k=(B,1,G,D), v=...,
+    length=(B,))``, in the pool's dtype.
     """
     ctr = _ctr(cfg)
     B, S, E = x.shape
@@ -172,7 +195,11 @@ def attention(
     q = logical(q, "batch", None, "kv_heads", None, None)
     k = logical(k, "batch", None, "kv_heads", None)
 
-    if kv_cache is not None:
+    paged = isinstance(kv_cache, KVPages)
+    if paged:
+        k, v = k.astype(kv_cache.k.dtype), v.astype(kv_cache.v.dtype)
+        new_cache = {"k": k, "v": v, "length": kv_cache.lengths + S}
+    elif kv_cache is not None:
         # decode: append new k/v at cache.length
         T = kv_cache["k"].shape[1]
         idx = kv_cache["length"]
@@ -208,7 +235,12 @@ def attention(
         new_cache = None
 
     causal = cfg.causal and not cfg.encoder_only
-    if cfg.attn_impl == "chunked" and kv_cache is None and S > cfg.attn_chunk:
+    if paged:
+        c = kv_cache
+        out = paged_attention(q[:, 0], k[:, 0], v[:, 0], c.k, c.v, c.tables,
+                              c.lengths, c.layer, window=window,
+                              softcap=cfg.attn_softcap)[:, None]
+    elif cfg.attn_impl == "chunked" and kv_cache is None and S > cfg.attn_chunk:
         out = _chunked_attention(
             cfg, q, k.astype(q.dtype), v.astype(q.dtype), positions, k_pos,
             causal=causal, window=window,

@@ -24,7 +24,7 @@ from repro.models.ssm import init_mamba, init_ssm_cache, mamba_mixer
 
 __all__ = [
     "init_params", "forward", "prefill", "lm_loss", "init_cache",
-    "decode_step", "Model",
+    "decode_step", "decode_step_paged", "Model",
 ]
 
 
@@ -135,7 +135,10 @@ def _run_stack(cfg: ModelConfig, params, x, positions, cache=None, remat=False):
     """Shared stack runner.  Returns (x, new_cache | None, aux).
 
     A cached call's aux is ``{"moe_held": (n_moe_layers, B, S, n_held)}``
-    in layer order for a stack with sparse layers, else empty."""
+    in layer order for a stack with sparse layers, else empty.  A cache
+    whose layers are :class:`~repro.models.layers.KVPages` is read in
+    place: the period scan closes over the pool and takes each period's
+    index, so no period's pages are sliced out of it."""
     aux_acc = {"load_balance_loss": jnp.zeros((), jnp.float32)}
     new_prefix, prefix_held = [], []
     prefix_caches = cache["prefix"] if cache is not None else [None] * len(cfg.prefix)
@@ -161,8 +164,14 @@ def _run_stack(cfg: ModelConfig, params, x, positions, cache=None, remat=False):
         aux_acc = _acc_aux(aux_acc, jax.tree.map(jnp.sum, aux_scan))
         return x, None, aux_acc
 
+    paged = any(isinstance(c, L.KVPages) for c in cache["pattern"])
+    periods = jnp.arange(cfg.n_periods) if paged else cache["pattern"]
+
     def period_body_cached(x, inp):
         period_params, period_cache = inp
+        if paged:
+            period_cache = [c._replace(layer=period_cache)
+                            for c in cache["pattern"]]
         new_caches, held = [], []
         for j, spec in enumerate(cfg.pattern):
             x, nc, aux = _block(
@@ -175,12 +184,12 @@ def _run_stack(cfg: ModelConfig, params, x, positions, cache=None, remat=False):
         return x, (new_caches, jnp.stack(held) if held else None)
 
     x, (new_pattern, held) = jax.lax.scan(
-        period_body_cached, x, (params["pattern"], cache["pattern"])
+        period_body_cached, x, (params["pattern"], periods)
     )
     new_cache = {
         "prefix": new_prefix,
         "pattern": new_pattern,
-        "length": cache["length"] + positions.shape[0],
+        "length": cache["length"] + positions.shape[-1],
     }
     if held is not None:   # (periods, sparse layers of a period, ...)
         prefix_held.append(held.reshape((-1,) + held.shape[2:]))
@@ -293,6 +302,34 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, *, stats: bool = False)
     pos = cache["length"][None]
     x, new_cache, aux = _run_stack(cfg, params, x, pos, cache=cache)
     out = _lm_head(cfg, params, x)[:, -1], new_cache
+    return (*out, aux) if stats else out
+
+
+def decode_step_paged(cfg: ModelConfig, params, pool, tables, lengths,
+                      tokens, *, stats: bool = False):
+    """One decode step of a batch of requests whose K/V live in a
+    page-major pool (:class:`repro.runtime.pages.PagedKV`), read in place.
+
+    ``pool``: the pool tree, every attention leaf ``(n_pages, ...,
+    page_size, G * D)``; ``tables``: (B, P) page ids; ``lengths``: (B,)
+    rows each request has cached; ``tokens``: (B, 1).  Returns (logits
+    (B, V), rows), and with ``stats`` the aux (``moe_held``: see
+    :func:`_run_stack`).  ``rows`` is the cache tree with one token row:
+    each attention layer's K/V at position ``lengths[b]``, for the
+    caller to write into its page."""
+    if cfg.encoder_only:
+        raise ValueError(f"{cfg.arch_id} is encoder-only: no decode step")
+
+    def pages(c, layer_axis: bool):
+        k, v = (c[n] if layer_axis else c[n][:, None] for n in ("k", "v"))
+        return L.KVPages(k, v, tables, lengths)
+
+    cache = {"prefix": [pages(c, False) for c in pool["prefix"]],
+             "pattern": [pages(c, True) for c in pool["pattern"]],
+             "length": lengths}
+    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.activation_dtype())
+    x, rows, aux = _run_stack(cfg, params, x, lengths[:, None], cache=cache)
+    out = _lm_head(cfg, params, x)[:, -1], rows
     return (*out, aux) if stats else out
 
 

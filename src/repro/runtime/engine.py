@@ -750,13 +750,14 @@ class ServingRuntime:
 
     def _decode_inputs_paged(self, decodes: list[RequestState], bucket: int):
         """(step, arguments after the params, logits row of each request)
-        for one decode over page tables: gather each request's pages into
-        a view, step, scatter the one written KV row back.  The
-        executable is keyed on the (slot-bucket, page-bucket) lattice
-        point; the batch pads with full duplicates of request 0's
+        for one decode over page tables: step over each request's pages
+        (read in place, or gathered into a view), write the one new KV
+        row back.  The executable is keyed on the (slot-bucket,
+        page-bucket) lattice point; the batch pads with full duplicates of request 0's
         (table, length, token) row, so the padded rows compute — and
         scatter — identical values.  Counts the KV rows the step attends
-        against those its view gathers."""
+        against those it reads, and the pages it reads
+        (:meth:`PagedKV.reads`)."""
         n = len(decodes)
         P = self.lattice.page_bucket(max(len(s.pages) for s in decodes))
         key = self.buckets.key("decode", (bucket, P), self._fingerprint())
@@ -771,7 +772,7 @@ class ServingRuntime:
         toks = self._tokens[[s.slot for s in decodes]]
         # the step writes row `length` and attends rows 0..length
         self.metrics.on_kv_rows(int(lengths.sum()) + n,
-                                bucket * P * self.page_size)
+                                *self.kv.reads(lengths, bucket, P))
         if bucket > n:
             pad = bucket - n
             tables = np.concatenate([tables, np.repeat(tables[:1], pad, 0)])

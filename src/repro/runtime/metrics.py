@@ -28,9 +28,13 @@ class ServingMetrics:
     from *decode efficiency* (active slots / bucket rows — how much of
     each launched decode batch is useful work; 1.0 for a perfectly
     snapped bucket).  On the paged path *KV liveness* counts rows: of
-    the rows each decode launch gathers into its view (``bucket × P ×
-    page_size``, ``kv_rows_viewed``), those its active requests attend
-    (length + 1 each, ``kv_rows_live``).  A sparse model counts its
+    the rows each decode launch reads (``kv_rows_viewed``: reading pages
+    in place, the rows of the pages a full-attention layer reads plus
+    the new row, ``⌈length / page_size⌉ × page_size + 1`` a request;
+    through views, ``bucket × P × page_size``), those its active
+    requests attend (length + 1 each, ``kv_rows_live``); and the pages
+    it reads, summed over requests and attention layers, a window layer
+    only those its window reaches (``kv_pages_read``).  A sparse model counts its
     routing, for decode and prefill apart (``moe_*_decode``,
     ``moe_*_prefill``): the (token, expert) pairs routed over all its
     sparse layers (``pairs_routed``), those that land on the experts this
@@ -70,7 +74,8 @@ class ServingMetrics:
         self.prefix_shared_pages = 0
         self.prefix_shared_tokens = 0
         self.kv_rows_live = 0      # Σ rows attended over decode launches
-        self.kv_rows_viewed = 0    # Σ rows gathered into decode views
+        self.kv_rows_viewed = 0    # Σ rows decode launches read
+        self.kv_pages_read = 0     # Σ pages read, over attention layers
         self.moe = {f"moe_{what}_{phase}": 0
                     for phase in ("decode", "prefill")
                     for what in ("pairs_routed", "pairs_held",
@@ -156,11 +161,12 @@ class ServingMetrics:
         self._active_rows += int(n_active)
         self._bucket_rows += int(bucket_rows)
 
-    def on_kv_rows(self, live: int, viewed: int) -> None:
+    def on_kv_rows(self, live: int, viewed: int, pages: int) -> None:
         """One paged decode launch: ``live`` rows attended by its active
-        requests, ``viewed`` rows in the views it gathers."""
+        requests, ``viewed`` rows and ``pages`` pages it reads."""
         self.kv_rows_live += int(live)
         self.kv_rows_viewed += int(viewed)
+        self.kv_pages_read += int(pages)
 
     def on_moe(self, phase: str, routed: int, held: int,
                touched: int) -> None:
@@ -217,6 +223,7 @@ class ServingMetrics:
             "prefix_shared_tokens": self.prefix_shared_tokens,
             "kv_rows_live": self.kv_rows_live,
             "kv_rows_viewed": self.kv_rows_viewed,
+            "kv_pages_read": self.kv_pages_read,
             **self.moe,
             "pool_free_min": (
                 -1 if self._pool_free_min is None else self._pool_free_min

@@ -29,10 +29,10 @@ Two halves, mirroring the scheduler/engine split:
   features)``) plus the jitted gather/commit/decode builders the engine
   caches per bucket lattice point.
 
-**Page 0 is the null page.**  Gather and commit pad their page tables
-to the lattice width with it; whatever lands there is only ever read
-through positions the attention mask zeroes exactly, so the padding is
-value-safe without per-request branches.
+**Page 0 is the null page.**  Page tables pad to the lattice width
+with it; decode reading pages in place never copies it, and a gathered
+view reads it only through positions the attention mask zeroes exactly,
+so the padding is value-safe without per-request branches.
 
 Correctness invariant (pinned by the differential tests): greedy
 output is token-identical to the unpaged runtime.  The gathered view
@@ -45,6 +45,7 @@ params).
 from __future__ import annotations
 
 import hashlib
+from collections import Counter
 
 import numpy as np
 
@@ -356,6 +357,33 @@ class PagedKV:
                              + (self.page_size, row), x.dtype)
 
         self.pool = jax.tree.map(pages, self.axes, one)
+        #: whether decode reads the pool in place (float K/V); a pool of
+        #: int8 K/V with scales is gathered into views
+        self.inplace = all(jnp.issubdtype(x.dtype, jnp.floating)
+                           for x in jax.tree.leaves(self.pool))
+        #: attention layers by window (None: full)
+        self.windows = Counter(s.window for s in cfg.layers
+                               if s.mixer == "attn")
+
+    def reads(self, lengths, bucket: int, P: int) -> tuple[int, int]:
+        """(rows, pages) of cached K/V one decode launch reads for the
+        requests with ``lengths`` rows cached, in a ``bucket`` x ``P``
+        lattice point.  In place: the rows of the pages a full layer reads
+        plus the new row, and the pages every attention layer reads, a
+        window layer only those its window reaches (:func:`page_span`).
+        Through views: every row and page of every view."""
+        from repro.kernels.flash_attn import page_span
+
+        ps = self.page_size
+        if not self.inplace:
+            return bucket * P * ps, bucket * P * sum(self.windows.values())
+        lengths = np.asarray(lengths)
+        rows = int(np.sum(-(-lengths // ps))) * ps + len(lengths)
+        pages = 0
+        for window, layers in self.windows.items():
+            _, first, end = page_span(lengths, window, ps, np)
+            pages += layers * int(np.sum(end - first))
+        return rows, pages
 
     # -------------------------------------------------------------- weights
     def _gather(self, pool, tables, lengths):
@@ -439,34 +467,66 @@ class PagedKV:
 
     def build_decode(self, decode_vmapped, bucket: int, P: int):
         """Jitted paged decode for one ``(slot-bucket, page-bucket)``
-        lattice point: gather views, run the vmapped step, write the
-        single new row of every leaf back into its page, in place, one
-        ``dynamic_update_slice`` per batch row.  Returns ``(logits,
-        new_pool)`` and whatever the step returns after its cache (a
-        sparse model's routing counts).
+        lattice point: run the step, write the single new row of every
+        leaf into its page, in place, one ``dynamic_update_slice`` per
+        batch row.  Returns ``(logits, new_pool)`` and whatever the step
+        returns after its cache (a sparse model's routing counts).
+
+        A pool of float K/V is read in place:
+        :func:`~repro.models.transformer.decode_step_paged` runs the
+        batch, each attention layer reading every request's pages through
+        its table (:func:`~repro.kernels.flash_attn.paged_attention`), and
+        returns only the new rows.  A pool of int8 K/V with scales
+        (``kv_quant``) is gathered into views of ``P * page_size`` rows
+        that ``decode_vmapped`` steps.
 
         The engine pads the batch to ``bucket`` by duplicating an
         active request's (table, length, token) row — duplicates
         compute identical updates, so the row writes are
         value-deterministic (same rule as the unpaged slot scatter).
 
-        The gather, the model step and the row writes run under the
-        name scopes ``kv_view``, ``decode_model`` and ``kv_write``, which
-        reach each op's ``op_name`` metadata and change nothing else in
-        the compiled program.  So JAX's persistent compilation cache,
-        whose key leaves metadata out unless
+        The gather (views only), the model step and the row writes run
+        under the name scopes ``kv_view``, ``decode_model`` and
+        ``kv_write``, which reach each op's ``op_name`` metadata and change
+        nothing else in the compiled program.  So JAX's persistent
+        compilation cache, whose key leaves metadata out unless
         ``jax_compilation_cache_include_metadata_in_key`` is set, may
         load an executable carrying the metadata of an older compile."""
         import jax
+        import jax.numpy as jnp
         from jax import lax
 
+        from repro.models.transformer import decode_step_paged
+
         ps = self.page_size
+        inplace = self.inplace
+        moe = any(s.ff == "moe" for s in self.cfg.layers)
+
+        def step(params, pool, tables, lengths, toks):
+            """(logits (B, 1, V), each leaf's new rows, stats, and where
+            request b's row sits on a leaf's token axis)."""
+            if not inplace:
+                with jax.named_scope("kv_view"):
+                    view = self._gather(pool, tables, lengths)
+                with jax.named_scope("decode_model"):
+                    return (*decode_vmapped(params, view, toks), lengths)
+            with jax.named_scope("decode_model"):
+                logits, rows, *aux = decode_step_paged(
+                    self.cfg, params, pool, tables, lengths, toks[:, 0],
+                    stats=moe)
+            # the routing counts as the vmapped step lays them out
+            aux = [{k: jnp.moveaxis(h, 1, 0)[:, :, None]
+                    for k, h in a.items()} for a in aux]
+            # rows: the batch on the leaf's own batch axis, one token
+            rows = jax.tree.map(
+                lambda ax, x: x if ax < 0 else jnp.expand_dims(
+                    jnp.moveaxis(x, ax - 1, 0), ax),
+                self.axes, rows)
+            return (logits[:, None], rows, *aux, jnp.zeros_like(lengths))
 
         def serve_decode(params, pool, tables, lengths, toks):
-            with jax.named_scope("kv_view"):
-                view = self._gather(pool, tables, lengths)
-            with jax.named_scope("decode_model"):
-                logits, new_view, *stats = decode_vmapped(params, view, toks)
+            logits, new_view, *stats, at = step(params, pool, tables,
+                                                lengths, toks)
 
             def s(ax, pool_leaf, new_leaf):
                 if ax < 0:
@@ -475,7 +535,8 @@ class PagedKV:
                 for b in range(new_leaf.shape[0]):
                     n = lengths[b]
                     row = lax.dynamic_slice(
-                        new_leaf, (b,) + (0,) * ax + (n,) + (0,) * len(post),
+                        new_leaf,
+                        (b,) + (0,) * ax + (at[b],) + (0,) * len(post),
                         (1,) + pre + (1,) + post)
                     pool_leaf = lax.dynamic_update_slice(
                         pool_leaf,

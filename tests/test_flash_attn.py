@@ -74,3 +74,87 @@ def test_flash_gqa_wrapper_pattern():
     ).reshape(B, G, R, S, D)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+# ------------------------------------------------------------ paged decode
+PAGE = 16
+
+
+def paged_oracle(q, k_new, v_new, k_pages, v_pages, tables, lengths, layer,
+                 window):
+    """Dense masked softmax in float32, request by request: the cached
+    rows laid end to end from the request's pages, the new row last, at
+    position ``lengths[b]``."""
+    B, G, R, D = q.shape
+    out = []
+    for b in range(B):
+        n = int(lengths[b])
+        rows = lambda pages: np.concatenate(  # noqa: E731
+            [np.asarray(pages[p, layer, 0], np.float32) for p in tables[b]]
+        )[:n].reshape(n, G, D)
+        k = np.concatenate([rows(k_pages), np.asarray(k_new[b])[None]])
+        v = np.concatenate([rows(v_pages), np.asarray(v_new[b])[None]])
+        s = np.einsum("grd,tgd->grt", np.asarray(q[b]), k) * D**-0.5
+        rel = n - np.arange(n + 1)
+        s = np.where(rel < (window or n + 1), s, -np.inf)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        out.append(np.einsum("grt,tgd->grd", p / p.sum(-1, keepdims=True), v))
+    return np.stack(out)
+
+
+#: lengths 1, 16k - 1, 16k and 16k + 1, then request 0 again: the batch
+#: padding the runtime adds
+LENGTHS = [1, 2 * PAGE - 1, 3 * PAGE, 4 * PAGE + 1, 1]
+
+
+@pytest.mark.parametrize("window", [None, 24], ids=["full", "window24"])
+@pytest.mark.parametrize("heads", [(36, 1, 64), (4, 8, 128)],
+                         ids=["mha64", "gqa128"])
+def test_paged_attention_vs_dense(heads, window):
+    """minicpm-2b's 36 heads of 64 and mellum2-12b-a2.5b's 4 kv heads of
+    128 with 8 queries each, in one lane-dense row per token: the paged
+    kernel equals the dense masked softmax over the request's pages,
+    scattered through the pool and null-padded, window cut included."""
+    from repro.kernels.flash_attn import paged_attention
+
+    G, R, D = heads
+    rng = np.random.default_rng(G)
+    n_pages, layers, P = 24, 2, 6
+    k_pages, v_pages = (
+        jnp.asarray(rng.standard_normal((n_pages, layers, 1, PAGE, G * D)),
+                    jnp.float32) for _ in range(2))
+    lengths = np.asarray(LENGTHS, np.int32)
+    B = len(lengths)
+    free = list(rng.permutation(np.arange(1, n_pages)))
+    tables = np.zeros((B, P), np.int32)               # the null page pads
+    for b, n in enumerate(lengths[:-1]):
+        k = n // PAGE + 1
+        tables[b, :k] = [free.pop() for _ in range(k)]
+    tables[-1] = tables[0]
+    pad = lambda x: jnp.concatenate([x[:-1], x[:1]])     # noqa: E731
+    q = pad(jnp.asarray(rng.standard_normal((B, G, R, D)), jnp.float32))
+    k_new, v_new = (pad(jnp.asarray(rng.standard_normal((B, G, D)),
+                                    jnp.float32)) for _ in range(2))
+    got = paged_attention(q, k_new, v_new, k_pages, v_pages,
+                          jnp.asarray(tables), jnp.asarray(lengths), 1,
+                          window=window)
+    want = paged_oracle(q, k_new, v_new, k_pages, v_pages, tables, lengths,
+                        1, window)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(got)[-1], np.asarray(got)[0])
+
+
+@pytest.mark.parametrize("window", [None, 1, 17, 100])
+def test_page_span_covers_the_attended_rows(window):
+    """The pages a request reads hold exactly the rows its window
+    reaches, on the host (NumPy) as in the kernel."""
+    from repro.kernels.flash_attn import page_span
+
+    lengths = np.arange(0, 70)
+    spans = (np.broadcast_to(x, lengths.shape)
+             for x in page_span(lengths, window, PAGE, np))
+    for n, s, f, e in zip(lengths, *spans):
+        rows = [t for t in range(n) if window is None or n - t < window]
+        assert s == (rows[0] if rows else s)
+        assert (f, e) == ((rows[0] // PAGE, (rows[-1] // PAGE) + 1)
+                          if rows else (f, f))

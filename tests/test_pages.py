@@ -505,17 +505,43 @@ def _marking_step(kv):
     return jax.vmap(step, in_axes=(None, 0, 0))
 
 
+def _marking_paged_step(kv):
+    """The same stand-in for the in-place step: each leaf's new row, in
+    the cache's layout with the batch on its batch axis, is ``token +
+    1``."""
+    def step(cfg, params, pool, tables, lengths, tokens, stats=False):
+        B = tokens.shape[0]
+
+        def w(ax, spec):
+            if ax < 0:
+                return lengths + 1
+            shape = spec.shape[:ax - 1] + (B, 1) + spec.shape[ax + 1:]
+            mark = (tokens[:, 0] + 1).astype(spec.dtype)
+            return jax.numpy.broadcast_to(
+                mark.reshape((1,) * (ax - 1) + (B,) + (1,) * (len(shape) - ax)),
+                shape)
+
+        return tokens[:, 0].astype(np.float32), jax.tree.map(
+            w, kv.axes, kv.shapes)
+
+    return step
+
+
 @pytest.mark.parametrize("P", KV_LATTICE)
-def test_decode_writes_one_row_per_request(paged_kv, P):
+def test_decode_writes_one_row_per_request(paged_kv, P, monkeypatch):
     """The decode's row write lands at ``(tables[b, len // ps], len %
     ps)`` and nowhere else, with the batch padded by a duplicate of
-    request 0 as the engine pads it."""
+    request 0 as the engine pads it, whether the step reads the pool in
+    place (bf16) or through views (``kv_quant``)."""
     kv = paged_kv
     lengths = np.asarray([P * kv.page_size - 2, (P * kv.page_size) // 2],
                          np.int32)
     tables = _tables(kv, P, lengths, seed=7)
     toks = np.asarray([[[5]], [[9]]], np.int32)
     pad = lambda a: np.concatenate([a, a[:1]])            # noqa: E731
+    assert kv.inplace == (not kv.cfg.kv_quant)
+    monkeypatch.setattr("repro.models.transformer.decode_step_paged",
+                        _marking_paged_step(kv))
     step = kv.build_decode(_marking_step(kv), 3, P)
     logits, pool = step(None, kv.pool, *map(jax.numpy.asarray, (
         pad(tables), pad(lengths), pad(toks))))
@@ -532,3 +558,130 @@ def test_decode_writes_one_row_per_request(paged_kv, P):
         np.testing.assert_array_equal(np.asarray(after), want)
 
     jax.tree.map(check, kv.axes, kv.pool, pool)
+
+
+# ------------------------------------------- decode reading pages in place
+def _step_case(arch, kv_quant=False):
+    """A tiny bf16 model of ``arch``'s attention shape, a pool of 4-row
+    pages filled with small random K/V, and a decode batch over it:
+    lengths 1, 4k - 1, 4k, 4k + 1 and more, scattered tables padded with
+    the null page, request 0 duplicated as the engine pads."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models.transformer import Model, decode_step
+    from repro.runtime.pages import PagedKV
+
+    cfg = get_config(arch, smoke=True, dtype="bfloat16",
+                     param_dtype="bfloat16", kv_quant=kv_quant)
+    if arch == "mellum2-12b-a2.5b":   # GQA, windows shorter than the rows
+        cfg = cfg.with_(n_kv_heads=2, pattern=tuple(
+            dataclasses.replace(s, window=6) if s.window else s
+            for s in cfg.pattern))
+    params = Model(cfg).init(jax.random.PRNGKey(1))
+    kv = PagedKV(cfg, n_pages=24, page_size=KV_ROWS)
+    rng = np.random.default_rng(5)
+    kv.pool = jax.tree.map(lambda x: jax.numpy.asarray(
+        rng.integers(-99, 99, x.shape) / (99 if x.dtype != np.int8 else 1),
+        x.dtype), kv.pool)
+    lengths = np.asarray([1, 7, 8, 9, 18, 1], np.int32)
+    tables = _tables(kv, 6, lengths[:-1], seed=11)
+    tables = np.concatenate([tables, tables[:1]])
+    toks = rng.integers(0, cfg.vocab_size, (len(lengths), 1, 1)).astype(
+        np.int32)
+    toks[-1] = toks[0]
+    moe = any(s.ff == "moe" for s in cfg.layers)
+    vm = jax.vmap(lambda p, c, t: decode_step(cfg, p, c, t, stats=moe),
+                  in_axes=(None, 0, 0))
+    args = (params, kv.pool) + tuple(
+        jax.numpy.asarray(a) for a in (tables, lengths, toks))
+    return kv, vm, args
+
+
+def _as_f32(tree):
+    return jax.tree.map(lambda x: x.astype(np.float32)
+                        if jax.numpy.issubdtype(x.dtype, jax.numpy.floating)
+                        else x, tree)
+
+
+@pytest.mark.parametrize("arch", ["minicpm-2b", "mellum2-12b-a2.5b"])
+def test_in_place_decode_matches_the_view_path(arch):
+    """The bf16 decode reading pages in place gives the logits of the
+    view path run in float32 on the same weights and pool, within bf16
+    rounding (the bf16 view path reads 1.0% and 1.7% of the largest
+    logit away), for MHA (minicpm-2b) and GQA with window layers
+    (mellum2-12b-a2.5b); and writes each request's new row of every leaf
+    into the same page and offset, every other pool row untouched."""
+    from repro.models.transformer import decode_step
+    from repro.runtime.pages import PagedKV
+
+    kv, _, args = _step_case(arch)
+    tables, lengths = np.asarray(args[2]), np.asarray(args[3])
+    got = kv.build_decode(None, len(lengths), tables.shape[1])(*args)
+    cfg = kv.cfg.with_(dtype="float32", param_dtype="float32")
+    ref = PagedKV(cfg, kv.n_pages, kv.page_size)
+    ref.inplace = False
+    moe = any(s.ff == "moe" for s in cfg.layers)
+    vm = jax.vmap(lambda p, c, t: decode_step(cfg, p, c, t, stats=moe),
+                  in_axes=(None, 0, 0))
+    want = ref.build_decode(vm, len(lengths), tables.shape[1])(
+        *_as_f32(args[:2]), *args[2:])
+    assert len(got) == len(want)
+    scale = float(np.abs(np.asarray(want[0])).max())
+    np.testing.assert_allclose(np.asarray(got[0], np.float32),
+                               np.asarray(want[0]), atol=3e-2 * scale,
+                               rtol=0)
+    written = {(tables[b, n // KV_ROWS], n % KV_ROWS)
+               for b, n in enumerate(lengths)}
+
+    def check(ax, before, new, old):
+        if ax < 0:
+            return
+        before, new, old = (np.asarray(x, np.float32)
+                            for x in (before, new, old))
+        for page in range(kv.n_pages):
+            for r in range(KV_ROWS):
+                at = (page,) + (slice(None),) * ax + (r,)
+                if (page, r) in written:
+                    np.testing.assert_allclose(
+                        new[at], old[at], rtol=0,
+                        atol=3e-2 * np.abs(old[at]).max())
+                else:
+                    np.testing.assert_array_equal(new[at], before[at])
+
+    jax.tree.map(check, kv.axes, args[1], got[1], want[1])
+
+
+def _all_eqns(closed):
+    """Every equation of a closed jaxpr, those of its sub-jaxprs too."""
+    out, stack = [], [closed.jaxpr]
+    while stack:
+        jaxpr = stack.pop()
+        for e in jaxpr.eqns:
+            out.append(e)
+            for v in e.params.values():
+                for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                    if hasattr(sub, "jaxpr"):
+                        stack.append(getattr(sub.jaxpr, "jaxpr", sub.jaxpr))
+                    elif hasattr(sub, "eqns"):
+                        stack.append(sub)
+    return out
+
+
+@pytest.mark.parametrize("kv_quant", [False, True], ids=["bf16", "kv_quant"])
+def test_kv_quant_pool_decodes_through_views(kv_quant):
+    """A pool of float K/V decodes through the paged-attention kernel; a
+    pool of int8 K/V with scales keeps the gathered view and gives what
+    the vmapped step gives over that view."""
+    kv, vm, args = _step_case("minicpm-2b", kv_quant=kv_quant)
+    step = kv.build_decode(vm, len(args[3]), args[2].shape[1])
+    kernels = {str(e.params["name"])
+               for e in _all_eqns(jax.make_jaxpr(step)(*args))
+               if e.primitive.name == "pallas_call"}
+    assert kv.inplace == (not kv_quant)
+    assert kernels == (set() if kv_quant else {"paged_attention"})
+    if kv_quant:
+        view = kv._gather(*args[1:4])
+        np.testing.assert_array_equal(np.asarray(step(*args)[0]),
+                                      np.asarray(vm(args[0], view,
+                                                    args[4])[0]))

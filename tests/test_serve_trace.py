@@ -143,17 +143,35 @@ def test_kv_row_counters_match_a_hand_count(served):
     Both prompts finish prefill in one tick and decode together once
     (cache lengths 5 and 9, tables of 2 and 3 pages); request 0 then
     decodes alone twice (lengths 6 and 7, still 2 pages).  A launch
-    attends length + 1 rows per request and gathers bucket x P x 4."""
+    attends length + 1 rows per request and reads the rows of the pages
+    that hold them, ceil(length / 4) x 4, plus the new row: 2, 3, 2 and
+    2 pages in the one attention layer."""
     cfg, _ = served
     rt = _runtime(served)
     rt.serve(_requests(cfg, [5, 9], [4, 2]))
-    lat = rt.lattice
     live = (5 + 1) + (9 + 1) + (6 + 1) + (7 + 1)
-    viewed = 4 * (lat.decode_bucket(2) * lat.page_bucket(3)
-                  + 2 * lat.decode_bucket(1) * lat.page_bucket(2))
+    viewed = (8 + 1) + (12 + 1) + (8 + 1) + (8 + 1)
     snap = rt.metrics.snapshot()
     assert (snap["kv_rows_live"], snap["kv_rows_viewed"]) == (live, viewed)
-    assert (live, viewed) == (31, 48)
+    assert (live, viewed) == (31, 40)
+    assert snap["kv_pages_read"] == 2 + 3 + 2 + 2
+
+
+def test_kv_pages_read_stop_at_the_window(served):
+    """The same traffic through a full layer and a layer whose window
+    of 6 reaches rows [length - 5, length): lengths 5, 9, 6 and 7 read
+    pages [0, 2), [1, 3), [0, 2) and [0, 2) there, 8 pages, against 9 in
+    the full layer."""
+    from repro.configs.base import LayerSpec
+    from repro.models.transformer import Model
+
+    cfg, _ = served
+    cfg = cfg.with_(pattern=(LayerSpec(window=6), LayerSpec()))
+    rt = _runtime((cfg, Model(cfg).init(jax.random.PRNGKey(0))))
+    rt.serve(_requests(cfg, [5, 9], [4, 2]))
+    snap = rt.metrics.snapshot()
+    assert (snap["kv_rows_live"], snap["kv_rows_viewed"]) == (31, 40)
+    assert snap["kv_pages_read"] == 8 + 9
 
 
 def test_unpaged_decode_counts_no_kv_rows(served):
@@ -174,8 +192,9 @@ def test_serve_decode_is_named_and_scoped(served):
                          jnp.zeros((2, 1, 1), jnp.int32))
     assert lowered.as_text().startswith("module @jit_serve_decode")
     hlo = lowered.compile().as_text()
-    for scope in ("kv_view", "decode_model", "kv_write"):
+    for scope in ("decode_model", "kv_write"):
         assert f'op_name="jit(serve_decode)/{scope}/' in hlo, scope
+    assert "kv_view" not in hlo       # pages are read in place
 
 
 @pytest.mark.parametrize("name", ["serve_prefill", "serve_page_view",

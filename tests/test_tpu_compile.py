@@ -176,8 +176,8 @@ def _paged_steps(one_chip):
 def test_scoped_serve_decode_compiles_to_the_same_program(one_chip,
                                                          monkeypatch):
     """The paged decode at the decode-batch cell's lattice point: the
-    ``kv_view`` / ``decode_model`` / ``kv_write`` name scopes change the
-    ops' metadata and nothing else."""
+    ``decode_model`` / ``kv_write`` name scopes change the ops' metadata
+    and nothing else."""
     monkeypatch.setattr("repro.kernels.sb_gemm.interpret_mode",
                         lambda *arrays: False)
     kv, args, vm = _paged_steps(one_chip)
@@ -187,11 +187,12 @@ def test_scoped_serve_decode_compiles_to_the_same_program(one_chip,
             *args).compile().as_text()
 
     scoped = compiled()
-    assert 'op_name="jit(serve_decode)/kv_view/' in scoped
+    for scope in ("decode_model", "kv_write"):
+        assert f'op_name="jit(serve_decode)/{scope}/' in scoped, scope
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
     plain = compiled()
-    assert "kv_view" not in plain
+    assert "decode_model" not in plain and "kv_write" not in plain
     ops = _strip_metadata(scoped)
     assert len(ops) > 100 and ops == _strip_metadata(plain)
 
@@ -225,7 +226,9 @@ def test_paged_pool_is_page_major_and_never_relaid_out(one_chip,
     monkeypatch.setattr("repro.kernels.sb_gemm.interpret_mode",
                         lambda *arrays: False)
     kv, args, vm = _paged_steps(one_chip)
-    _assert_pool_never_relaid_out(kv, args, vm, SLOTS, PAGES, one_chip)
+    texts = _assert_pool_never_relaid_out(kv, args, vm, SLOTS, PAGES,
+                                          one_chip)
+    _assert_reads_pages_in_place(kv, texts["serve_decode"], SLOTS, PAGES)
 
 
 def _assert_pool_never_relaid_out(kv, args, vm, slots, pages, one_chip):
@@ -268,6 +271,18 @@ def _assert_pool_never_relaid_out(kv, args, vm, slots, pages, one_chip):
     return texts
 
 
+def _assert_reads_pages_in_place(kv, text, slots, pages):
+    """A compiled paged decode calls the paged-attention kernel and
+    holds no value of a view's size: no layer's K or V rows of a whole
+    page bucket (``slots * pages * page_size`` rows of ``G * D``), alone
+    or stacked over the layers of a pool leaf."""
+    assert "paged_attention" in _kernels(text)
+    view = {slots * pages * kv.page_size * x.shape[-1] * n
+            for x in jax.tree.leaves(kv.pool) for n in (1, x.shape[1])}
+    held = {int(np.prod(_dims(d))) for d, _, _, _ in _INSTR.findall(text)}
+    assert not view & held, sorted(view & held)
+
+
 # ------------------------------------------------------- held experts
 #: mellum2-12b-a2.5b at published widths: 16 held experts of 2304 x 896
 EXPERTS, D_MODEL, D_EXPERT = 16, 2304, 896
@@ -300,9 +315,9 @@ def test_sparse_serve_decode_holds_only_the_expert_kernel(one_chip,
                                                           monkeypatch):
     """mellum2-12b-a2.5b's paged decode at 8 slots (one period of its
     four layer kinds, published widths, 16 held experts): the expert
-    kernel is the only Mosaic kernel in ``serve_decode``, and its GQA
-    pool (4 x 128 = 512 lanes a row) is page-major and never relaid
-    out."""
+    kernel and the paged-attention kernel are the only Mosaic kernels in
+    ``serve_decode``, no value of a view's size is held, and its GQA pool
+    (4 x 128 = 512 lanes a row) is page-major and never relaid out."""
     import dataclasses
 
     from repro.configs import get_config
@@ -332,4 +347,6 @@ def test_sparse_serve_decode_holds_only_the_expert_kernel(one_chip,
     assert {x.shape[-1] for x in jax.tree.leaves(kv.pool)} == {512}
     texts = _assert_pool_never_relaid_out(kv, args, vm, slots, pages,
                                           one_chip)
-    assert _kernels(texts["serve_decode"]) == ["expert_swiglu"]
+    assert _kernels(texts["serve_decode"]) == ["expert_swiglu",
+                                               "paged_attention"]
+    _assert_reads_pages_in_place(kv, texts["serve_decode"], slots, pages)

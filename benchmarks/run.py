@@ -7,11 +7,9 @@ Prints ``name,us_per_call,derived`` CSV.  Run:
 (``benchmarks.common.QUICK``) and modules whose ``run()`` accepts a
 ``quick`` keyword also shrink their problem sizes.
 
-Modules that publish a ``LAST_RESULTS`` dict (``fig14_runtime``,
-``fig15_predict``) get it written as machine-readable JSON next to the
-repo root — ``BENCH_runtime.json`` tracks the serving perf trajectory
-and ``BENCH_predict.json`` the cost-model regret/cold-start bars, PR
-over PR (override the directory with ``REPRO_BENCH_DIR``).
+A module that publishes a ``LAST_RESULTS`` dict (``fig14_runtime``) gets
+it written as machine-readable JSON (``BENCH_runtime.json``) at the repo
+root (override the directory with ``REPRO_BENCH_DIR``).
 """
 
 import argparse
@@ -38,16 +36,12 @@ MODULES = [
     "fig12_sharded",
     "fig13_program",
     "fig14_runtime",
-    "fig15_predict",
-    "obs_overhead",
     "table2_cases",
 ]
 
 #: module → JSON artifact written after a successful run.
 JSON_ARTIFACTS = {
     "fig14_runtime": "BENCH_runtime.json",
-    "fig15_predict": "BENCH_predict.json",
-    "obs_overhead": "BENCH_obs.json",
 }
 
 
@@ -69,15 +63,6 @@ def _write_json_artifact(mod, mod_name: str) -> None:
     with open(path, "w") as f:
         json.dump(payload, f, indent=2, sort_keys=True)
     print(f"# wrote {path}", file=sys.stderr)
-    # feed the regression sentinel: headline metrics land in the
-    # append-only history ledger, tagged with the quick cohort so
-    # quick-profile noise never judges full-profile baselines
-    from benchmarks import history
-
-    rec = history.append_record(mod_name, payload, quick=common.QUICK)
-    if rec:
-        print(f"# history: {mod_name} -> {history.history_path()} "
-              f"{rec['metrics']}", file=sys.stderr)
 
 
 def main() -> None:

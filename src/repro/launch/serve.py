@@ -94,16 +94,9 @@ def main():
                     help="tuning-cache JSON path (default: "
                          "$REPRO_TUNING_CACHE or ~/.cache/repro/tuning.json)")
     ap.add_argument("--tune-policy", default=None,
-                    choices=["off", "cached", "measure", "predict"],
-                    help="dispatcher policy for pretune + serving; "
-                         "'predict' answers cache misses from the learned "
-                         "cost model when confident, so --pretune only "
-                         "measures low-confidence keys (default: measure)")
-    ap.add_argument("--cache-import", action="append", default=[],
-                    metavar="JSON", dest="cache_imports",
-                    help="merge a tuning cache exported by another machine "
-                         "(repro.tuning.federate) into this one before "
-                         "pretune; repeatable")
+                    choices=["off", "cached", "measure"],
+                    help="dispatcher policy for pretune + serving "
+                         "(default: measure)")
     ap.add_argument("--trace", default=None, metavar="OUT_JSON",
                     help="record a span trace of warm-up + serving and "
                          "write it as Chrome-trace JSON (open in "
@@ -117,45 +110,13 @@ def main():
     ap.add_argument("--metrics-every", type=int, default=0, metavar="TICKS",
                     help="print a metrics-registry snapshot every N "
                          "serving ticks (runtime mode only)")
-    ap.add_argument("--metrics-jsonl", default=None, metavar="OUT_JSONL",
-                    help="sample the metrics registry every serving tick "
-                         "and append one flat JSON record per sample "
-                         "(runtime mode only)")
-    ap.add_argument("--metrics-prom", default=None, metavar="OUT_TXT",
-                    help="write a Prometheus text-exposition dump of the "
-                         "sampled series (gauges + quantile summaries) "
-                         "after serving")
-    ap.add_argument("--metrics-interval", type=float, default=1.0,
-                    metavar="SECONDS",
-                    help="minimum seconds between metric samples "
-                         "(default 1.0; 0 = sample every tick — a full "
-                         "registry snapshot per tick is measurable on "
-                         "the hot loop)")
-    ap.add_argument("--watchdogs", action="store_true",
-                    help="run the SLO watchdog pack (decode stall, "
-                         "recompile storm, page-pool pressure) over the "
-                         "sampled series; alerts print and, when tracing "
-                         "is on, land as trace instants")
-    ap.add_argument("--numerics-every", type=int, default=0, metavar="N",
-                    help="probe every Nth decode step's logits for "
-                         "NaN/Inf (one device sync per probe; 0 = off)")
-    ap.add_argument("--drift-check", action="store_true",
-                    help="after serving, compare traced contraction "
-                         "durations against the tuning cache, evict + "
-                         "re-measure drifted keys and refit the cost "
-                         "model past the drift gate (enables tracing)")
     args = ap.parse_args()
     place_compile_cache()
     if args.paged and args.legacy:
         ap.error("--paged serves through the runtime; drop --legacy")
-    want_health = bool(args.metrics_jsonl or args.metrics_prom
-                       or args.watchdogs or args.numerics_every > 0)
-    if args.legacy and (want_health or args.drift_check):
-        ap.error("fleet-health options serve through the runtime; "
-                 "drop --legacy")
 
     tracer = None
-    if args.trace or args.trace_jsonl or args.drift_check:
+    if args.trace or args.trace_jsonl:
         from repro.obs import trace as obs_trace
 
         tracer = obs_trace.enable_tracing(capacity=args.trace_capacity)
@@ -170,26 +131,11 @@ def main():
     cfg = get_config(args.arch, smoke=args.smoke)
     params = init_serving_params(cfg, seed=0, mesh=mesh)
 
-    tuner = None
-    if args.cache_imports:
-        from repro.tuning.dispatch import (
-            Dispatcher, default_cache_path, set_dispatcher,
-        )
-        from repro.tuning.federate import import_into
-
-        tuner = Dispatcher(args.tuning_cache or default_cache_path(),
-                           policy=args.tune_policy or "measure")
-        for src in args.cache_imports:
-            st = import_into(tuner.cache, src)
-            print(f"cache-import {src}: +{st['added']} added, "
-                  f"{st['merged']} merged ({st['imported']} read)")
-        set_dispatcher(tuner)
-
     t0 = time.perf_counter()
     if args.legacy:
         engine = ServeEngine(
             cfg, params, slots=args.slots, max_len=args.max_len,
-            pretune=args.pretune, tuner=tuner,
+            pretune=args.pretune,
             tuning_cache=args.tuning_cache,
             tune_policy=args.tune_policy, mesh=mesh,
         )
@@ -200,7 +146,7 @@ def main():
             prefill_chunk=args.chunk,
             paged=args.paged, page_size=args.page_size, pages=args.pages,
             prefix_sharing=not args.no_prefix_share,
-            pretune=args.pretune, tuner=tuner,
+            pretune=args.pretune,
             tuning_cache=args.tuning_cache,
             tune_policy=args.tune_policy, mesh=mesh,
         )
@@ -225,29 +171,11 @@ def main():
     ]
     registry = runtime.register_metrics()
 
-    monitor = None
-    if want_health:
-        from repro.obs.health import HealthMonitor, default_watchdogs
-        from repro.obs.timeseries import MetricsSampler
-
-        sampler = MetricsSampler(
-            registry, interval_s=args.metrics_interval,
-            jsonl_path=args.metrics_jsonl,
-        )
-        monitor = HealthMonitor(
-            sampler,
-            watchdogs=default_watchdogs() if args.watchdogs else [],
-            on_alert=lambda a: print(
-                f"ALERT [{a.severity}] {a.name}: {a.message}"),
-        )
-        monitor.attach(runtime, numerics_every=args.numerics_every)
-        monitor.register()
-
-    printers = []
+    tick_cb = None
     if args.metrics_every > 0 and not args.legacy:
         every = args.metrics_every
 
-        def print_cb(step):
+        def tick_cb(step):
             if step % every == 0:
                 snap = registry.snapshot()
                 s = snap.get("serving", {})
@@ -257,16 +185,6 @@ def main():
                       f"occupancy={s.get('slot_occupancy', 0.0):.2f} "
                       f"dispatcher_hits={d.get('hits')} "
                       f"misses={d.get('misses')}")
-
-        printers.append(print_cb)
-    if monitor is not None:
-        printers.append(lambda step: monitor.tick())
-
-    tick_cb = None
-    if printers:
-        def tick_cb(step):
-            for p in printers:
-                p(step)
 
     t0 = time.perf_counter()
     if args.legacy:
@@ -289,33 +207,6 @@ def main():
         ))
     for r in reqs[:3]:
         print(f"  req {r.rid}: prompt[:8]={r.prompt[:8].tolist()} -> {r.output}")
-
-    if monitor is not None:
-        st = monitor.stats()
-        print(f"health: {st['checks']} checks, {st['alerts_total']} alerts"
-              + ("".join(f", {k[len('alerts_'):]}={v}"
-                         for k, v in sorted(st.items())
-                         if k.startswith("alerts_") and k != "alerts_total")))
-        if args.metrics_prom:
-            monitor.sampler.write_prometheus(args.metrics_prom)
-            print(f"metrics: prometheus text -> {args.metrics_prom}")
-        if args.metrics_jsonl:
-            print(f"metrics: {monitor.sampler.samples} samples -> "
-                  f"{args.metrics_jsonl}")
-
-    if args.drift_check:
-        from repro.tuning.dispatch import get_dispatcher
-        from repro.tuning.drift import DriftDetector
-
-        disp = runtime.tuner if runtime.tuner is not None else get_dispatcher()
-        report = DriftDetector(disp).run(tracer.events())
-        print("drift: " + ", ".join(
-            f"{k}={v}" for k, v in report.summary().items()))
-        for key in report.drifted:
-            kd = report.keys[key]
-            print(f"  drifted {key}: live={kd.live_us:.1f}us "
-                  f"cached={kd.cached_us:.1f}us score={kd.score:.2f} "
-                  f"({'re-measured' if key in report.remeasured else 'evicted'})")
 
     if tracer is not None:
         from repro.obs import export as obs_export

@@ -11,13 +11,7 @@ the rest of the stack at module scope, so any layer may import it):
   JSONL records (predictor training data), plus schema validation;
 * :mod:`repro.obs.registry` — the MetricsRegistry unifying
   ServingMetrics, dispatcher, bucket-table and program-cache counters
-  behind one snapshot API;
-* :mod:`repro.obs.timeseries` — bounded time-series over registry
-  snapshots (ring-buffered series, P² streaming quantiles, Prometheus
-  text exposition, JSONL append);
-* :mod:`repro.obs.health` — SLO watchdogs (decode stall, recompile
-  storm, page-pool pressure, sampled NaN/Inf probe) emitting typed
-  alerts through the tracer.
+  behind one snapshot API.
 
 Capture a trace from the serving launcher::
 
@@ -28,20 +22,7 @@ then open ``out.json`` in https://ui.perfetto.dev.  See
 ``docs/observability.md``.
 """
 
-from repro.obs.health import (
-    Alert,
-    HealthMonitor,
-    NumericsProbe,
-    Watchdog,
-    default_watchdogs,
-)
 from repro.obs.registry import MetricsRegistry, get_registry, set_registry
-from repro.obs.timeseries import (
-    MetricsSampler,
-    P2Quantile,
-    StreamingHistogram,
-    TimeSeries,
-)
 from repro.obs.trace import (
     NULL_SPAN,
     Span,
@@ -60,7 +41,4 @@ __all__ = [
     "enabled", "enable_tracing", "disable_tracing",
     "get_tracer", "set_tracer", "span", "instant",
     "MetricsRegistry", "get_registry", "set_registry",
-    "TimeSeries", "P2Quantile", "StreamingHistogram", "MetricsSampler",
-    "Alert", "Watchdog", "HealthMonitor", "NumericsProbe",
-    "default_watchdogs",
 ]

@@ -155,9 +155,9 @@ class ServingRuntime:
         )
         self.buckets = BucketTable()
         self.metrics = ServingMetrics(slots, **({"clock": clock} if clock else {}))
-        #: optional callable fed each decode step's logits (the numerics
-        #: probe installs here — see repro.obs.health.NumericsProbe);
-        #: ``None`` keeps the decode path at a single branch.
+        #: optional callable fed each decode step's logits (tests and the
+        #: benchmark's tests install a recorder here); ``None`` keeps the
+        #: decode path at a single branch.
         self.logits_probe = None
         if self.paged:
             self.pool = PagePool(
@@ -375,12 +375,9 @@ class ServingRuntime:
 
         Measures (and persists, when the dispatcher's cache has a path)
         the fastest execution mode for every distinct contraction the
-        model issues at serving shapes.  With ``tune_policy="predict"``
-        the warm-up is *predict-first*: keys the cost model (fitted on
-        the cache — e.g. one imported from the fleet, see
-        :mod:`repro.tuning.federate`) is confident about skip their
-        measurement sweep entirely, so warm-up wall-clock drops by the
-        predictor's coverage.  Returns the pretune stats dict; the
+        model issues at serving shapes; keys already in the cache are
+        skipped, and under ``tune_policy`` ``"cached"`` or ``"off"``
+        nothing is measured.  Returns the pretune stats dict; the
         dispatcher is kept on ``self.tuner``.
         """
         if tuner is None:

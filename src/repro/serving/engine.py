@@ -42,8 +42,7 @@ class ServeEngine:
                  mesh=None, sharding_rules=None):
         """See :class:`repro.runtime.engine.ServingRuntime` for the
         parameter semantics (``mesh`` serves sharded, ``pretune`` warms
-        the tuning cache — ``tune_policy="predict"`` makes that warm-up
-        predict-first, ``precompile`` warms the program cache)."""
+        the tuning cache, ``precompile`` warms the program cache)."""
         self._rt = ServingRuntime(
             cfg, params, slots=slots, max_len=max_len, greedy=greedy,
             chunked_prefill=False, bucketed_decode=False,

@@ -9,14 +9,9 @@ subsystem closes the loop empirically:
 :mod:`repro.tuning.measure`    — warmup + median-of-k timing harness;
 :mod:`repro.tuning.cache`      — persistent JSON store (canonical keys,
     atomic writes, versioned schema, corruption-tolerant loads);
-:mod:`repro.tuning.model`      — learned cost model fitted on the
-    cache's measurements (ridge + k-NN on log µs, per candidate family,
-    with a training-neighborhood confidence score);
-:mod:`repro.tuning.federate`   — cross-machine cache merge/import
-    (``python -m repro.tuning.federate merge a.json b.json -o f.json``);
 :mod:`repro.tuning.dispatch`   — ``tuned_contract`` / :class:`Dispatcher`
     tying them together under a :data:`TuningPolicy`
-    (off / cached / measure / predict).
+    (off / cached / measure).
 
 Entry points upward: ``contract(..., strategy="tuned")``,
 ``xeinsum(..., optimize="tuned")``, and the serving engine's warm-up pass
@@ -39,18 +34,11 @@ from repro.tuning.dispatch import (
     TuningPolicy,
     default_cache_path,
     get_dispatcher,
+    pick_best,
     set_dispatcher,
     tuned_contract,
 )
-from repro.tuning.federate import (
-    FederationError,
-    import_into,
-    merge_entries,
-    merge_payloads,
-    pick_best,
-)
 from repro.tuning.measure import Measurement, measure_candidate, time_callable
-from repro.tuning.model import CostModel, Prediction, model_for
 
 __all__ = [
     "SCHEMA_VERSION",
@@ -66,15 +54,8 @@ __all__ = [
     "get_dispatcher",
     "set_dispatcher",
     "tuned_contract",
-    "FederationError",
-    "import_into",
-    "merge_entries",
-    "merge_payloads",
     "pick_best",
     "Measurement",
     "measure_candidate",
     "time_callable",
-    "CostModel",
-    "Prediction",
-    "model_for",
 ]

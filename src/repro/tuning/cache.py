@@ -81,10 +81,9 @@ def valid_entry(entry) -> bool:
 
     ``best`` must be a parseable candidate key present in ``results``,
     and every result a number.  Extra keys ride along untouched — the
-    ``"predict"`` policy adds ``predicted``/``confidence``, the
-    transpose audit adds ``transposes`` — so caches grown by newer code
-    stay loadable by older code and mergeable by
-    :mod:`repro.tuning.federate`.
+    transpose audit adds ``transposes``, and caches written by an older
+    cost-model policy carry ``predicted``/``confidence`` — so caches
+    grown by other code versions stay loadable.
     """
     if not (
         isinstance(entry, dict)
@@ -196,20 +195,6 @@ class TuningCache:
         self._version += 1
         if persist:
             self.save()
-
-    def drop(self, key: str, *, persist: bool = True) -> bool:
-        """Evict one entry (drift remediation: a stale winner must be
-        re-measured, not served).  Bumps the fingerprint, so memoized
-        consumers — the cost model via
-        :func:`repro.tuning.model.model_for`, tuned program signatures —
-        refit/recompile on next use.  Returns whether the key existed."""
-        if key not in self.entries:
-            return False
-        del self.entries[key]
-        self._version += 1
-        if persist:
-            self.save()
-        return True
 
     def __contains__(self, key: str) -> bool:
         return key in self.entries

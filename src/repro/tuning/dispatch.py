@@ -11,24 +11,14 @@ routes a pairwise contraction through a :class:`Dispatcher`:
    * ``"measure"`` (default) — enumerate legal candidates
      (:mod:`repro.tuning.candidates`), time each
      (:mod:`repro.tuning.measure`), persist the results, run the winner;
-   * ``"predict"`` — ask the learned cost model
-     (:mod:`repro.tuning.model`, fitted on this cache's accumulated
-     measurements) to pick the winner; when its confidence clears
-     ``self.confidence`` the pick executes immediately — **zero
-     measurement stall** — and is persisted as an entry flagged
-     ``"predicted"`` (distinct from measured entries: the model never
-     trains on it, and a later ``tune()`` re-measures from scratch);
-     below the threshold, fall back to measurement (or analytic under
-     jit, where operands cannot be timed);
    * ``"cached"`` — no measurement; fall back to the analytic
      ``strategy="auto"`` plan (warm caches only, e.g. CI);
    * ``"off"`` — always the analytic plan (a kill switch).
 
 Under a ``jit`` trace operands are abstract and cannot be timed: misses
 silently degrade to the analytic plan (hits still dispatch the winner —
-the winner's identity is static, so it traces fine; confident
-*predictions* also survive jit, being pure arithmetic).  Counters
-(``hits`` / ``misses`` / ``measurements`` / ``predictions``) are exposed
+the winner's identity is static, so it traces fine).  Counters
+(``hits`` / ``misses`` / ``measurements``) are exposed
 on the dispatcher so callers can assert "a warm cache performs zero new
 measurements".
 
@@ -42,7 +32,7 @@ from __future__ import annotations
 import argparse
 import os
 import warnings
-from typing import Iterable, Literal
+from typing import Iterable, Literal, get_args
 
 import jax
 import jax.numpy as jnp
@@ -52,7 +42,6 @@ from repro.core.notation import ContractionSpec, parse_spec
 from repro.obs import trace as _trace
 from repro.tuning.cache import TuningCache, canonical_key
 from repro.tuning.candidates import Candidate, enumerate_candidates
-from repro.tuning.federate import pick_best
 from repro.tuning.measure import measure_candidates
 
 __all__ = [
@@ -63,22 +52,45 @@ __all__ = [
     "set_dispatcher",
     "default_cache_path",
     "path_cost",
+    "pick_best",
     "ANALYTIC_FLOPS_PER_US",
 ]
 
-TuningPolicy = Literal["off", "cached", "measure", "predict"]
+TuningPolicy = Literal["off", "cached", "measure"]
+_POLICIES = get_args(TuningPolicy)
 
 #: legacy flops→µs bridge (10 GFLOP/s).  :func:`path_cost` no longer
 #: uses it — unmeasured steps are priced by the per-step roofline bound
 #: (:func:`repro.obs.roofline.roofline_bound_us`, real hardware
-#: constants) or, under a ``"predict"`` dispatcher, by the cost model's
-#: µs.  Kept exported for external callers of the old pricing.
+#: constants).  Kept exported for external callers of the old pricing.
 ANALYTIC_FLOPS_PER_US = 1.0e4
 
 #: cache keys whose entry turned out structurally dangling (``best`` not
 #: in ``results`` — possible after hand edits or buggy external merges):
 #: each is warned about once per process, then silently treated as a miss.
 _WARNED_DANGLING: set[str] = set()
+
+
+#: ties break toward the analytic plan: a challenger must beat
+#: ``strategy="auto"`` by more than this factor to dethrone it.  With
+#: measurement noise, a hair-thin "win" is as likely to be a loss — and
+#: auto is the choice the rest of the stack reasons about.
+TIE_MARGIN = 0.85
+
+_AUTO_KEY = "xla:auto"
+
+
+def pick_best(results: dict) -> str:
+    """Winner over a per-candidate µs map, ties broken toward ``xla:auto``
+    (see :data:`TIE_MARGIN`)."""
+    best = min(results, key=results.get)
+    if (
+        best != _AUTO_KEY
+        and _AUTO_KEY in results
+        and results[best] > TIE_MARGIN * results[_AUTO_KEY]
+    ):
+        best = _AUTO_KEY
+    return best
 
 
 def default_cache_path() -> str:
@@ -95,17 +107,15 @@ class Dispatcher:
     Args:
       cache: a :class:`TuningCache`, a path for one, or ``None`` for an
         in-memory cache.
-      policy: ``"measure"`` | ``"predict"`` | ``"cached"`` | ``"off"``
-        (see module doc).
+      policy: ``"measure"`` | ``"cached"`` | ``"off"`` (see module doc);
+        anything else raises ``ValueError``.
       backends: backends candidates may use; default
         :func:`~repro.tuning.candidates.default_backends` (XLA-only off
         TPU — Pallas interpret mode is never the wall-clock winner there).
       iters/warmup: measurement repeats per candidate.
-      confidence: minimum cost-model confidence for a ``"predict"``
-        dispatch; below it the policy degrades to measurement.
       audit_transposes: scan each measured candidate's optimized HLO for
         surviving transposes and store the counts in the cache entry —
-        a Fig. 1-style regression signal and a cost-model feature.
+        a Fig. 1-style regression signal.
     """
 
     def __init__(
@@ -116,11 +126,8 @@ class Dispatcher:
         backends: tuple[str, ...] | None = None,
         iters: int = 5,
         warmup: int = 2,
-        confidence: float | None = None,
         audit_transposes: bool = False,
     ):
-        from repro.tuning.model import CONFIDENCE_THRESHOLD
-
         if not isinstance(cache, TuningCache):
             cache = TuningCache(cache)
         self.cache = cache
@@ -128,22 +135,29 @@ class Dispatcher:
         self.backends = backends
         self.iters = iters
         self.warmup = warmup
-        self.confidence = (
-            CONFIDENCE_THRESHOLD if confidence is None else float(confidence)
-        )
         self.audit_transposes = audit_transposes
         self.hits = 0
         self.misses = 0
         self.measurements = 0   # individual candidate timings performed
-        self.predictions = 0    # cold keys dispatched by the cost model
+
+    @property
+    def policy(self) -> TuningPolicy:
+        return self._policy
+
+    @policy.setter
+    def policy(self, policy: TuningPolicy) -> None:
+        if policy not in _POLICIES:
+            raise ValueError(
+                f"unknown tuning policy {policy!r}; choose from {_POLICIES}")
+        self._policy = policy
 
     # ---------------------------------------------------------------- lookup
     def lookup(self, spec, dims, dtype) -> tuple[Candidate, float] | None:
         """Cached (winning candidate, median µs) or ``None`` — no counters.
 
         Hardened against dangling entries whose ``best`` key is missing
-        from ``results`` or unparseable (possible after cross-machine
-        merges or hand-edited caches): those are treated as a miss with
+        from ``results`` or unparseable (possible in hand-edited
+        caches): those are treated as a miss with
         a once-per-key warning, never a ``KeyError`` on the serve path.
         """
         key = canonical_key(spec, dims, dtype)
@@ -169,12 +183,6 @@ class Dispatcher:
         hit = self.lookup(spec, dims, dtype)
         return hit[1] if hit else None
 
-    #: ties break toward the analytic plan: a challenger must beat
-    #: ``strategy="auto"`` by more than this factor to dethrone it.  With
-    #: measurement noise, a hair-thin "win" is as likely to be a loss —
-    #: and auto is the choice the rest of the stack reasons about.
-    TIE_MARGIN = 0.85
-
     # ------------------------------------------------------------------ tune
     def tune(self, spec, A, B) -> dict:
         """Measure every not-yet-measured legal candidate and persist.
@@ -188,9 +196,10 @@ class Dispatcher:
         drift cannot bias the winner.  Counts one measurement per newly
         timed candidate.  Returns the stored entry.
 
-        A prior entry flagged ``"predicted"`` is *discarded*, not
-        merged — its µs are model guesses, and keeping them verbatim
-        would launder a prediction into the training set.
+        A prior entry flagged ``"predicted"`` (written by an older
+        cost-model policy) is *discarded*, not merged — its µs are
+        guesses, and keeping them would pass a guess off as a
+        measurement.
         """
         cs = parse_spec(spec) if isinstance(spec, str) else spec
         from repro.core.contract import infer_dims
@@ -220,7 +229,7 @@ class Dispatcher:
                 k: m.transposes for k, m in measured.items()
                 if m.transposes is not None
             })
-            best = pick_best(results, tie_margin=self.TIE_MARGIN)
+            best = pick_best(results)
             entry = {"best": best, "results": results}
             if transposes:
                 entry["transposes"] = transposes
@@ -230,52 +239,6 @@ class Dispatcher:
                        n_measured=len(measured), winner=best,
                        best_us=float(results[best]))
             return entry
-
-    # --------------------------------------------------------------- predict
-    def model(self):
-        """The cost model over this cache — lazily refit on cache change
-        (:func:`repro.tuning.model.model_for` memoizes by fingerprint)."""
-        from repro.tuning.model import model_for
-
-        return model_for(self.cache)
-
-    def predict(self, spec, dims: dict, dtype):
-        """Cost-model verdict for one contraction (``None`` when no
-        candidate family has enough training data)."""
-        cs = parse_spec(spec) if isinstance(spec, str) else spec
-        return self.model().predict(cs, dims, dtype, backends=self.backends)
-
-    def _record_prediction(self, key: str, pred) -> None:
-        """Persist a model pick, flagged distinctly from measured entries."""
-        self.cache.put(key, {
-            "best": pred.candidate.key(),
-            "results": {k: float(v) for k, v in pred.per_candidate.items()},
-            "predicted": True,
-            "confidence": round(float(pred.confidence), 4),
-        })
-
-    def _try_predict(self, cs, dims, dtype):
-        """The ``"predict"`` miss path: a confident model pick, recorded
-        and traced, or ``None`` (caller falls back to measure/analytic)."""
-        pred = self.predict(cs, dims, dtype)
-        if pred is None or pred.confidence < self.confidence:
-            return None
-        self.predictions += 1
-        self._record_prediction(canonical_key(cs, dims, dtype), pred)
-        if _trace.enabled():
-            from repro.obs.roofline import contraction_record
-
-            rec = contraction_record(cs, dims, dtype)
-            _trace.instant(
-                "tuning_predict", "tuning", spec=cs.spec_str(),
-                winner=pred.candidate.key(), predicted_us=float(pred.us),
-                confidence=float(pred.confidence),
-                roofline_bound_us=rec["roofline_bound_us"],
-                predicted_roofline_fraction=(
-                    rec["roofline_bound_us"] / pred.us if pred.us > 0 else 0.0
-                ),
-            )
-        return pred.candidate
 
     # -------------------------------------------------------------- contract
     def contract(
@@ -314,15 +277,10 @@ class Dispatcher:
                     "tuning_miss", "tuning", spec=cs.spec_str(),
                     policy=self.policy, concrete=concrete,
                 )
-            cand = None
-            if self.policy == "predict":
-                # pure arithmetic: a confident pick works under jit too
-                cand = self._try_predict(cs, dims, dtype)
-            if cand is None:
-                if self.policy not in ("measure", "predict") or not concrete:
-                    return analytic()
-                entry = self.tune(cs, A, B)
-                cand = Candidate.from_key(entry["best"])
+            if self.policy != "measure" or not concrete:
+                return analytic()
+            entry = self.tune(cs, A, B)
+            cand = Candidate.from_key(entry["best"])
         else:
             self.hits += 1
             cand = hit[0]
@@ -359,16 +317,9 @@ class Dispatcher:
         :func:`repro.core.contract.record_contractions` around a model
         trace.  Deduplicates by canonical key, skips existing entries, and
         measures the rest on synthetic operands.  Returns summary stats.
-
-        Under the ``"predict"`` policy the warm-up is **predict-first**:
-        each missing key is offered to the cost model, and only the keys
-        it is *not* confident about are measured — warm-up wall-clock
-        drops by the predictor's coverage (``stats["predicted"]`` keys
-        skip their measurement sweeps entirely).
         """
         rng = np.random.default_rng(seed)
-        stats = {"unique": 0, "cached": 0, "tuned": 0, "predicted": 0,
-                 "skipped": 0}
+        stats = {"unique": 0, "cached": 0, "tuned": 0, "skipped": 0}
         seen: set[str] = set()
         with _trace.span("pretune", "tuning") as sp:
             for spec_str, dims, dtype_str in records:
@@ -382,11 +333,7 @@ class Dispatcher:
                 if key in self.cache:
                     stats["cached"] += 1
                     continue
-                if self.policy == "predict":
-                    if self._try_predict(cs, dims, dtype) is not None:
-                        stats["predicted"] += 1
-                        continue
-                elif self.policy != "measure":
+                if self.policy != "measure":
                     stats["skipped"] += 1
                     continue
                 A = jnp.asarray(
@@ -408,7 +355,6 @@ class Dispatcher:
             "hits": self.hits,
             "misses": self.misses,
             "measurements": self.measurements,
-            "predictions": self.predictions,
             "entries": len(self.cache),
             "policy": self.policy,
         }
@@ -423,7 +369,6 @@ class Dispatcher:
         self.hits = 0
         self.misses = 0
         self.measurements = 0
-        self.predictions = 0
 
 
 # -------------------------------------------------------------- path pricing
@@ -434,11 +379,8 @@ def path_cost(steps, dims: dict, dtype, dispatcher: "Dispatcher | None" = None
     ``steps`` may be :class:`~repro.core.einsum.PathStep` or
     :class:`~repro.core.program.ContractionStep` objects — anything with a
     pairwise ``spec`` and analytic ``flops``.  Steps with a cache entry
-    cost their recorded best µs (measured *or* model-predicted — a
-    ``"predict"`` dispatcher's recorded picks price exactly as they
-    dispatch).  Cold steps under a ``"predict"`` dispatcher are priced
-    by the cost model when it is confident; the final fallback is the
-    per-step **roofline bound**
+    cost their recorded best µs; cold steps are priced by the per-step
+    **roofline bound**
     (:func:`repro.obs.roofline.roofline_bound_us` — hardware ceilings,
     not the old one-size 10 GFLOP/s :data:`ANALYTIC_FLOPS_PER_US`
     scalar, which underpriced memory-bound steps by orders of
@@ -461,11 +403,6 @@ def path_cost(steps, dims: dict, dtype, dispatcher: "Dispatcher | None" = None
             total += us
             trusted += 1
             continue
-        if disp.policy == "predict":
-            pred = disp.predict(cs, dims, dtype)
-            if pred is not None and pred.confidence >= disp.confidence:
-                total += pred.us
-                continue
         total += contraction_record(cs, dims, dtype)["roofline_bound_us"]
     return (total, -trusted)
 

@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from test_paged_attention import paged_oracle
 
 from repro.kernels.flash_attn import flash_attention
 
@@ -78,28 +79,6 @@ def test_flash_gqa_wrapper_pattern():
 
 # ------------------------------------------------------------ paged decode
 PAGE = 16
-
-
-def paged_oracle(q, k_new, v_new, k_pages, v_pages, tables, lengths, layer,
-                 window):
-    """Dense masked softmax in float32, request by request: the cached
-    rows laid end to end from the request's pages, the new row last, at
-    position ``lengths[b]``."""
-    B, G, R, D = q.shape
-    out = []
-    for b in range(B):
-        n = int(lengths[b])
-        rows = lambda pages: np.concatenate(  # noqa: E731
-            [np.asarray(pages[p, layer, 0], np.float32) for p in tables[b]]
-        )[:n].reshape(n, G, D)
-        k = np.concatenate([rows(k_pages), np.asarray(k_new[b])[None]])
-        v = np.concatenate([rows(v_pages), np.asarray(v_new[b])[None]])
-        s = np.einsum("grd,tgd->grt", np.asarray(q[b]), k) * D**-0.5
-        rel = n - np.arange(n + 1)
-        s = np.where(rel < (window or n + 1), s, -np.inf)
-        p = np.exp(s - s.max(-1, keepdims=True))
-        out.append(np.einsum("grt,tgd->grd", p / p.sum(-1, keepdims=True), v))
-    return np.stack(out)
 
 
 #: lengths 1, 16k - 1, 16k and 16k + 1, then request 0 again: the batch

@@ -539,3 +539,34 @@ def test_runtime_metrics_snapshot_end_to_end(served):
     assert 0 < snap["bucket_hit_rate"] <= 1
     assert snap["throughput_tok_s"] > 0
     assert 0 < snap["slot_occupancy"] <= 1
+
+
+def test_logits_probe_sees_every_decode_step(served):
+    """``logits_probe`` is handed each paged decode launch's logits, one
+    row per decoding request in batch order: the argmax of every row is
+    the token that step emitted, and no decode token goes unseen."""
+    from repro.runtime.engine import ServingRuntime
+
+    cfg, _, params = served
+    rt = ServingRuntime(cfg, params, slots=2, max_len=64, prefill_chunk=8,
+                        paged=True, precompile=False)
+    at, seen, launches = [], [], []
+    run_impl = rt._run_decode_impl
+
+    def decode_impl(decodes):
+        at[:] = [s.rid for s in decodes]
+        launches.append(len(decodes))
+        run_impl(decodes)
+
+    rt._run_decode_impl = decode_impl
+    rt.logits_probe = lambda lg: seen.append(
+        [(rid, int(np.argmax(np.asarray(lg[r, 0], np.float32))))
+         for r, rid in enumerate(at)])
+    reqs = _ragged_requests(cfg, [3, 11, 7], max_new=4)
+    rt.serve(reqs)
+    assert all(r.done for r in reqs)
+    assert [len(step) for step in seen] == launches
+    for r in reqs:
+        # the first token comes from prefill, every later one from a decode
+        assert r.output[1:] == [t for step in seen for rid, t in step
+                                if rid == r.rid]

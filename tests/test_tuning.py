@@ -23,18 +23,14 @@ from repro.tuning import (
     SCHEMA_VERSION,
     Candidate,
     Dispatcher,
-    FederationError,
     TuningCache,
     canonical_key,
     enumerate_candidates,
-    import_into,
-    merge_entries,
+    pick_best,
     set_dispatcher,
     tuned_contract,
     validate_tiles,
 )
-from repro.tuning.federate import load_payload, merge_entry
-from repro.tuning.federate import main as federate_main
 
 SPEC = "mk,pkn->pmn"
 DIMS = {"m": 12, "k": 16, "p": 4, "n": 8}
@@ -406,98 +402,47 @@ def test_audit_transposes_stored_in_entry(tmp_path):
     assert reloaded["transposes"] == entry["transposes"]
 
 
-# ---------------------------------------------------------------- federation
-_F1 = {"best": "xla:auto", "results": {"xla:auto": 10.0, "xla:direct": 30.0}}
-_F2 = {"best": "xla:direct", "results": {"xla:direct": 4.0, "xla:flat": 9.0}}
+# ------------------------------------------------------------------ policies
+def test_unknown_policy_refused():
+    with pytest.raises(ValueError, match="unknown tuning policy"):
+        Dispatcher(None, policy="predict")
+    d = Dispatcher(None, policy="cached")
+    with pytest.raises(ValueError, match="bogus"):
+        d.policy = "bogus"
+    assert d.policy == "cached"
 
 
-def test_federation_merge_commutative_associative_idempotent():
-    a = {"k1": _F1, "k2": _F1}
-    b = {"k1": _F2, "k3": _F2}
-    ab = merge_entries(a, b)
-    assert ab == merge_entries(b, a)                     # commutative
-    assert merge_entries(ab, b) == ab                    # absorbs repeats
-    assert merge_entries(ab, ab) == ab                   # idempotent
-    assert set(ab) == {"k1", "k2", "k3"}
+def test_pick_best_ties_toward_auto():
+    # the fastest candidate wins when it beats auto by more than the margin
+    assert pick_best({"xla:auto": 10.0, "xla:direct": 4.0,
+                      "xla:flat": 9.0}) == "xla:direct"
+    # ... but a hair-thin challenger still loses to auto
+    assert pick_best({"xla:auto": 10.0, "xla:direct": 9.5}) == "xla:auto"
+    # without auto in the map, the fastest wins outright
+    assert pick_best({"xla:direct": 9.5, "xla:flat": 9.0}) == "xla:flat"
 
 
-def test_federation_winner_repicked_over_union():
-    # both sources were locally right; the union's fastest candidate is
-    # one neither source crowned alone
-    m = merge_entry(_F1, _F2)
-    assert m["results"] == {"xla:auto": 10.0, "xla:direct": 4.0,
-                            "xla:flat": 9.0}
-    assert m["best"] == "xla:direct"
-    # ... but a hair-thin challenger still loses to auto (tie margin)
-    m2 = merge_entry({"best": "xla:auto", "results": {"xla:auto": 10.0}},
-                     {"best": "xla:direct", "results": {"xla:direct": 9.5}})
-    assert m2["best"] == "xla:auto"
-
-
-def test_federation_measured_beats_predicted():
-    pred = {"best": "xla:direct", "results": {"xla:direct": 3.0},
-            "predicted": True, "confidence": 0.9}
-    meas = {"best": "xla:auto", "results": {"xla:auto": 10.0}}
-    assert merge_entry(pred, meas) == meas
-    assert merge_entry(meas, pred) == meas
-    weaker = {**pred, "confidence": 0.2}
-    assert merge_entry(pred, weaker) == pred
-    assert merge_entry(weaker, pred) == pred
-
-
-def test_federation_rejects_corrupt_sources(tmp_path):
-    bad = tmp_path / "bad.json"
-    bad.write_text("{not json")
-    with pytest.raises(FederationError, match="unreadable"):
-        load_payload(bad)
-    bad.write_text(json.dumps({"schema": SCHEMA_VERSION + 9, "entries": {}}))
-    with pytest.raises(FederationError, match="schema"):
-        load_payload(bad)
-    bad.write_text(json.dumps({"schema": SCHEMA_VERSION,
-                               "entries": {"k": {"results": "nope"}}}))
-    with pytest.raises(FederationError, match="malformed"):
-        load_payload(bad)
-    # strict: a bad source must leave the target cache untouched
-    c = TuningCache(None)
-    with pytest.raises(FederationError):
-        import_into(c, os.fspath(bad))
-    assert len(c) == 0
-
-
-def test_federation_import_into_live_cache(tmp_path):
-    src = tmp_path / "src.json"
-    src.write_text(json.dumps({"schema": SCHEMA_VERSION,
-                               "entries": {"k1": _F2, "k9": _F1}}))
-    c = TuningCache(tmp_path / "dst.json")
-    c.put("k1", _F1)
-    fp = c.fingerprint()
-    stats = import_into(c, src)
-    assert stats == {"imported": 2, "merged": 1, "added": 1}
-    assert c.get("k1")["best"] == "xla:direct"   # re-picked over the union
-    assert c.fingerprint() != fp                 # consumers must refit
-    assert TuningCache(c.path).get("k1")["best"] == "xla:direct"  # persisted
-
-
-def test_federation_cli_merge_then_zero_remeasure(tmp_path, capsys):
-    """The fleet scenario end-to-end: two machines tune disjoint working
-    sets, the CLI merges their caches, and a dispatcher over the merged
-    store serves both sets without a single new measurement."""
-    a_path, b_path = tmp_path / "a.json", tmp_path / "b.json"
-    spec2, dims2 = "ab,bc->ac", {"a": 8, "b": 8, "c": 8}
-    A1, B1 = _operands(seed=1)
-    A2, B2 = _operands(spec2, dims2, seed=2)
-    _disp(a_path).contract(SPEC, A1, B1)
-    _disp(b_path).contract(spec2, A2, B2)
-
-    out = tmp_path / "fleet.json"
-    federate_main(["merge", os.fspath(a_path), os.fspath(b_path),
-                   "-o", os.fspath(out)])
-    assert "2 unique" in capsys.readouterr().out
-
-    d = _disp(out)
-    d.contract(SPEC, A1, B1)
-    d.contract(spec2, A2, B2)
-    assert d.measurements == 0 and d.hits == 2
+def test_predicted_entry_loads_and_tune_discards_it(tmp_path):
+    """A cache file written by the retired cost-model policy still loads
+    and dispatches as a hit, but ``tune`` never keeps its guessed µs."""
+    key = canonical_key(SPEC, DIMS, jnp.float32)
+    guess = {"best": "xla:direct", "results": {"xla:direct": 0.001},
+             "predicted": True, "confidence": 0.9}
+    path = tmp_path / "old.json"
+    path.write_text(json.dumps({"schema": SCHEMA_VERSION,
+                                "entries": {key: guess}}))
+    d = _disp(path)
+    assert d.cache.get(key) == guess
+    cand, us = d.lookup(SPEC, DIMS, jnp.float32)
+    assert (cand.key(), us) == ("xla:direct", 0.001)
+    A, B = _operands()
+    entry = d.tune(SPEC, A, B)
+    assert "predicted" not in entry
+    assert entry["results"]["xla:direct"] != 0.001
+    assert set(entry["results"]) == {
+        c.key() for c in enumerate_candidates(
+            parse_spec(SPEC), DIMS, dtype=jnp.float32, backends=("xla",))}
+    assert TuningCache(path).get(key) == entry
 
 
 def test_tuned_contract_correct_and_counts(tmp_path):

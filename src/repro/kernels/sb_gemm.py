@@ -204,7 +204,7 @@ def expert_order(touched):
 def _expert_kernel(order_ref, n_ref, x_ref, c_ref, wi_ref, wg_ref, wo_ref,
                    o_ref, acc_ref, *, upcast: bool):
     """Grid step i: accumulate ``(c * silu(x Wg) * (x Wi)) Wo`` of the
-    i-th touched expert."""
+    i-th touched expert.  ``n_ref``: (touched experts, period)."""
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -213,7 +213,9 @@ def _expert_kernel(order_ref, n_ref, x_ref, c_ref, wi_ref, wg_ref, wo_ref,
 
     @pl.when(i < n_ref[0])
     def _step():
-        x, wi, wg, wo = x_ref[...], wi_ref[...], wg_ref[...], wo_ref[...]
+        x = x_ref[...]
+        # weights stored in another dtype are cast a block at a time
+        wi, wg, wo = (r[...].astype(x.dtype) for r in (wi_ref, wg_ref, wo_ref))
         if upcast:  # interpret-on-CPU only: XLA:CPU lacks bf16 dot thunks
             x, wi, wg, wo = (a.astype(jnp.float32) for a in (x, wi, wg, wo))
         g = jnp.dot(x, wg, preferred_element_type=jnp.float32)
@@ -227,7 +229,8 @@ def _expert_kernel(order_ref, n_ref, x_ref, c_ref, wi_ref, wg_ref, wo_ref,
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def expert_swiglu_pallas(x, comb, wi, wg, wo, *, interpret: bool | None = None):
+def expert_swiglu_pallas(x, comb, wi, wg, wo, period, *,
+                         interpret: bool | None = None):
     """Dropless SwiGLU over held experts: ``sum_e comb[:, e] * (silu(x
     Wg_e) * (x Wi_e)) Wo_e``, one kernel.
 
@@ -242,30 +245,41 @@ def expert_swiglu_pallas(x, comb, wi, wg, wo, *, interpret: bool | None = None):
     operations.  A step holds one whole expert (three 2304 x 896 bf16
     matrices at published widths, 12.4 MB, double-buffered).
 
+    The weights are read in place from a stack of layers (a scanned
+    model's periods): ``period``, scalar-prefetched with the expert
+    order and count, picks the layer, so no layer's weights are sliced
+    out of the stack first.  Weights stored in another dtype than ``x``
+    are cast a block at a time inside the kernel.
+
     ``x``: (T, E); ``comb``: (T, X) float32 combine weights; ``wi``,
-    ``wg``: (X, E, F); ``wo``: (X, F, E).  Returns (T, E) in ``x``'s
-    dtype, accumulated in float32.
+    ``wg``: (P, X, E, F); ``wo``: (P, X, F, E); ``period``: int32 scalar
+    in ``[0, P)``.  Returns (T, E) in ``x``'s dtype, accumulated in
+    float32.
     """
     if interpret is None:
         interpret = interpret_mode(x, wi)
     T, E = x.shape
-    X, _, F = wi.shape
+    _, X, _, F = wi.shape
     rows = -(-T // 16) * 16          # bf16 sublane tile
     order, n = expert_order(jnp.any(comb > 0, axis=0))
+    n = jnp.concatenate([n, jnp.asarray(period, jnp.int32).reshape(1)])
     xp = jnp.pad(x, ((0, rows - T), (0, 0)))
     # one weight per (expert, row), repeated over a lane tile
     cp = jnp.broadcast_to(
         jnp.pad(comb.astype(jnp.float32), ((0, rows - T), (0, 0))).T[..., None],
         (X, rows, 128))
+
+    def weights(block):   # expert o[i] of period n[1]
+        return pl.BlockSpec((None, None) + block,
+                            lambda i, o, n: (n[1], o[i], 0, 0))
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(X,),
         in_specs=[
             pl.BlockSpec((rows, E), lambda i, o, n: (0, 0)),
             pl.BlockSpec((None, rows, 128), lambda i, o, n: (o[i], 0, 0)),
-            pl.BlockSpec((None, E, F), lambda i, o, n: (o[i], 0, 0)),
-            pl.BlockSpec((None, E, F), lambda i, o, n: (o[i], 0, 0)),
-            pl.BlockSpec((None, F, E), lambda i, o, n: (o[i], 0, 0)),
+            weights((E, F)), weights((E, F)), weights((F, E)),
         ],
         out_specs=pl.BlockSpec((rows, E), lambda i, o, n: (0, 0)),
         scratch_shapes=[pltpu.VMEM((rows, E), jnp.float32)],

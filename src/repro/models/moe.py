@@ -8,7 +8,8 @@ this chip holds (``MoEConfig.n_held`` from ``first_held``; an
 expert-parallel share, whose exchange with the other chips is not run).
 Its expert FFN is one Pallas kernel over the held experts
 (:func:`repro.kernels.sb_gemm.expert_swiglu_pallas`): no capacity, no
-dropped token, no weight gathered per token.  The decode step is vmapped
+dropped token, no weight gathered per token, and a scanned layer's
+weights read in place from their period stacks.  The decode step is vmapped
 over slots, so the layer carries its own batching rule: under ``vmap``
 the whole batch's tokens are one token group.
 
@@ -42,7 +43,7 @@ from repro.kernels.sb_gemm import expert_swiglu_pallas
 from repro.models.layers import init_dense, init_mlp, mlp
 
 __all__ = ["init_moe", "moe_ffn", "moe_held", "route", "held_weights",
-           "router_aux_loss"]
+           "router_aux_loss", "EXPERT_WEIGHTS"]
 
 
 def _ctr(cfg: ModelConfig):
@@ -120,7 +121,11 @@ def held_weights(m: MoEConfig, top_w, top_e):
     return comb, jnp.sum(chose, axis=1, dtype=jnp.int32)
 
 
-def _held_ffn(cfg: ModelConfig, params, x):
+#: the routed experts' weights, the held-expert kernel's operands
+EXPERT_WEIGHTS = ("wi", "wg", "wo")
+
+
+def _held_ffn(cfg: ModelConfig, params, x, period):
     m: MoEConfig = cfg.moe
     if cfg.mlp_act != "swiglu":
         raise NotImplementedError("the held-expert kernel is SwiGLU")
@@ -130,35 +135,44 @@ def _held_ffn(cfg: ModelConfig, params, x):
         comb, held = held_weights(m, *route(m, params["router"], xt))
     with jax.named_scope("moe_experts"):
         y = expert_swiglu_pallas(
-            xt, comb, *(params[k].astype(x.dtype) for k in ("wi", "wg", "wo")))
+            xt, comb, *(params[k] for k in EXPERT_WEIGHTS), period)
     y = y.reshape(B, S, E)
     for i in range(m.n_shared):  # every chip computes these alike
         y = y + mlp(cfg, jax.tree.map(lambda p, i=i: p[i], params["shared"]), x)
     return y, held.reshape(B, S, -1)
 
 
-def moe_held(cfg: ModelConfig, params, x):
+def moe_held(cfg: ModelConfig, params, x, period=None):
     """The dropless held-expert layer.  x: (B, S, E) → (y (B, S, E),
     held (B, S, n_held) int32: each token's choices among the held
     experts).
 
+    ``period``: where ``params``' routed-expert weights are a scanned
+    pattern's whole stacks (``(n_periods, n_held, ...)``), the period
+    whose experts run, read in place by the kernel; ``None`` for one
+    layer's weights.
+
     Under ``vmap`` over ``x`` (the runtime's decode over slots) the
     batch's tokens run as one group: one routing, one kernel launch,
     each held expert's weights read once for all of them."""
+    if period is None:   # one layer's weights: a stack of one period
+        params = {k: v[None] if k in EXPERT_WEIGHTS else v
+                  for k, v in params.items()}
+        period = 0
     fn = jax.custom_batching.custom_vmap(functools.partial(_held_ffn, cfg))
 
     @fn.def_vmap
-    def _one_group(axis_size, in_batched, params, x):
-        p_batched, x_batched = in_batched
-        if any(jax.tree.leaves(p_batched)):
+    def _one_group(axis_size, in_batched, params, x, period):
+        p_batched, x_batched, period_batched = in_batched
+        if any(jax.tree.leaves(p_batched)) or period_batched:
             raise NotImplementedError("expert weights batched under vmap")
         if not x_batched:
-            return fn(params, x), (False, False)
-        y, held = fn(params, x.reshape((-1,) + x.shape[2:]))
+            return fn(params, x, period), (False, False)
+        y, held = fn(params, x.reshape((-1,) + x.shape[2:]), period)
         return (y.reshape(x.shape), held.reshape(x.shape[:-1] + (-1,))), (
             True, True)
 
-    return fn(params, x)
+    return fn(params, x, jnp.asarray(period, jnp.int32))
 
 
 def moe_ffn(cfg: ModelConfig, params, x, *, capacity: int | None = None):

@@ -19,7 +19,7 @@ from repro.core.einsum import xeinsum
 from repro.distributed.sharding import logical
 from repro.models import layers as L
 from repro.models.frontend import apply_frontend, init_frontend
-from repro.models.moe import init_moe, moe_ffn, moe_held
+from repro.models.moe import EXPERT_WEIGHTS, init_moe, moe_ffn, moe_held
 from repro.models.ssm import init_mamba, init_ssm_cache, mamba_mixer
 
 __all__ = [
@@ -52,12 +52,14 @@ def _init_block(key, cfg: ModelConfig, spec: LayerSpec):
     return p
 
 
-def _block(cfg: ModelConfig, spec: LayerSpec, params, x, *, positions, cache=None):
+def _block(cfg: ModelConfig, spec: LayerSpec, params, x, *, positions,
+           cache=None, period=None):
     """Pre-norm residual block.  Returns (x, new_cache, aux).
 
     A sparse layer runs the dropless held-expert layer on cached calls
     (serving), whose aux is ``{"moe_held": (B, S, n_held) int32}``, and
-    the capacity-routed layer otherwise (training)."""
+    the capacity-routed layer otherwise (training).  ``period``: see
+    :func:`~repro.models.moe.moe_held`."""
     aux = {}
     h = L.rms_norm(x, params["norm1"], cfg.rms_eps)
     if spec.mixer == "attn":
@@ -75,7 +77,7 @@ def _block(cfg: ModelConfig, spec: LayerSpec, params, x, *, positions, cache=Non
         if spec.ff == "dense":
             x = x + L.mlp(cfg, params["mlp"], h)
         elif cache is not None:
-            y, held = moe_held(cfg, params["moe"], h)
+            y, held = moe_held(cfg, params["moe"], h, period)
             x = x + y
             aux = {"moe_held": held}
         else:
@@ -120,6 +122,22 @@ def _acc_aux(acc, aux):
 
 
 # -------------------------------------------------------------- the stack
+def _split_experts(cfg: ModelConfig, pattern):
+    """(experts, rest): the routed-expert weights of each sparse pattern
+    layer, whole period stacks keyed by the layer's index in the period,
+    and the pattern's params without them.  XLA cannot fuse a scan's
+    slice of a stack into the expert kernel (a custom call) and would
+    copy each period's experts out first, so the scan leaves them out."""
+    experts, rest = {}, []
+    for j, (spec, p) in enumerate(zip(cfg.pattern, pattern)):
+        if spec.ff == "moe":
+            moe = dict(p["moe"])
+            experts[j] = {k: moe.pop(k) for k in EXPERT_WEIGHTS if k in moe}
+            p = {**p, "moe": moe}
+        rest.append(p)
+    return experts, rest
+
+
 def _embed_inputs(cfg: ModelConfig, params, batch):
     dt = cfg.activation_dtype()
     if cfg.frontend is not None and cfg.frontend.kind == "audio":
@@ -138,7 +156,10 @@ def _run_stack(cfg: ModelConfig, params, x, positions, cache=None, remat=False):
     in layer order for a stack with sparse layers, else empty.  A cache
     whose layers are :class:`~repro.models.layers.KVPages` is read in
     place: the period scan closes over the pool and takes each period's
-    index, so no period's pages are sliced out of it."""
+    index, so no period's pages are sliced out of it.  The routed
+    experts of sparse pattern layers are read in place likewise: the
+    scan closes over their weights' period stacks and the expert kernel
+    takes the period's index."""
     aux_acc = {"load_balance_loss": jnp.zeros((), jnp.float32)}
     new_prefix, prefix_held = [], []
     prefix_caches = cache["prefix"] if cache is not None else [None] * len(cfg.prefix)
@@ -165,18 +186,21 @@ def _run_stack(cfg: ModelConfig, params, x, positions, cache=None, remat=False):
         return x, None, aux_acc
 
     paged = any(isinstance(c, L.KVPages) for c in cache["pattern"])
-    periods = jnp.arange(cfg.n_periods) if paged else cache["pattern"]
+    experts, pattern = _split_experts(cfg, params["pattern"])
+    periods = jnp.arange(cfg.n_periods) if paged or experts else None
 
     def period_body_cached(x, inp):
-        period_params, period_cache = inp
+        period_params, period_cache, period = inp
         if paged:
-            period_cache = [c._replace(layer=period_cache)
-                            for c in cache["pattern"]]
+            period_cache = [c._replace(layer=period) for c in cache["pattern"]]
         new_caches, held = [], []
         for j, spec in enumerate(cfg.pattern):
+            p = period_params[j]
+            if j in experts:
+                p = {**p, "moe": {**p["moe"], **experts[j]}}
             x, nc, aux = _block(
-                cfg, spec, period_params[j], x, positions=positions,
-                cache=period_cache[j],
+                cfg, spec, p, x, positions=positions, cache=period_cache[j],
+                period=period,
             )
             new_caches.append(nc)
             if "moe_held" in aux:
@@ -184,7 +208,8 @@ def _run_stack(cfg: ModelConfig, params, x, positions, cache=None, remat=False):
         return x, (new_caches, jnp.stack(held) if held else None)
 
     x, (new_pattern, held) = jax.lax.scan(
-        period_body_cached, x, (params["pattern"], periods)
+        period_body_cached, x,
+        (pattern, None if paged else cache["pattern"], periods),
     )
     new_cache = {
         "prefix": new_prefix,

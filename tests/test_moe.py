@@ -13,7 +13,9 @@ import pytest
 from repro.configs import get_config
 from repro.configs.base import MoEConfig, YaRN
 from repro.models.layers import mlp, rope_freqs
-from repro.models.moe import held_weights, init_moe, moe_held, route
+from repro.kernels.sb_gemm import expert_swiglu_pallas
+from repro.models.moe import (EXPERT_WEIGHTS, held_weights, init_moe,
+                              moe_held, route)
 
 
 def _cfg(n_experts=16, top_k=4, n_shared=0, **moe):
@@ -168,6 +170,59 @@ def test_vmapped_layer_is_one_group_of_the_stacked_tokens():
     calls = _kernels(jax.make_jaxpr(f)(x).jaxpr)
     assert len(calls) == 1
     assert calls[0].invars[2].aval.shape[0] == 16      # 6 rows, padded
+
+
+#: periods of the stacks the kernel reads in place
+PERIODS = 3
+
+
+@pytest.mark.parametrize("x_dtype,w_dtype", [
+    ("float32", "float32"),
+    ("bfloat16", "bfloat16"),
+    ("bfloat16", "float32"),     # cast a block at a time in the kernel
+])
+def test_expert_kernel_reads_each_period_of_a_stack_in_place(x_dtype,
+                                                             w_dtype):
+    """Given a stack of three periods' weights and a period p, the kernel
+    gives, bit for bit, what it gives for period p's weights alone, cast
+    to the activations' dtype first.  Every other period and the held
+    experts no token chose (1 and 5) are NaN: the kernel reads neither."""
+    X, E, F, T = 8, 32, 16, 5
+    ks = jax.random.split(jax.random.key(8), 5)
+    wi, wg, wo = (jax.random.normal(k, (PERIODS, X) + s).astype(w_dtype)
+                  for k, s in zip(ks, ((E, F), (E, F), (F, E))))
+    x = jax.random.normal(ks[3], (T, E)).astype(x_dtype)
+    comb = jax.random.uniform(ks[4], (T, X)).at[:, jnp.array([1, 5])].set(0.0)
+    for p in range(PERIODS):
+        want = expert_swiglu_pallas(
+            x, comb, *(w[p:p + 1].astype(x_dtype) for w in (wi, wg, wo)), 0)
+        other = (jnp.arange(PERIODS) != p)[:, None]
+        untouched = jnp.isin(jnp.arange(X), jnp.array([1, 5]))[None]
+        poison = (other | untouched)[..., None, None]
+        got = expert_swiglu_pallas(
+            x, comb, *(jnp.where(poison, jnp.nan, w) for w in (wi, wg, wo)),
+            p)
+        assert got.dtype == x.dtype and bool(jnp.all(jnp.isfinite(got)))
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+
+
+def test_layer_of_a_stack_runs_as_its_own_weights():
+    """A sparse layer outside the period scan (a prefix layer) holds one
+    layer's weights and runs them as a stack of one period: bit for bit
+    what the layer gives reading the same weights from a stack of three
+    periods, for every period."""
+    cfg = _cfg()
+    stack = jax.vmap(lambda k: init_moe(k, cfg))(
+        jax.random.split(jax.random.key(9), PERIODS))
+    x = jax.random.normal(jax.random.key(10), (2, 3, cfg.d_model))
+    for p in range(PERIODS):
+        layer = jax.tree.map(lambda v: v[p], stack)
+        y, held = moe_held(cfg, layer, x)
+        y_in_place, held_in_place = moe_held(
+            cfg, {**layer, **{k: stack[k] for k in EXPERT_WEIGHTS}}, x, p)
+        np.testing.assert_array_equal(np.asarray(y_in_place), np.asarray(y))
+        np.testing.assert_array_equal(held_in_place, held)
 
 
 def _kernels(jaxpr):

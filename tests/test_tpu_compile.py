@@ -286,12 +286,16 @@ def _assert_reads_pages_in_place(kv, text, slots, pages):
 # ------------------------------------------------------- held experts
 #: mellum2-12b-a2.5b at published widths: 16 held experts of 2304 x 896
 EXPERTS, D_MODEL, D_EXPERT = 16, 2304, 896
+#: one layer's expert stack, as a scan would slice it out of its periods
+LAYER_STACKS = (f"[{EXPERTS},{D_MODEL},{D_EXPERT}]",
+                f"[{EXPERTS},{D_EXPERT},{D_MODEL}]")
 
 
 @pytest.mark.parametrize("rows", [8, 64])
 def test_expert_kernel_compiles_for_v5e(rows, one_chip):
     """The held-expert SwiGLU at published widths, for a decode batch of
-    8 and a prefill chunk of 64 tokens."""
+    8 and a prefill chunk of 64 tokens, reading one period of a stack of
+    two."""
     from repro.kernels.sb_gemm import expert_swiglu_pallas
 
     def aval(shape, dtype=jnp.bfloat16):
@@ -299,8 +303,10 @@ def test_expert_kernel_compiles_for_v5e(rows, one_chip):
 
     text = jax.jit(lambda *a: expert_swiglu_pallas(*a, interpret=False)).lower(
         aval((rows, D_MODEL)), aval((rows, EXPERTS), jnp.float32),
-        aval((EXPERTS, D_MODEL, D_EXPERT)), aval((EXPERTS, D_MODEL, D_EXPERT)),
-        aval((EXPERTS, D_EXPERT, D_MODEL))).compile().as_text()
+        aval((2, EXPERTS, D_MODEL, D_EXPERT)),
+        aval((2, EXPERTS, D_MODEL, D_EXPERT)),
+        aval((2, EXPERTS, D_EXPERT, D_MODEL)),
+        aval((), jnp.int32)).compile().as_text()
     assert _kernels(text) == ["expert_swiglu"]
 
 
@@ -311,13 +317,10 @@ def _kernels(text: str) -> list[str]:
                    if 'custom_call_target="tpu_custom_call"' in line})
 
 
-def test_sparse_serve_decode_holds_only_the_expert_kernel(one_chip,
-                                                          monkeypatch):
-    """mellum2-12b-a2.5b's paged decode at 8 slots (one period of its
-    four layer kinds, published widths, 16 held experts): the expert
-    kernel and the paged-attention kernel are the only Mosaic kernels in
-    ``serve_decode``, no value of a view's size is held, and its GQA pool
-    (4 x 128 = 512 lanes a row) is page-major and never relaid out."""
+def _sparse_serving(one_chip, monkeypatch, n_periods, slots=8, pages=32):
+    """(config, abstract params, PagedKV, abstract decode arguments,
+    vmapped decode) for mellum2-12b-a2.5b at published widths, 16 held
+    experts, ``n_periods`` periods of its four layer kinds."""
     import dataclasses
 
     from repro.configs import get_config
@@ -326,8 +329,7 @@ def test_sparse_serve_decode_holds_only_the_expert_kernel(one_chip,
 
     monkeypatch.setattr("repro.kernels.sb_gemm.interpret_mode",
                         lambda *arrays: False)
-    slots, pages = 8, 32
-    cfg = get_config("mellum2-12b-a2.5b", pad_vocab=False, n_periods=1,
+    cfg = get_config("mellum2-12b-a2.5b", pad_vocab=False, n_periods=n_periods,
                      dtype="bfloat16", param_dtype="bfloat16")
     cfg = cfg.with_(moe=dataclasses.replace(cfg.moe, n_held=EXPERTS))
     kv = PagedKV(cfg, n_pages=slots * (3584 // PAGE_ROWS) + 1,
@@ -344,9 +346,65 @@ def test_sparse_serve_decode_holds_only_the_expert_kernel(one_chip,
             aval(jnp.zeros((slots, 1, 1), jnp.int32)))
     vm = jax.vmap(lambda p, c, t: decode_step(cfg, p, c, t, stats=True),
                   in_axes=(None, 0, 0))
+    return cfg, params, kv, args, vm
+
+
+def test_sparse_serve_decode_holds_only_the_expert_kernel(one_chip,
+                                                          monkeypatch):
+    """mellum2-12b-a2.5b's paged decode at 8 slots (one period of its
+    four layer kinds, published widths, 16 held experts): the expert
+    kernel and the paged-attention kernel are the only Mosaic kernels in
+    ``serve_decode``, no value of a view's size is held, and its GQA pool
+    (4 x 128 = 512 lanes a row) is page-major and never relaid out."""
+    slots, pages = 8, 32
+    _, _, kv, args, vm = _sparse_serving(one_chip, monkeypatch, 1, slots,
+                                         pages)
     assert {x.shape[-1] for x in jax.tree.leaves(kv.pool)} == {512}
     texts = _assert_pool_never_relaid_out(kv, args, vm, slots, pages,
                                           one_chip)
     assert _kernels(texts["serve_decode"]) == ["expert_swiglu",
                                                "paged_attention"]
     _assert_reads_pages_in_place(kv, texts["serve_decode"], slots, pages)
+
+
+def test_sparse_serve_programs_read_expert_weights_in_place(one_chip,
+                                                           monkeypatch):
+    """mellum2-12b-a2.5b's paged ``serve_decode`` at 8 slots and its
+    ``serve_prefill`` of a 64-token chunk, two periods so that a period
+    scan exists: the expert kernel takes each weight as its whole
+    ``(periods, experts, ...)`` stack, and no op of either program yields
+    one layer's expert stack (the slice a scan would copy out for it)."""
+    from repro.models.transformer import init_cache, prefill
+
+    slots, pages = 8, 32
+    cfg, params, kv, args, vm = _sparse_serving(one_chip, monkeypatch, 2,
+                                                slots, pages)
+
+    def aval(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    stage = jax.tree.map(aval, jax.eval_shape(
+        lambda: init_cache(cfg, 1, pages * PAGE_ROWS)))
+    texts = {
+        "serve_decode": kv.build_decode(vm, slots, pages).lower(*args),
+        "serve_prefill": jax.jit(
+            lambda p, t, c: prefill(cfg, p, {"tokens": t}, c, stats=True)
+        ).lower(params, aval(jnp.zeros((1, 64), jnp.int32)), stage),
+    }
+    stacks = sorted(2 * [f"bf16[2,{EXPERTS},{D_MODEL},{D_EXPERT}]"]
+                    + [f"bf16[2,{EXPERTS},{D_EXPERT},{D_MODEL}]"])
+    for name, lowered in texts.items():
+        text = lowered.compile().as_text()
+        assert "expert_swiglu" in _kernels(text), name
+        for shape in LAYER_STACKS:
+            assert shape not in text, (name, shape)
+        calls = [line for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line
+                 and re.search(r"%expert_swiglu(\.\d+)? = ", line)]
+        # one launch per sparse layer of the scanned period
+        assert len(calls) == len(cfg.pattern), (name, len(calls))
+        for call in calls:
+            operands = re.search(r"operand_layout_constraints=\{(.*?\})\}",
+                                 call).group(1)
+            weights = sorted(re.findall(r"bf16\[2,[0-9,]+\]", operands))
+            assert weights == stacks, (name, operands)
